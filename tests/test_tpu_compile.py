@@ -1,0 +1,120 @@
+"""Ask the v5e compiler, with no chip attached, whether the programs of
+the main path compile at their real sizes.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, and every xdist worker imports
+this file.  The compiles run in the test's own process for the same reason.
+Nothing here runs on a device; a pass says the chip's compiler accepts the
+program, not that the chip ran it (``chip_smoke.py`` is that check).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import __graft_entry__ as ge
+from spark_rapids_jni_tpu.ops import pallas_kernels as PK
+
+HBM_BYTES = 15.75 * (1 << 30)  # what the v5e compiler calls its hbm
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("mf", [2, 0], ids=["int_float", "int_only"])
+def test_onehot_groupby_kernel_compiles(one_chip, mf):
+    n = 1 << 22
+    PK._onehot_gb_call.lower(
+        _sds((n,), jnp.int32, one_chip), _sds((n, 9), jnp.int8, one_chip),
+        _sds((n, mf), jnp.float32, one_chip),
+        domain=101, interpret=False).compile()
+
+
+def test_q6_step_compiles_and_fits(one_chip):
+    n = 1 << 24
+    batch = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: ge._device_batch(0, n)))
+    mem = jax.jit(ge._q6_step).lower(batch).compile().memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES
+
+
+def _lower_slot_build(s):
+    n, S = 1 << 16, 4096
+    return PK._slot_build_call.lower(
+        _sds((n,), jnp.int32, s), _sds((n, 2), jnp.uint32, s),
+        _sds((n,), jnp.bool_, s), num_slots=S, max_rounds=S,
+        interpret=False)
+
+
+def _lower_slot_probe(s):
+    n, S = 1 << 16, 4096
+    return PK._slot_probe_call.lower(
+        _sds((S,), jnp.int32, s), _sds((S, 2), jnp.uint32, s),
+        _sds((n,), jnp.int32, s), _sds((n, 2), jnp.uint32, s),
+        _sds((n,), jnp.bool_, s), _sds((1,), jnp.int32, s),
+        n_build=n, interpret=False)
+
+
+def _lower_partition_scatter(s):
+    P, C, M = 8, 4096, 8192
+
+    def f(chunk, occ, morsel, cnts, base, rnd):
+        return PK.partition_scatter(chunk, occ, morsel, cnts, base, rnd,
+                                    P, C, interpret=False)
+
+    return jax.jit(f).lower(
+        (_sds((P * C,), jnp.int64, s), _sds((P * C,), jnp.float32, s)),
+        _sds((P * C,), jnp.bool_, s),
+        (_sds((M,), jnp.int64, s), _sds((M,), jnp.float32, s)),
+        _sds((P,), jnp.int32, s), _sds((P,), jnp.int32, s),
+        _sds((), jnp.int32, s))
+
+
+# The opt-in "pallas" engine tier has only ever run in interpret mode; the
+# chip's compiler refuses all three kernels as written.  strict xfail: the
+# PR that rewrites or deletes them has to touch these lines.
+@pytest.mark.parametrize("lower", [
+    pytest.param(_lower_slot_build, id="slot_build", marks=pytest.mark.xfail(
+        strict=True, raises=NotImplementedError,
+        reason="Unimplemented primitive in Pallas TPU lowering for "
+               "KernelType.TC: scatter-min")),
+    pytest.param(_lower_slot_probe, id="slot_probe", marks=pytest.mark.xfail(
+        strict=True, raises=ValueError,
+        reason="Only arrays with 32-bit element types can be converted to "
+               "scalars, but got: float64")),
+    pytest.param(_lower_partition_scatter, id="partition_scatter",
+                 marks=pytest.mark.xfail(
+                     strict=True, raises=NotImplementedError,
+                     reason="Unimplemented primitive in Pallas TPU lowering "
+                            "for KernelType.TC: cumsum")),
+])
+def test_pallas_engine_tier_is_refused(one_chip, lower):
+    lower(one_chip).compile()
