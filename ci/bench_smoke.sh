@@ -3,10 +3,10 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 # chip_smoke.py is for the chip: a CPU run of it must fail and say what it found
-if JAX_PLATFORMS=cpu python chip_smoke.py 2> /tmp/chip_smoke_cpu.err; then
+if err=$(JAX_PLATFORMS=cpu python chip_smoke.py 2>&1 >/dev/null); then
   echo "chip_smoke.py passed on the CPU" >&2; exit 1
 fi
-grep -q "platform 'cpu'" /tmp/chip_smoke_cpu.err
+grep -q "platform 'cpu'" <<<"$err"
 BENCH_FORCE_CPU=1 BENCH_N_ROWS=65536 BENCH_REPS=2 python bench.py \
   | tee /tmp/bench_smoke_q6.out
 # plan-IR scenario: q6/q95 plus the IR-only q9 lowered by the whole-plan

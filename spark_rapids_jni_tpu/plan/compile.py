@@ -282,8 +282,8 @@ def _lower_join(node: ir.Join, env, prebuilts, st):
 
 def _lower_aggregate(node: ir.Aggregate, env, prebuilts, st):
     from ..relational import keys as _rk
-    from ..relational.aggregate import (AggSpec, group_by,
-                                        group_by_domain_or_sort,
+    from ..relational.aggregate import (AggSpec, _resolve_groupby_engine,
+                                        group_by, group_by_domain_or_sort,
                                         group_by_onehot)
 
     aggs = [AggSpec(a.op, a.column, a.out_name) for a in node.aggs]
@@ -297,8 +297,9 @@ def _lower_aggregate(node: ir.Aggregate, env, prebuilts, st):
         b, live, pfx = _lower(child.child, env, prebuilts, st)
         key_col = b[node.keys[0]]
         if (_plain_int_key(key_col)
-                and config.get("groupby_engine") == "sort"):
-            # sort-order reuse: the seg radix words ride the regroup
+                and _resolve_groupby_engine(None) == "sort"):
+            # sort-order reuse (pinned "sort", or what "auto" resolves
+            # to off the CPU): the seg radix words ride the regroup
             # sort as secondary operands, so the group-by receives an
             # already-grouped input and skips its own sort
             segkeys = _rk.batch_radix_keys([key_col], equality=True,

@@ -236,6 +236,15 @@ def _resolve_groupby_engine(engine):
     return engine
 
 
+def _resolve_onehot_engine(engine):
+    """The domain engines' ``auto`` (the ``q6_onehot_engine`` knob's
+    default): segment-sum scatter on the CPU, the XLA one-hot contraction
+    on accelerators."""
+    if engine == "auto":
+        return "scatter" if jax.default_backend() == "cpu" else "xla"
+    return engine
+
+
 def group_by(
     batch: ColumnBatch,
     key_names: Sequence[str],
@@ -799,8 +808,7 @@ def _domain_partials(batch, key_name, aggs, domain, row_valid=None,
     # the domain engines run arithmetic on raw key/value buffers: encoded
     # columns materialize here (their late point of need)
     batch = materialize_batch(batch)
-    if engine == "auto":
-        engine = "scatter" if jax.default_backend() == "cpu" else "xla"
+    engine = _resolve_onehot_engine(engine)
     if engine == "scatter":
         return _domain_partials_scatter(batch, key_name, aggs, domain,
                                         row_valid)
@@ -940,10 +948,13 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
         # ever live (a full-width [n, K+1] float one-hot is multi-GB at
         # bench row counts; the f64-emulated contraction of one OOM'd
         # real v5e HBM at 16M rows in round 3).  Static n means static
-        # slices, combined in int64/float64 across chunks.
-        B = 1 << 23
-        kids = jnp.arange(K + 1, dtype=jnp.int32)[None, :]
+        # slices, combined in int64/float64 across chunks.  The f64
+        # contraction is emulated with eight f32 [K+1, B] operands: at
+        # K=100 a 2^23-row block of them is 27.9 GB, which the v5e
+        # compiler refuses; 2^20-row blocks need 6.87 GB at 2^24 rows.
         fdt = jnp.float32 if use_f32x3 else jnp.float64
+        B = 1 << 23 if fdt == jnp.float32 or not float_cols else 1 << 20
+        kids = jnp.arange(K + 1, dtype=jnp.int32)[None, :]
         part = jnp.zeros((K + 1, X8.shape[1]), jnp.int64)
         fpart = (jnp.zeros((K + 1, F.shape[1]), jnp.float64)
                  if float_cols else None)

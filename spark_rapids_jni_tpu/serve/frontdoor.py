@@ -323,6 +323,7 @@ class WorkerHandle:
         self.proc = proc
         self.host = host
         self.token = token  # incarnation identity for hello reattach
+        self.backend: Optional[str] = None  # jax.default_backend(), by hello
         self.link: Optional[wire.Transport] = None
         self.state = "starting"  # starting | healthy | reconnecting | dead
         self.spawned_at = time.monotonic()
@@ -409,10 +410,26 @@ class _AdoptedProc:
             os.kill(self.pid, signal.SIGKILL)
 
 
+def _keep_supervisor_on_host():
+    """The supervisor decodes arrow results into JAX arrays
+    (``ipc_to_batch``), so it starts a backend of its own — and an
+    accelerator belongs to one process, which must be a worker.  If this
+    process has not started a backend yet, hold it to the host CPU.
+    Workers are spawned with ``os.environ``, which this leaves alone."""
+    import jax
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        jax.config.update("jax_platforms", "cpu")
+
+
 class FrontDoor:
     """The supervisor: ``submit(kind, params)`` → session handle pinned
     to a worker process; ``shutdown()`` drains the fleet and returns a
-    per-worker cleanliness report (idempotent)."""
+    per-worker cleanliness report (idempotent).  The process that builds
+    one stays on the host CPU (:func:`_keep_supervisor_on_host`); each
+    worker reports the backend it started in its hello
+    (``WorkerHandle.backend``)."""
 
     def __init__(self, workers: Optional[int] = None,
                  pool_bytes: int = 64 << 20,
@@ -438,6 +455,7 @@ class FrontDoor:
                  adopt_dir: Optional[str] = None,
                  result_cache=None):
         global _last_metrics
+        _keep_supervisor_on_host()
         self._n_workers = int(workers if workers is not None
                               else config.get("serve_workers"))
         hosts_raw = hosts if hosts is not None else config.get("serve_hosts")
@@ -1293,6 +1311,7 @@ class FrontDoor:
                     # resume-token reattach, sessions stay live
                     self.metrics.bump("reconnects")
                 w.ever_connected = True
+                w.backend = hello.get("backend")
                 link.settimeout(0.2)  # reader poll tick (supersession)
                 old, w.link = w.link, link
                 if old is not None:

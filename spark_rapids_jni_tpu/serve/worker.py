@@ -339,9 +339,14 @@ class _SupervisorLink:
     def connect(self):
         """Dial + idempotent hello.  Raises on failure (the ladder in
         :meth:`reconnect` is the retry policy)."""
+        import jax
+
+        # the backend starts before the first dial: a worker that cannot
+        # have the device its environment names dies here, in worker.log,
+        # instead of after it was given a query
+        extra = {"backend": jax.default_backend()}
         t = self._wire.connect(self.kind, self.address, role="wk",
                                timeout_s=2.0)
-        extra = {}
         if self.active_sids_fn is not None:
             with contextlib.suppress(Exception):
                 extra["active_sids"] = sorted(self.active_sids_fn())

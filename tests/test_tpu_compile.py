@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import __graft_entry__ as ge
+from spark_rapids_jni_tpu import config
 from spark_rapids_jni_tpu.ops import pallas_kernels as PK
 
 HBM_BYTES = 15.75 * (1 << 30)  # what the v5e compiler calls its hbm
@@ -56,12 +57,23 @@ def test_onehot_groupby_kernel_compiles(one_chip, mf):
         domain=101, interpret=False).compile()
 
 
-def test_q6_step_compiles_and_fits(one_chip):
+@pytest.mark.parametrize("float_mode", ["f32x3", "f64"])
+def test_q6_step_compiles_and_fits(one_chip, monkeypatch, float_mode):
     n = 1 << 24
     batch = jax.tree_util.tree_map(
         lambda s: _sds(s.shape, s.dtype, one_chip),
         jax.eval_shape(lambda: ge._device_batch(0, n)))
-    mem = jax.jit(ge._q6_step).lower(batch).compile().memory_analysis()
+    # the engines' "auto" asks jax.default_backend(): answer as the chip
+    # does, or this compiles the CPU's scatter branch for the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config.set("q6_float_mode", float_mode)
+    try:
+        # a function of its own: jit keeps one trace per function, and the
+        # knob is read while tracing
+        lowered = jax.jit(lambda b: ge._q6_step(b)).lower(batch)
+    finally:
+        config.reset("q6_float_mode")
+    mem = lowered.compile().memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < HBM_BYTES
 
