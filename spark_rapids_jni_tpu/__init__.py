@@ -47,6 +47,14 @@ if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
             ".jax_cache"))
 
+# The program's named scopes (profiler.scope) are op metadata, which JAX
+# leaves out of the cache key by default: an executable cached by a commit
+# with other scopes (or none) would then be served, and its device trace
+# would carry that commit's names.  With metadata in the key a checkout
+# compiles once after any change to the lowering's names or line numbers
+# (it shows in a first run's set-up only) and a trace always names what ran.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 from . import columnar  # noqa: E402
 from . import ops  # noqa: E402
 from . import relational  # noqa: E402

@@ -45,7 +45,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .. import config
+from .. import config, profiler
 from ..columnar.column import Column, ColumnBatch
 from ..columnar.encoded import PACKED_COLUMNS, is_encoded, \
     packed_filter_mask, predicate_mask
@@ -170,10 +170,13 @@ def _exchange_local(b: ColumnBatch, key: str, live, partitions: int,
     from ..parallel.partition import regroup_order, spark_partition_id
     from ..relational.gather import gather_column
 
-    pid = spark_partition_id([b[key]], partitions, live)
-    order = regroup_order(pid, partitions + 1, secondary=secondary)
-    return ColumnBatch({name: gather_column(col, order)
-                        for name, col in zip(b.names, b.columns)})
+    with profiler.scope("exchange.partition_id"):
+        pid = spark_partition_id([b[key]], partitions, live)
+    with profiler.scope("exchange.regroup"):
+        order = regroup_order(pid, partitions + 1, secondary=secondary)
+    with profiler.scope("exchange.scatter"):
+        return ColumnBatch({name: gather_column(col, order)
+                            for name, col in zip(b.names, b.columns)})
 
 
 def _plain_int_key(col) -> bool:
@@ -194,6 +197,26 @@ class _State:
         self.agg_i = 0
 
 
+def node_scope(node: ir.PlanNode) -> str:
+    """The named scope a node's own operations are lowered under:
+    ``plan.filter.<column>``, ``plan.exchange.<key>``, ``plan.join.<right
+    scan name>``, ``plan.aggregate.<first key>``, ``plan.sort``,
+    ``plan.project``.  A child is lowered before and outside its parent's
+    scope, so a device operation's path starts at the one node it
+    belongs to."""
+    if isinstance(node, ir.Filter):
+        return "plan.filter." + profiler.scope_name(node.column)
+    if isinstance(node, ir.Exchange):
+        return "plan.exchange." + profiler.scope_name(node.key)
+    if isinstance(node, ir.Join):
+        right = node.right.name if isinstance(node.right, ir.Scan) \
+            else node.right_on
+        return "plan.join." + profiler.scope_name(right)
+    if isinstance(node, ir.Aggregate):
+        return "plan.aggregate." + profiler.scope_name(node.keys[0])
+    return "plan." + type(node).__name__.lower()
+
+
 def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
     """Returns ``(batch, live, prefix)``: ``live`` is a bool row mask or
     None (statically all-live); ``prefix`` records that the mask is of
@@ -205,25 +228,29 @@ def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
 
     if isinstance(node, ir.Filter):
         b, live, _pfx = _lower(node.child, env, prebuilts, st)
-        mask = _filter_mask(b[node.column], node.op, node.value)
-        live = mask if live is None else live & mask
+        with profiler.scope(node_scope(node)):
+            mask = _filter_mask(b[node.column], node.op, node.value)
+            live = mask if live is None else live & mask
         return b, live, False
 
     if isinstance(node, ir.Project):
         b, live, pfx = _lower(node.child, env, prebuilts, st)
-        return b.select(list(node.columns)), live, pfx
+        with profiler.scope(node_scope(node)):
+            return b.select(list(node.columns)), live, pfx
 
     if isinstance(node, ir.Exchange):
         b, live, pfx = _lower(node.child, env, prebuilts, st)
-        live_arr = (jnp.ones((b.num_rows,), jnp.bool_) if live is None
-                    else live)
-        staged = _exchange_local(b, node.key, live_arr, node.partitions)
-        if live is None or pfx:
-            return staged, live, pfx
-        n = staged.num_rows
-        new_live = jnp.arange(n, dtype=jnp.int32) < jnp.sum(
-            live.astype(jnp.int32))
-        return staged, new_live, True
+        with profiler.scope(node_scope(node)):
+            live_arr = (jnp.ones((b.num_rows,), jnp.bool_) if live is None
+                        else live)
+            staged = _exchange_local(b, node.key, live_arr,
+                                     node.partitions)
+            if live is None or pfx:
+                return staged, live, pfx
+            n = staged.num_rows
+            new_live = jnp.arange(n, dtype=jnp.int32) < jnp.sum(
+                live.astype(jnp.int32))
+            return staged, new_live, True
 
     if isinstance(node, ir.Sort):
         return _lower_sort(node, env, prebuilts, st)
@@ -243,17 +270,19 @@ def _lower_sort(node: ir.Sort, env, prebuilts, st):
 
     b, live, _pfx = _lower(node.child, env, prebuilts, st)
     keys = [SortKey(k) for k in node.keys]
-    if live is None:
-        return sort_by(b, keys), None, True
-    # dead rows last (same __occ trick as the distributed sort epilogue)
-    aug = b.with_column("__occ", Column(live.astype(jnp.int32),
-                                        jnp.ones_like(live), T.INT32))
-    out = sort_by(aug, [SortKey("__occ", ascending=False)] + keys)
-    n = out.num_rows
-    new_live = jnp.arange(n, dtype=jnp.int32) < jnp.sum(
-        live.astype(jnp.int32))
-    return (out.select([nm for nm in out.names if nm != "__occ"]),
-            new_live, True)
+    with profiler.scope(node_scope(node)):
+        if live is None:
+            return sort_by(b, keys), None, True
+        # dead rows last (same __occ trick as the distributed sort
+        # epilogue)
+        aug = b.with_column("__occ", Column(live.astype(jnp.int32),
+                                            jnp.ones_like(live), T.INT32))
+        out = sort_by(aug, [SortKey("__occ", ascending=False)] + keys)
+        n = out.num_rows
+        new_live = jnp.arange(n, dtype=jnp.int32) < jnp.sum(
+            live.astype(jnp.int32))
+        return (out.select([nm for nm in out.names if nm != "__occ"]),
+                new_live, True)
 
 
 def _lower_join(node: ir.Join, env, prebuilts, st):
@@ -264,27 +293,27 @@ def _lower_join(node: ir.Join, env, prebuilts, st):
     info = st.join_plans[st.join_i]
     st.join_i += 1
 
-    if info["strategy"] == "broadcast":
-        out, cnt = hash_join(
-            b, rb, [node.left_on], [node.right_on], node.how,
-            left_valid=live, right_valid=rlive,
-            prebuilt=prebuilts[info["prebuilt"]], engine=info["engine"])
-    elif info["dense_domain"] is not None:
-        out, cnt = join_dense_or_hash(
-            b, rb, node.left_on, node.right_on, info["dense_domain"],
-            node.how, left_valid=live, right_valid=rlive)
-    else:
-        out, cnt = hash_join(b, rb, [node.left_on], [node.right_on],
-                             node.how, left_valid=live, right_valid=rlive)
-    new_live = jnp.arange(out.num_rows, dtype=jnp.int32) < cnt
+    with profiler.scope(node_scope(node)):
+        if info["strategy"] == "broadcast":
+            out, cnt = hash_join(
+                b, rb, [node.left_on], [node.right_on], node.how,
+                left_valid=live, right_valid=rlive,
+                prebuilt=prebuilts[info["prebuilt"]],
+                engine=info["engine"])
+        elif info["dense_domain"] is not None:
+            out, cnt = join_dense_or_hash(
+                b, rb, node.left_on, node.right_on, info["dense_domain"],
+                node.how, left_valid=live, right_valid=rlive)
+        else:
+            out, cnt = hash_join(b, rb, [node.left_on], [node.right_on],
+                                 node.how, left_valid=live,
+                                 right_valid=rlive)
+        new_live = jnp.arange(out.num_rows, dtype=jnp.int32) < cnt
     return out, new_live, True
 
 
 def _lower_aggregate(node: ir.Aggregate, env, prebuilts, st):
-    from ..relational import keys as _rk
-    from ..relational.aggregate import (AggSpec, _resolve_groupby_engine,
-                                        group_by, group_by_domain_or_sort,
-                                        group_by_onehot)
+    from ..relational.aggregate import AggSpec
 
     aggs = [AggSpec(a.op, a.column, a.out_name) for a in node.aggs]
     hint = st.agg_hints[st.agg_i]
@@ -293,8 +322,22 @@ def _lower_aggregate(node: ir.Aggregate, env, prebuilts, st):
     child = node.child
     fuse = (isinstance(child, ir.Exchange) and len(node.keys) == 1
             and child.key == node.keys[0])
-    if fuse:
-        b, live, pfx = _lower(child.child, env, prebuilts, st)
+    # a fused Exchange is lowered here, under the aggregate's scope (as
+    # the regroup that orders its rows, or not at all)
+    b, live, pfx = _lower(child.child if fuse else child, env, prebuilts,
+                          st)
+    with profiler.scope(node_scope(node)):
+        return _aggregate(node, aggs, hint, child if fuse else None,
+                          b, live, pfx)
+
+
+def _aggregate(node: ir.Aggregate, aggs, hint, fused, b, live, pfx):
+    from ..relational import keys as _rk
+    from ..relational.aggregate import (_resolve_groupby_engine, group_by,
+                                        group_by_domain_or_sort,
+                                        group_by_onehot)
+
+    if fused is not None:
         key_col = b[node.keys[0]]
         if (_plain_int_key(key_col)
                 and _resolve_groupby_engine(None) == "sort"):
@@ -306,8 +349,8 @@ def _lower_aggregate(node: ir.Aggregate, env, prebuilts, st):
                                            nulls_first=True)
             live_arr = (jnp.ones((b.num_rows,), jnp.bool_) if live is None
                         else live)
-            staged = _exchange_local(b, child.key, live_arr,
-                                     child.partitions, secondary=segkeys)
+            staged = _exchange_local(b, fused.key, live_arr,
+                                     fused.partitions, secondary=segkeys)
             if live is not None and not pfx:
                 live = jnp.arange(staged.num_rows, dtype=jnp.int32) < \
                     jnp.sum(live.astype(jnp.int32))
@@ -316,8 +359,6 @@ def _lower_aggregate(node: ir.Aggregate, env, prebuilts, st):
             return res, ng, True
         # scatter/auto engines and encoded keys: the single-chip
         # exchange feeds a complete local aggregation — elide it
-    else:
-        b, live, _pfx = _lower(child, env, prebuilts, st)
 
     key_col = b[node.keys[0]] if len(node.keys) == 1 else None
     domain_ok = (node.domain is not None and key_col is not None
@@ -365,18 +406,21 @@ class CompiledPlan:
     def __call__(self, inputs: dict):
         from ..mem.executor import run_with_retry
 
-        missing = [n for n in self.input_names if n not in inputs]
-        if missing:
-            raise KeyError(f"plan inputs missing: {missing}")
-        env = {n: inputs[n] for n in self.input_names}
-        prebuilts = []
-        for h in self.build_handles:
-            # pin across get(): an evictor may not drop the table while
-            # the fetch is in flight; the returned arrays keep their
-            # buffers alive on their own afterwards
-            with h.pinned():
-                prebuilts.append(tuple(run_with_retry(h.get)))
-        return self.fn(env, tuple(prebuilts))
+        # one span per launch of the compiled program: its count in
+        # profiler.stage_totals() is the "plan.dispatches" counter
+        with profiler.span("plan.dispatch"):
+            missing = [n for n in self.input_names if n not in inputs]
+            if missing:
+                raise KeyError(f"plan inputs missing: {missing}")
+            env = {n: inputs[n] for n in self.input_names}
+            prebuilts = []
+            for h in self.build_handles:
+                # pin across get(): an evictor may not drop the table
+                # while the fetch is in flight; the returned arrays keep
+                # their buffers alive on their own afterwards
+                with profiler.span("plan.prebuilt_fetch"), h.pinned():
+                    prebuilts.append(tuple(run_with_retry(h.get)))
+            return self.fn(env, tuple(prebuilts))
 
     def close(self):
         for h in self.build_handles:
@@ -463,34 +507,41 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
     ShuffleRegistry's recorded metrics — Spark's AQE loop: earlier
     exchanges' observed skew/rows inform later plans with no caller
     plumbing."""
-    if stats is None:
-        stats = _default_stats()
-    decisions = adaptive.plan_decisions(plan, inputs, stats)
-    key = plan_cache_key(plan, inputs, decisions)
-    cache = get_plan_cache()
-    cached = cache.get(key)
-    if cached is not None:
-        cached.last_lookup = "hit"
-        return cached
+    with profiler.span("plan.lookup"):
+        with profiler.span("plan.decisions"):
+            if stats is None:
+                stats = _default_stats()
+            decisions = adaptive.plan_decisions(plan, inputs, stats)
+        with profiler.span("plan.key"):
+            key = plan_cache_key(plan, inputs, decisions)
+        cache = get_plan_cache()
+        cached = cache.get(key)
+        if cached is not None:
+            cached.last_lookup = "hit"
+            return cached
 
-    join_plans, agg_hints, handles = _resolve_join_plans(
-        plan, inputs, decisions, ctx)
-    input_names = ir.scan_names(plan)
+        join_plans, agg_hints, handles = _resolve_join_plans(
+            plan, inputs, decisions, ctx)
+        input_names = ir.scan_names(plan)
 
-    def run(env, prebuilts):
-        _TRACE_COUNT[0] += 1
-        st = _State(join_plans, agg_hints)
-        out = _lower(plan, env, prebuilts, st)
-        if isinstance(plan, ir.Aggregate):
-            res, ng, _pfx = out
-            return res, ng
-        batch, live, _pfx = out
-        return batch if live is None else (batch, live)
+        def run(env, prebuilts):
+            # the Python lowering: runs only when jit traces, so this
+            # span appears on a cache miss (or a retrace) and never on
+            # a hit
+            with profiler.span("plan.trace"):
+                _TRACE_COUNT[0] += 1
+                st = _State(join_plans, agg_hints)
+                out = _lower(plan, env, prebuilts, st)
+                if isinstance(plan, ir.Aggregate):
+                    res, ng, _pfx = out
+                    return res, ng
+                batch, live, _pfx = out
+                return batch if live is None else (batch, live)
 
-    compiled = CompiledPlan(plan, key, jax.jit(run), input_names, handles,
-                            decisions)
-    cache.put(key, compiled)
-    return compiled
+        compiled = CompiledPlan(plan, key, jax.jit(run), input_names,
+                                handles, decisions)
+        cache.put(key, compiled)
+        return compiled
 
 
 def _maybe_execute_streaming(plan: ir.PlanNode, inputs: dict, ctx=None):

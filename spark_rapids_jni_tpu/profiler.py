@@ -12,8 +12,15 @@ captures into JSON offline).  The TPU equivalent wraps the XLA profiler
   profiles to distributed storage exactly like the reference's
   ``DataWriter`` path.
 * :func:`convert_profile` — the offline converter: reads a captured
-  stream back into per-event records (kernel/op name, start, duration),
-  decoding the Chrome-trace JSON the XLA profiler produces.
+  stream back into per-event records (kernel/op name, start, duration,
+  the program's ``scope`` on device operations, the query's ``sid`` on
+  host spans); :func:`device_time_by_scope` adds those up.
+* :func:`span` / :func:`note` / :func:`scope` — the one tracer inside the
+  program.  A span is a host interval under a query id: always recorded in
+  a bounded per-process ring (:func:`spans`, :func:`stage_totals`) and,
+  while a profiler session is on, written into the trace with its ``sid``
+  as a stat.  A scope names the device operations lowered inside it
+  (``jax.named_scope``): op metadata, free at run time.
 
 Frame format: ``b"SPTPUPRF" u32(version) [u32(len) bytes]*`` — the same
 size-prefixed-records idea as ``profiler.fbs`` (``ProfileHeader`` magic +
@@ -25,16 +32,22 @@ capture explains its own anomalies.
 
 from __future__ import annotations
 
+import collections
 import glob
 import gzip
 import io
 import json
 import os
+import re
 import shutil
 import struct
 import tempfile
 import threading
-from typing import Callable, List, Optional
+import time
+from typing import Callable, Dict, List, Optional
+
+import jax
+from jax.profiler import TraceAnnotation
 
 MAGIC = b"SPTPUPRF"
 VERSION = 1
@@ -68,8 +81,6 @@ class Profiler:
     @classmethod
     def start(cls):
         """Begin collecting (cuProfilerStart equivalent)."""
-        import jax
-
         with cls._lock:
             if not cls._initialized:
                 raise ProfilerError("profiler not initialized")
@@ -81,8 +92,6 @@ class Profiler:
     @classmethod
     def stop(cls):
         """Stop collecting and flush the capture to the writer."""
-        import jax
-
         with cls._lock:
             if not cls._initialized or not cls._running:
                 return
@@ -97,8 +106,6 @@ class Profiler:
             if not cls._initialized:
                 return
             if cls._running:
-                import jax
-
                 jax.profiler.stop_trace()
                 cls._running = False
                 cls._flush_locked()
@@ -198,14 +205,139 @@ def fleet_summary() -> dict:
     return fleet_metrics()
 
 
-def trace_range(name: str):
-    """Named range in the captured trace — the NVTX-range analogue
-    (reference compiles nvtx3 ranges into kernels for nsys, SURVEY §5);
-    here ``with trace_range("stage"):`` annotates the XLA trace so the
-    converter's events carry pipeline-stage names."""
-    import jax
+# ---------------------------------------------------------------------------
+# the tracer: host spans under a query id, device scopes
+# ---------------------------------------------------------------------------
 
-    return jax.profiler.TraceAnnotation(name)
+RING_SPANS = 4096
+
+Span = collections.namedtuple("Span", "name sid parent t0_ns t1_ns")
+
+_ring: collections.deque = collections.deque(maxlen=RING_SPANS)
+_totals: Dict[str, list] = {}   # name -> [count, sum_ns, max_ns]
+_ring_lock = threading.Lock()
+_tls = threading.local()
+
+
+def _record(name, sid, parent, t0_ns, t1_ns):
+    dur = t1_ns - t0_ns
+    with _ring_lock:
+        _ring.append((name, sid, parent, t0_ns, t1_ns))
+        t = _totals.get(name)
+        if t is None:
+            _totals[name] = [1, dur, dur]
+        else:
+            t[0] += 1
+            t[1] += dur
+            if dur > t[2]:
+                t[2] = dur
+
+
+class span:
+    """``with span("plan.dispatch", sid=7, rec="x") as sp:`` — one host
+    interval of the program, the NVTX-range analogue (reference compiles
+    nvtx3 ranges into kernels for nsys, SURVEY §5).
+
+    The interval is read off ``time.perf_counter_ns()`` and appended to
+    the process's ring as ``(name, sid, parent, t0_ns, t1_ns)``; ``parent``
+    is the name of the span open around it on this thread, and a span
+    without a ``sid`` takes its parent's, so everything a query runs nests
+    under the id its outermost span was given.  The same interval is a
+    ``TraceAnnotation`` carrying ``sid`` and ``attrs`` as stats, which the
+    XLA profiler records only while a session is on.  After exit ``sp.ms``
+    is the duration, and ``sink(name, ms)`` has been called with it if one
+    was given (a session adding the stage to its timeline)."""
+
+    __slots__ = ("name", "sid", "parent", "t0_ns", "t1_ns", "_attrs",
+                 "_ann", "_sink")
+
+    def __init__(self, name: str, sid=None, sink=None, **attrs):
+        self.name = name
+        self.sid = sid
+        self.parent = None
+        self._attrs = attrs
+        self._sink = sink
+
+    def __enter__(self):
+        try:
+            stack = _tls.stack
+        except AttributeError:
+            stack = _tls.stack = []
+        if stack:
+            top = stack[-1]
+            self.parent = top.name
+            if self.sid is None:
+                self.sid = top.sid
+        stack.append(self)
+        attrs = self._attrs
+        if self.sid is not None:
+            attrs = dict(attrs, sid=self.sid)
+        self._ann = TraceAnnotation(self.name, **attrs)
+        self._ann.__enter__()
+        self.t0_ns = self.t1_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1_ns = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        _tls.stack.pop()
+        _record(self.name, self.sid, self.parent, self.t0_ns, self.t1_ns)
+        if self._sink is not None:
+            self._sink(self.name, self.ms)
+        return False
+
+    @property
+    def ms(self) -> float:
+        return (self.t1_ns - self.t0_ns) / 1e6
+
+
+def note(name: str, sid, t0_ns: int, t1_ns: int) -> float:
+    """Record an interval no ``with`` can bracket (a queue wait that starts
+    in one thread and ends in another), stamped by the caller with
+    ``time.perf_counter_ns()``.  Ring and totals only: a finished interval
+    cannot be written into a running trace.  Returns its milliseconds."""
+    _record(name, sid, None, int(t0_ns), int(t1_ns))
+    return (t1_ns - t0_ns) / 1e6
+
+
+def spans(since_ns: int = 0) -> List[Span]:
+    """Copies of the ring's spans that started at or after ``since_ns``
+    (``time.perf_counter_ns()``), oldest first.  The ring keeps the last
+    ``RING_SPANS``."""
+    with _ring_lock:
+        rows = list(_ring)
+    return [Span(*r) for r in rows if r[3] >= since_ns]
+
+
+def stage_totals() -> Dict[str, dict]:
+    """``{name: {count, sum_ms, max_ms}}`` over every span and note since
+    the process started (not only those still in the ring).
+    ``stage_totals()["plan.dispatch"]["count"]`` is the number of compiled
+    plans launched."""
+    with _ring_lock:
+        return {n: {"count": c, "sum_ms": s / 1e6, "max_ms": m / 1e6}
+                for n, (c, s, m) in _totals.items()}
+
+
+_SCOPE_NAME = re.compile(r"^[A-Za-z0-9_]+(\.[A-Za-z0-9_]+)+$")
+
+
+def scope(name: str):
+    """``with scope("join.dense_probe"):`` names the device operations
+    lowered inside it.  Names are ``<layer>.<what>`` (letters, digits,
+    ``_``, ``.``): the dot is how :func:`device_time_by_scope` tells the
+    program's scopes from JAX's own path components."""
+    if not _SCOPE_NAME.match(name):
+        raise ValueError(f"scope name {name!r}: want <layer>.<what> of "
+                         "letters, digits, '_' and '.'")
+    return jax.named_scope(name)
+
+
+def scope_name(text) -> str:
+    """A string made fit for a scope name's component (a column or table
+    name from a plan): anything but letters, digits and ``_`` becomes
+    ``_``."""
+    return re.sub(r"[^A-Za-z0-9_]", "_", str(text)) or "_"
 
 
 class FileWriter:
@@ -239,20 +371,27 @@ def _iter_frames(data: bytes):
 
 
 # ---------------------------------------------------------------------------
-# xplane.pb decoding (minimal protobuf wire reader; no tensorflow needed)
+# xplane.pb decoding
 # ---------------------------------------------------------------------------
+# Events (name, start, duration, their own stats: a span's ``sid``) are read
+# with ``jax.profiler.ProfileData``.  What it does not expose is the stats of
+# an event's METADATA, and that is where the TPU runtime puts what the
+# compiler knew of a device operation: ``tf_op`` (the JAX op name with its
+# named-scope path, ``jit(run)/plan.join.dim1/join.dense_probe/gather:``).
+# So one minimal protobuf wire reader stays, for the metadata tables alone.
 # Field numbers from tsl/profiler/protobuf/xplane.proto:
 #   XSpace   { repeated XPlane planes = 1; }
-#   XPlane   { int64 id=1; string name=2; repeated XLine lines=3;
-#              map<int64, XEventMetadata> event_metadata=4; }
-#   XLine    { int64 id=1; string name=2; int64 timestamp_ns=3;
-#              repeated XEvent events=4; string display_name=11; }
-#   XEvent   { int64 metadata_id=1; int64 offset_ps=2;
-#              int64 duration_ps=3; }
-#   XEventMetadata { int64 id=1; string name=2; }
-# The device planes ("/device:TPU:0 ...") carry per-kernel events — the
-# role of the reference's CUPTI activity records
-# (profiler_serializer.cpp:222-280).
+#   XPlane   { string name=2; repeated XLine lines=3;
+#              map<int64, XEventMetadata> event_metadata=4;
+#              map<int64, XStatMetadata> stat_metadata=5; }
+#   XEventMetadata { int64 id=1; string name=2; repeated XStat stats=5; }
+#   XStatMetadata  { int64 id=1; string name=2; }
+#   XStat    { int64 metadata_id=1; string str_value=5; uint64 ref_value=7; }
+# The device planes ("/device:TPU:0") carry per-kernel events — the role of
+# the reference's CUPTI activity records (profiler_serializer.cpp:222-280).
+
+OPS_LINE = "XLA Ops"
+NO_SCOPE = "(none)"
 
 
 def _pb_fields(buf: bytes):
@@ -303,59 +442,117 @@ def _pb_fields(buf: bytes):
             raise ProfilerError(f"unsupported protobuf wire type {wt}")
 
 
-def _decode_xspace(payload: bytes) -> List[dict]:
-    """XSpace bytes -> flat event records (plane/line/kernel name/us)."""
-    events: List[dict] = []
-    for f, wt, plane_buf in _pb_fields(payload):
+def _op_names(payload: bytes) -> Dict[str, Dict[str, str]]:
+    """XSpace bytes -> ``{plane: {event name: tf_op}}`` from the planes'
+    event-metadata stats."""
+    out: Dict[str, Dict[str, str]] = {}
+    for f, wt, plane in _pb_fields(payload):
         if f != 1 or wt != 2:
             continue
-        plane_name = ""
-        meta_names = {}
-        lines = []
-        for pf, pwt, pv in _pb_fields(plane_buf):
-            if pf == 2 and pwt == 2:
-                plane_name = pv.decode("utf-8", "replace")
-            elif pf == 3 and pwt == 2:
-                lines.append(pv)
-            elif pf == 4 and pwt == 2:
-                # map entry { int64 key=1; XEventMetadata value=2; }
-                mid, mname = 0, ""
+        pname, stat_names, metas = "", {}, []
+        for pf, pwt, pv in _pb_fields(plane):
+            if pwt != 2:
+                continue
+            if pf == 2:
+                pname = pv.decode("utf-8", "replace")
+            elif pf == 4:
+                metas.append(pv)
+            elif pf == 5:
+                # map entry { int64 key=1; XStatMetadata value=2; }
                 for mf, mwt, mv in _pb_fields(pv):
-                    if mf == 1 and mwt == 0:
-                        mid = mv
-                    elif mf == 2 and mwt == 2:
-                        for ef, ewt, ev in _pb_fields(mv):
-                            if ef == 2 and ewt == 2:
-                                mname = ev.decode("utf-8", "replace")
-                meta_names[mid] = mname
-        for line_buf in lines:
-            line_name = ""
-            ts_ns = 0
-            evs = []
-            for lf, lwt, lv in _pb_fields(line_buf):
-                if lf == 2 and lwt == 2:
-                    line_name = lv.decode("utf-8", "replace")
-                elif lf == 3 and lwt == 0:
-                    ts_ns = lv
-                elif lf == 4 and lwt == 2:
-                    evs.append(lv)
-            for ev_buf in evs:
-                mid = off_ps = dur_ps = 0
-                for ef, ewt, ev in _pb_fields(ev_buf):
-                    if ef == 1 and ewt == 0:
-                        mid = ev
-                    elif ef == 2 and ewt == 0:
-                        off_ps = ev
-                    elif ef == 3 and ewt == 0:
-                        dur_ps = ev
-                events.append({
-                    "name": meta_names.get(mid, f"event:{mid}"),
-                    "ts_us": ts_ns / 1e3 + off_ps / 1e6,
-                    "dur_us": dur_ps / 1e6,
-                    "plane": plane_name,
-                    "line": line_name,
-                })
+                    if mf == 2 and mwt == 2:
+                        fields = {sf: sv for sf, _w, sv in _pb_fields(mv)}
+                        stat_names[fields.get(1, 0)] = \
+                            fields.get(2, b"").decode("utf-8", "replace")
+        tf_op = {i for i, n in stat_names.items() if n == "tf_op"}
+        if not tf_op:
+            continue
+        names = {}
+        for entry in metas:
+            for mf, mwt, mv in _pb_fields(entry):
+                if mf != 2 or mwt != 2:
+                    continue
+                ename, op = "", None
+                for ef, ewt, ev in _pb_fields(mv):
+                    if ef == 2 and ewt == 2:
+                        ename = ev.decode("utf-8", "replace")
+                    elif ef == 5 and ewt == 2:
+                        st = {xf: xv for xf, _w, xv in _pb_fields(ev)}
+                        if st.get(1) in tf_op:
+                            op = (st[5].decode("utf-8", "replace")
+                                  if 5 in st else stat_names.get(st.get(7)))
+                if op is not None:
+                    names[ename] = op
+        if names:
+            out[pname] = names
+    return out
+
+
+def scope_path(op_name: str) -> str:
+    """The program's scopes in a JAX op name, outermost first, joined by
+    ``/``: ``jit(run)/plan.join.dim1/join.dense_probe/gather:`` ->
+    ``plan.join.dim1/join.dense_probe``; ``""`` when it has none."""
+    return "/".join(c for c in re.split(r"[/:]", op_name or "")
+                    if _SCOPE_NAME.match(c))
+
+
+def convert_xplane(payload: bytes) -> List[dict]:
+    """One ``.xplane.pb``'s bytes -> flat event records ``{name, ts_us,
+    dur_us, plane, line}``; a host span also carries its ``sid``, and every
+    operation of a device plane's ``XLA Ops`` line its ``scope``
+    (:func:`scope_path` of the op name; ``""`` for an operation lowered
+    outside every scope)."""
+    from jax.profiler import ProfileData
+
+    op_names = _op_names(payload)
+    events: List[dict] = []
+    for plane in ProfileData.from_serialized_xspace(payload).planes:
+        ops = op_names.get(plane.name, {})
+        device = plane.name.startswith("/device:")
+        for line in plane.lines:
+            scoped = device and line.name == OPS_LINE
+            for ev in line.events:
+                rec = {"name": ev.name, "ts_us": ev.start_ns / 1e3,
+                       "dur_us": ev.duration_ns / 1e3,
+                       "plane": plane.name, "line": line.name}
+                if scoped:
+                    rec["scope"] = scope_path(ops.get(ev.name, ""))
+                elif not device:
+                    for k, v in ev.stats:
+                        if k == "sid":
+                            rec["sid"] = int(v) if str(v).isdigit() else v
+                events.append(rec)
     return events
+
+
+def device_time_by_scope(events: List[dict], depth: int = 2
+                         ) -> Dict[str, float]:
+    """Device seconds by scope prefix (the first ``depth`` scopes of each
+    operation's path), over the records of :func:`convert_profile` that
+    carry a ``scope``.  Self-time: an operation that encloses others on
+    its plane (a ``while`` and its body) counts without them.  Operations
+    outside every scope add up under ``"(none)"``."""
+    out: Dict[str, float] = {}
+    by_plane: Dict[str, list] = {}
+    for e in events:
+        if "scope" in e:
+            by_plane.setdefault(e["plane"], []).append(e)
+    for evs in by_plane.values():
+        stack: list = []   # [key, end_us, child_us, dur_us]
+
+        def close(upto):
+            while stack and stack[-1][1] <= upto:
+                key, _end, child, dur = stack.pop()
+                out[key] = out.get(key, 0.0) + max(dur - child, 0.0) / 1e6
+                if stack:
+                    stack[-1][2] += dur
+
+        for e in sorted(evs, key=lambda e: (e["ts_us"], -e["dur_us"])):
+            close(e["ts_us"])
+            key = "/".join(e["scope"].split("/")[:depth]) or NO_SCOPE
+            stack.append([key, e["ts_us"] + e["dur_us"], 0.0, e["dur_us"]])
+        close(float("inf"))
+    return out
 
 
 def convert_profile(capture_path: str) -> List[dict]:
@@ -366,9 +563,11 @@ def convert_profile(capture_path: str) -> List[dict]:
 
     * ``*.trace.json.gz`` Chrome-trace -> {"name", "ts_us", "dur_us",
       "tid", "pid"} records;
-    * ``*.xplane.pb`` XSpace protos -> {"name", "ts_us", "dur_us",
-      "plane", "line"} records, where device planes carry the per-kernel
-      activity (the reference's CUPTI record role);
+    * ``*.xplane.pb`` XSpace protos -> :func:`convert_xplane` records
+      ({"name", "ts_us", "dur_us", "plane", "line"}, ``sid`` on the
+      program's host spans, ``scope`` on device operations), where device
+      planes carry the per-kernel activity (the reference's CUPTI record
+      role);
     * the synthetic ``faultinj.fired.json`` frame -> one
       ``faultinj:<kind>@<boundary>`` record per injection that fired in
       the window, carrying the injector's (seq, occurrence) clock.
@@ -393,7 +592,7 @@ def convert_profile(capture_path: str) -> List[dict]:
                         }
                     )
         elif name.endswith(".xplane.pb"):
-            events.extend(_decode_xspace(payload))
+            events.extend(convert_xplane(payload))
         elif name == "faultinj.fired.json":
             for e in json.loads(payload):
                 events.append({

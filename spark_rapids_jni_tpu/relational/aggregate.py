@@ -68,6 +68,7 @@ from ..columnar.encoded import (
     materialize_batch,
     materialize_column,
 )
+from ..profiler import scope
 from . import keys as K
 from .gather import gather_column
 
@@ -303,13 +304,15 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
     n = batch.num_rows
     batch = _materialize_agg_values(batch, aggs)
     key_cols = _canon_keys([batch[k] for k in key_names])
-    karr = K.batch_radix_keys(key_cols, equality=True, nulls_first=True)
     have_rv = row_valid is not None
-    if have_rv:
-        occ = row_valid.astype(jnp.bool_)
-        karr = [jnp.where(occ, jnp.uint32(0), jnp.uint32(1))] + [
-            jnp.where(occ, k, jnp.zeros((), k.dtype)) for k in karr
-        ]
+    with scope("agg.sortscan_keys"):
+        karr = K.batch_radix_keys(key_cols, equality=True,
+                                  nulls_first=True)
+        if have_rv:
+            occ = row_valid.astype(jnp.bool_)
+            karr = [jnp.where(occ, jnp.uint32(0), jnp.uint32(1))] + [
+                jnp.where(occ, k, jnp.zeros((), k.dtype)) for k in karr
+            ]
     iota = jnp.arange(n, dtype=jnp.int32)
 
     agg_cols = []
@@ -357,28 +360,34 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
         sperm = iota
         spay = tuple(payload[1:])
     else:
-        res = jax.lax.sort(tuple(karr) + tuple(payload), num_keys=nk,
-                           is_stable=True)
+        with scope("agg.sortscan_sort"):
+            res = jax.lax.sort(tuple(karr) + tuple(payload), num_keys=nk,
+                               is_stable=True)
         skeys = res[:nk]
         sperm = res[nk]
         spay = res[nk + 1:]
 
-    boundary = ~K.rows_equal_adjacent(skeys)
-    sorted_occ = (skeys[0] == 0) if have_rv else jnp.ones((n,), jnp.bool_)
-    num_groups = (boundary & sorted_occ).sum(dtype=jnp.int32)
+    with scope("agg.sortscan_boundary"):
+        boundary = ~K.rows_equal_adjacent(skeys)
+        sorted_occ = (skeys[0] == 0) if have_rv \
+            else jnp.ones((n,), jnp.bool_)
+        num_groups = (boundary & sorted_occ).sum(dtype=jnp.int32)
 
-    # last row of each live group: next row starts a new group / is dead /
-    # doesn't exist
-    nxt_boundary = jnp.concatenate(
-        [boundary[1:], jnp.ones((1,), jnp.bool_)])
-    nxt_occ = jnp.concatenate([sorted_occ[1:], jnp.zeros((1,), jnp.bool_)])
-    is_end = sorted_occ & (nxt_boundary | ~nxt_occ)
-    # compact end positions to the front (2-operand flag sort, no scatter)
-    ends = jax.lax.sort(
-        ((~is_end).astype(jnp.uint32), iota), num_keys=1, is_stable=True
-    )[1]
-    prev_ends = jnp.roll(ends, 1)
-    out_valid = iota < num_groups
+        # last row of each live group: next row starts a new group / is
+        # dead / doesn't exist
+        nxt_boundary = jnp.concatenate(
+            [boundary[1:], jnp.ones((1,), jnp.bool_)])
+        nxt_occ = jnp.concatenate(
+            [sorted_occ[1:], jnp.zeros((1,), jnp.bool_)])
+        is_end = sorted_occ & (nxt_boundary | ~nxt_occ)
+        # compact end positions to the front (2-operand flag sort, no
+        # scatter)
+        ends = jax.lax.sort(
+            ((~is_end).astype(jnp.uint32), iota), num_keys=1,
+            is_stable=True
+        )[1]
+        prev_ends = jnp.roll(ends, 1)
+        out_valid = iota < num_groups
 
     def at_ends_diff(cs):
         """Per-group total from a prefix scan: cs[end_g] - cs[end_{g-1}]."""
@@ -387,134 +396,138 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                        jnp.take(cs, prev_ends))
         return ce - cp
 
-    out = {}
-    starts = jnp.where(iota == 0, 0, prev_ends + 1)
-    rows0 = jnp.take(sperm, jnp.clip(starts, 0, n - 1))
-    for name in key_names:
-        out[name] = gather_column(batch[name], rows0, out_valid)
+    with scope("agg.sortscan_reduce"):
+        out = {}
+        starts = jnp.where(iota == 0, 0, prev_ends + 1)
+        rows0 = jnp.take(sperm, jnp.clip(starts, 0, n - 1))
+        for name in key_names:
+            out[name] = gather_column(batch[name], rows0, out_valid)
 
-    def sorted_valid(name):
-        return jnp.take(batch[name].validity, sperm) & sorted_occ
+        def sorted_valid(name):
+            return jnp.take(batch[name].validity, sperm) & sorted_occ
 
-    def sorted_col(name):
-        if ride and name in spans:
-            off = spans[name]
-            data = spay[off - 1]  # payload[0] is iota (== sperm)
-            valid = spay[off] & sorted_occ
-            return data, valid
-        col = batch[name]
-        return jnp.take(col.data, sperm), sorted_valid(name)
+        def sorted_col(name):
+            if ride and name in spans:
+                off = spans[name]
+                data = spay[off - 1]  # payload[0] is iota (== sperm)
+                valid = spay[off] & sorted_occ
+                return data, valid
+            col = batch[name]
+            return jnp.take(col.data, sperm), sorted_valid(name)
 
-    for spec in aggs:
-        if spec.op == "count":
-            if spec.column is None:
-                ones = sorted_occ.astype(jnp.int64)
-            else:
-                ones = sorted_valid(spec.column).astype(jnp.int64)
-            out[spec.out_name] = Column(at_ends_diff(jnp.cumsum(ones)),
-                                        out_valid, T.INT64)
-            continue
-
-        if isinstance(batch[spec.column], Decimal128Column):
-            # Decimal128 aggregation over sorted runs.  sum/mean: exact
-            # 256-bit segmented sums (values sign-extend to uint32[n,8]; a
-            # 2^31-row group of |v|<2^127 stays < 2^158, never wraps) —
-            # sum gets Spark's decimal(min(38, p+10), s) with overflow ->
-            # null, mean divides by the count per Average's bounded(p+4,
-            # s+4) HALF_UP.  min/max: signed-128 segmented scans on the
-            # raw limb pairs.  (Non-ANSI nullOnOverflow; reference
-            # DecimalUtils ops are per-element — group aggregation lives
-            # above cudf in the plugin, so semantics follow Spark's
-            # aggregate expressions.)
-            from ..ops import decimal as D
-
-            dcol = batch[spec.column]
-            svalid = sorted_valid(spec.column)
-            slimbs = jnp.take(dcol.limbs, sperm, axis=0)
-            nn_d = at_ends_diff(jnp.cumsum(svalid.astype(jnp.int32)))
-            has_any_d = out_valid & (nn_d > 0)
-            if spec.op in ("min", "max"):
-                if spec.op == "min":  # fill nulls with +max signed 128
-                    flo = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-                    fhi = jnp.uint64(0x7FFFFFFFFFFFFFFF)
-                else:                 # fill with -min signed 128
-                    flo = jnp.uint64(0)
-                    fhi = jnp.uint64(0x8000000000000000)
-                lo = jnp.where(svalid, slimbs[:, 0], flo)
-                hi = jnp.where(svalid, slimbs[:, 1], fhi)
-                rlo, rhi = _seg_scan_minmax128(lo, hi, boundary, spec.op)
-                out[spec.out_name] = Decimal128Column(
-                    jnp.stack([jnp.take(rlo, ends),
-                               jnp.take(rhi, ends)], axis=1),
-                    has_any_d, dcol.dtype)
-                continue
-            u = D._from_i128(slimbs)
-            u = jnp.where(svalid[:, None], u, jnp.zeros((), jnp.uint32))
-            run = _seg_scan_sum256(u, boundary)
-            s256 = jnp.take(run, ends, axis=0)
-            if spec.op == "mean":
-                limbs128, ok, out_t = _decimal_avg(s256, nn_d, dcol.dtype)
-                out[spec.out_name] = Decimal128Column(
-                    limbs128, has_any_d & ok, out_t)
-                continue
-            out_p = min(38, dcol.dtype.precision + 10)
-            mag, _ = D._abs(s256)
-            overflow = ~D._lt_u(mag, jnp.broadcast_to(D._pow10(out_p),
-                                                      mag.shape))
-            out[spec.out_name] = Decimal128Column(
-                D._to_i128(s256), has_any_d & ~overflow,
-                T.SparkType.decimal(out_p, dcol.dtype.scale))
-            continue
-
-        data, valid = sorted_col(spec.column)
-        col_dtype = batch[spec.column].dtype
-        nn = at_ends_diff(jnp.cumsum(valid.astype(jnp.int32)))
-        has_any = nn > 0
-
-        if spec.op in ("sum", "mean"):
-            out_t = T.FLOAT64 if spec.op == "mean" else _sum_dtype(col_dtype)
-            acc = data.astype(out_t.jnp_dtype if spec.op == "sum"
-                              else jnp.float64)
-            acc = jnp.where(valid, acc, jnp.zeros((), acc.dtype))
-            if jnp.issubdtype(acc.dtype, jnp.floating):
-                s = jnp.take(_seg_scan_sum(acc, boundary), ends)
-            else:
-                s = at_ends_diff(jnp.cumsum(acc))  # exact mod-2^64
-            if spec.op == "mean":
-                s = s / jnp.maximum(nn, 1).astype(jnp.float64)
-            out[spec.out_name] = Column(s, out_valid & has_any, out_t)
-        else:  # min / max — Spark float semantics: NaN greatest, one NaN
-            is_float = jnp.issubdtype(data.dtype, jnp.floating)
-            was_bool = data.dtype == jnp.bool_
-            if is_float:
-                fill = jnp.array(jnp.inf if spec.op == "min" else -jnp.inf,
-                                 data.dtype)
-                nan_in = valid & jnp.isnan(data)
-                valid_num = valid & ~jnp.isnan(data)
-            elif was_bool:
-                data = data.astype(jnp.uint8)
-                fill = jnp.uint8(1 if spec.op == "min" else 0)
-                valid_num = valid
-            else:
-                info = jnp.iinfo(data.dtype)
-                fill = jnp.array(info.max if spec.op == "min" else info.min,
-                                 data.dtype)
-                valid_num = valid
-            masked = jnp.where(valid_num, data, fill)
-            run = _seg_scan_minmax(masked, boundary, spec.op)
-            r = jnp.take(run, ends)
-            if is_float:
-                seg_nan = at_ends_diff(jnp.cumsum(nan_in.astype(jnp.int32))) > 0
-                seg_num = at_ends_diff(
-                    jnp.cumsum(valid_num.astype(jnp.int32))) > 0
-                nan = jnp.array(jnp.nan, r.dtype)
-                if spec.op == "max":
-                    r = jnp.where(seg_nan, nan, r)
+        for spec in aggs:
+            if spec.op == "count":
+                if spec.column is None:
+                    ones = sorted_occ.astype(jnp.int64)
                 else:
-                    r = jnp.where(seg_nan & ~seg_num, nan, r)
-            if was_bool:
-                r = r.astype(jnp.bool_)
-            out[spec.out_name] = Column(r, out_valid & has_any, col_dtype)
+                    ones = sorted_valid(spec.column).astype(jnp.int64)
+                out[spec.out_name] = Column(at_ends_diff(jnp.cumsum(ones)),
+                                            out_valid, T.INT64)
+                continue
+
+            if isinstance(batch[spec.column], Decimal128Column):
+                # Decimal128 aggregation over sorted runs.  sum/mean: exact
+                # 256-bit segmented sums (values sign-extend to uint32[n,8]; a
+                # 2^31-row group of |v|<2^127 stays < 2^158, never wraps) —
+                # sum gets Spark's decimal(min(38, p+10), s) with overflow ->
+                # null, mean divides by the count per Average's bounded(p+4,
+                # s+4) HALF_UP.  min/max: signed-128 segmented scans on the
+                # raw limb pairs.  (Non-ANSI nullOnOverflow; reference
+                # DecimalUtils ops are per-element — group aggregation lives
+                # above cudf in the plugin, so semantics follow Spark's
+                # aggregate expressions.)
+                from ..ops import decimal as D
+
+                dcol = batch[spec.column]
+                svalid = sorted_valid(spec.column)
+                slimbs = jnp.take(dcol.limbs, sperm, axis=0)
+                nn_d = at_ends_diff(jnp.cumsum(svalid.astype(jnp.int32)))
+                has_any_d = out_valid & (nn_d > 0)
+                if spec.op in ("min", "max"):
+                    if spec.op == "min":  # fill nulls with +max signed 128
+                        flo = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+                        fhi = jnp.uint64(0x7FFFFFFFFFFFFFFF)
+                    else:                 # fill with -min signed 128
+                        flo = jnp.uint64(0)
+                        fhi = jnp.uint64(0x8000000000000000)
+                    lo = jnp.where(svalid, slimbs[:, 0], flo)
+                    hi = jnp.where(svalid, slimbs[:, 1], fhi)
+                    rlo, rhi = _seg_scan_minmax128(lo, hi, boundary, spec.op)
+                    out[spec.out_name] = Decimal128Column(
+                        jnp.stack([jnp.take(rlo, ends),
+                                   jnp.take(rhi, ends)], axis=1),
+                        has_any_d, dcol.dtype)
+                    continue
+                u = D._from_i128(slimbs)
+                u = jnp.where(svalid[:, None], u, jnp.zeros((), jnp.uint32))
+                run = _seg_scan_sum256(u, boundary)
+                s256 = jnp.take(run, ends, axis=0)
+                if spec.op == "mean":
+                    limbs128, ok, out_t = _decimal_avg(s256, nn_d, dcol.dtype)
+                    out[spec.out_name] = Decimal128Column(
+                        limbs128, has_any_d & ok, out_t)
+                    continue
+                out_p = min(38, dcol.dtype.precision + 10)
+                mag, _ = D._abs(s256)
+                overflow = ~D._lt_u(mag, jnp.broadcast_to(D._pow10(out_p),
+                                                          mag.shape))
+                out[spec.out_name] = Decimal128Column(
+                    D._to_i128(s256), has_any_d & ~overflow,
+                    T.SparkType.decimal(out_p, dcol.dtype.scale))
+                continue
+
+            data, valid = sorted_col(spec.column)
+            col_dtype = batch[spec.column].dtype
+            nn = at_ends_diff(jnp.cumsum(valid.astype(jnp.int32)))
+            has_any = nn > 0
+
+            if spec.op in ("sum", "mean"):
+                out_t = (T.FLOAT64 if spec.op == "mean"
+                         else _sum_dtype(col_dtype))
+                acc = data.astype(out_t.jnp_dtype if spec.op == "sum"
+                                  else jnp.float64)
+                acc = jnp.where(valid, acc, jnp.zeros((), acc.dtype))
+                if jnp.issubdtype(acc.dtype, jnp.floating):
+                    s = jnp.take(_seg_scan_sum(acc, boundary), ends)
+                else:
+                    s = at_ends_diff(jnp.cumsum(acc))  # exact mod-2^64
+                if spec.op == "mean":
+                    s = s / jnp.maximum(nn, 1).astype(jnp.float64)
+                out[spec.out_name] = Column(s, out_valid & has_any, out_t)
+            else:  # min / max — Spark float semantics: NaN greatest, one NaN
+                is_float = jnp.issubdtype(data.dtype, jnp.floating)
+                was_bool = data.dtype == jnp.bool_
+                if is_float:
+                    fill = jnp.array(jnp.inf if spec.op == "min" else -jnp.inf,
+                                     data.dtype)
+                    nan_in = valid & jnp.isnan(data)
+                    valid_num = valid & ~jnp.isnan(data)
+                elif was_bool:
+                    data = data.astype(jnp.uint8)
+                    fill = jnp.uint8(1 if spec.op == "min" else 0)
+                    valid_num = valid
+                else:
+                    info = jnp.iinfo(data.dtype)
+                    fill = jnp.array(
+                        info.max if spec.op == "min" else info.min,
+                        data.dtype)
+                    valid_num = valid
+                masked = jnp.where(valid_num, data, fill)
+                run = _seg_scan_minmax(masked, boundary, spec.op)
+                r = jnp.take(run, ends)
+                if is_float:
+                    seg_nan = at_ends_diff(
+                        jnp.cumsum(nan_in.astype(jnp.int32))) > 0
+                    seg_num = at_ends_diff(
+                        jnp.cumsum(valid_num.astype(jnp.int32))) > 0
+                    nan = jnp.array(jnp.nan, r.dtype)
+                    if spec.op == "max":
+                        r = jnp.where(seg_nan, nan, r)
+                    else:
+                        r = jnp.where(seg_nan & ~seg_num, nan, r)
+                if was_bool:
+                    r = r.astype(jnp.bool_)
+                out[spec.out_name] = Column(r, out_valid & has_any, col_dtype)
 
     return ColumnBatch(out), num_groups
 
@@ -779,7 +792,9 @@ def group_by_onehot(
     """
     parts, overflow = _domain_partials(batch, key_name, aggs, domain,
                                        row_valid, engine, float_mode)
-    res, ng = _finalize_domain(batch, key_name, int(domain), aggs, parts)
+    with scope("agg.finalize"):
+        res, ng = _finalize_domain(batch, key_name, int(domain), aggs,
+                                   parts)
     return res, ng, overflow
 
 
@@ -830,7 +845,8 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
     # null keys form their own group (bucket K), like the sort-scan path;
     # dead padding rows are dropped from the onehot entirely (callers
     # rely on the overflow flag to fall back to sort-scan)
-    bucket, overflow = _domain_bucket_overflow(col, live, K)
+    with scope("agg.onehot_bucket"):
+        bucket, overflow = _domain_bucket_overflow(col, live, K)
 
     # ---- plan the stacked payload ------------------------------------
     # int8 slots: [0]=ones(count*), then per referenced column one valid
@@ -863,45 +879,46 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
             if c not in target:
                 target.append(c)
 
-    cols8 = [jnp.ones((n,), jnp.int8)]  # slot 0: count(*)
-    for c in valid_slot:
-        valid_slot[c] = len(cols8)
-        cols8.append((batch[c].validity & row_live).astype(jnp.int8))
-    limb_slot = {}
-    for c in int_cols:
-        vcol = batch[c]
-        vvalid = vcol.validity & row_live
-        u = jax.lax.bitcast_convert_type(
-            jnp.where(vvalid, vcol.data.astype(jnp.int64), jnp.int64(0)),
-            jnp.uint64)
-        bytes8 = jax.lax.bitcast_convert_type(u, jnp.uint8)  # [n, 8]
-        x = jnp.where(vvalid[:, None],
-                      bytes8.astype(jnp.int16) - jnp.int16(128),
-                      jnp.int16(0)).astype(jnp.int8)
-        limb_slot[c] = len(cols8)
-        cols8.extend(x[:, j] for j in range(8))
-    # decimal128 sum columns: 16 byte limbs of the two's-complement
-    # unscaled value + one negative-flag slot (the signed sum is the
-    # unsigned-representation sum minus 2^128 x #negatives — unlike the
-    # int64 path that correction does NOT wrap away, since decimal
-    # overflow is judged exactly against 10^precision)
-    dec_slot = {}
-    for c in dec_cols:
-        vcol = batch[c]
-        vvalid = vcol.validity & row_live
-        limbs = jnp.where(vvalid[:, None], vcol.limbs,
-                          jnp.zeros((), jnp.uint64))
-        bytes16 = jax.lax.bitcast_convert_type(
-            limbs, jnp.uint8).reshape(n, 16)
-        x = jnp.where(vvalid[:, None],
-                      bytes16.astype(jnp.int16) - jnp.int16(128),
-                      jnp.int16(0)).astype(jnp.int8)
-        neg = (vvalid
-               & ((limbs[:, 1] >> jnp.uint64(63)) != 0)).astype(jnp.int8)
-        dec_slot[c] = len(cols8)
-        cols8.extend(x[:, j] for j in range(16))
-        cols8.append(neg)
-    X8 = jnp.stack(cols8, axis=1)  # [n, m8]
+    with scope("agg.onehot_payload"):
+        cols8 = [jnp.ones((n,), jnp.int8)]  # slot 0: count(*)
+        for c in valid_slot:
+            valid_slot[c] = len(cols8)
+            cols8.append((batch[c].validity & row_live).astype(jnp.int8))
+        limb_slot = {}
+        for c in int_cols:
+            vcol = batch[c]
+            vvalid = vcol.validity & row_live
+            u = jax.lax.bitcast_convert_type(
+                jnp.where(vvalid, vcol.data.astype(jnp.int64), jnp.int64(0)),
+                jnp.uint64)
+            bytes8 = jax.lax.bitcast_convert_type(u, jnp.uint8)  # [n, 8]
+            x = jnp.where(vvalid[:, None],
+                          bytes8.astype(jnp.int16) - jnp.int16(128),
+                          jnp.int16(0)).astype(jnp.int8)
+            limb_slot[c] = len(cols8)
+            cols8.extend(x[:, j] for j in range(8))
+        # decimal128 sum columns: 16 byte limbs of the two's-complement
+        # unscaled value + one negative-flag slot (the signed sum is the
+        # unsigned-representation sum minus 2^128 x #negatives — unlike the
+        # int64 path that correction does NOT wrap away, since decimal
+        # overflow is judged exactly against 10^precision)
+        dec_slot = {}
+        for c in dec_cols:
+            vcol = batch[c]
+            vvalid = vcol.validity & row_live
+            limbs = jnp.where(vvalid[:, None], vcol.limbs,
+                              jnp.zeros((), jnp.uint64))
+            bytes16 = jax.lax.bitcast_convert_type(
+                limbs, jnp.uint8).reshape(n, 16)
+            x = jnp.where(vvalid[:, None],
+                          bytes16.astype(jnp.int16) - jnp.int16(128),
+                          jnp.int16(0)).astype(jnp.int8)
+            neg = (vvalid
+                   & ((limbs[:, 1] >> jnp.uint64(63)) != 0)).astype(jnp.int8)
+            dec_slot[c] = len(cols8)
+            cols8.extend(x[:, j] for j in range(16))
+            cols8.append(neg)
+        X8 = jnp.stack(cols8, axis=1)  # [n, m8]
 
     def dekker_limbs(c):
         """Exact 3-way split of a masked f64 column into f32 (hi, mid, lo)."""
@@ -925,15 +942,16 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
     use_f32x3 = float_mode == "f32x3" or engine == "pallas"
 
     F = None
-    if float_cols:
-        if use_f32x3:
-            F = jnp.stack(
-                sum((dekker_limbs(c) for c in float_cols), []), axis=1)
-        else:
-            F = jnp.stack(
-                [jnp.where(batch[c].validity & row_live,
-                           batch[c].data.astype(jnp.float64), 0.0)
-                 for c in float_cols], axis=1)
+    with scope("agg.onehot_payload"):
+        if float_cols:
+            if use_f32x3:
+                F = jnp.stack(
+                    sum((dekker_limbs(c) for c in float_cols), []), axis=1)
+            else:
+                F = jnp.stack(
+                    [jnp.where(batch[c].validity & row_live,
+                               batch[c].data.astype(jnp.float64), 0.0)
+                     for c in float_cols], axis=1)
 
     if engine == "pallas":
         from ..ops.pallas_kernels import onehot_groupby_parts
@@ -958,76 +976,82 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
         part = jnp.zeros((K + 1, X8.shape[1]), jnp.int64)
         fpart = (jnp.zeros((K + 1, F.shape[1]), jnp.float64)
                  if float_cols else None)
+        fscope = ("agg.onehot_contract_f32x3" if use_f32x3
+                  else "agg.onehot_contract_f64")
         for lo in range(0, n, B):
-            ohc = ((bucket[lo:lo + B, None] == kids)
-                   & row_live[lo:lo + B, None])
-            part = part + jax.lax.dot_general(
-                ohc.astype(jnp.int8).T, X8[lo:lo + B],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            ).astype(jnp.int64)
-            if float_cols:
-                fpart = fpart + jax.lax.dot_general(
-                    ohc.astype(fdt).T, F[lo:lo + B],
+            with scope("agg.onehot_build"):
+                ohc = ((bucket[lo:lo + B, None] == kids)
+                       & row_live[lo:lo + B, None])
+            with scope("agg.onehot_contract_int8"):
+                part = part + jax.lax.dot_general(
+                    ohc.astype(jnp.int8).T, X8[lo:lo + B],
                     (((1,), (0,)), ((), ())),
-                    preferred_element_type=fdt,
-                ).astype(jnp.float64)
+                    preferred_element_type=jnp.int32,
+                ).astype(jnp.int64)
+            if float_cols:
+                with scope(fscope):
+                    fpart = fpart + jax.lax.dot_general(
+                        ohc.astype(fdt).T, F[lo:lo + B],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=fdt,
+                    ).astype(jnp.float64)
 
-    fsum_of = {}
-    for i, c in enumerate(float_cols):
-        if use_f32x3:
-            fsum_of[c] = (fpart[:, 3 * i] + fpart[:, 3 * i + 1]
-                          + fpart[:, 3 * i + 2])
-        else:
-            fsum_of[c] = fpart[:, i]
+    with scope("agg.onehot_rebuild"):
+        fsum_of = {}
+        for i, c in enumerate(float_cols):
+            if use_f32x3:
+                fsum_of[c] = (fpart[:, 3 * i] + fpart[:, 3 * i + 1]
+                              + fpart[:, 3 * i + 2])
+            else:
+                fsum_of[c] = fpart[:, i]
 
-    counts_star = part[:, 0]
-    cnt_of = {c: part[:, s] for c, s in valid_slot.items()}
+        counts_star = part[:, 0]
+        cnt_of = {c: part[:, s] for c, s in valid_slot.items()}
 
-    # ---- exact integer sums: rebuild from offset byte limbs ----------
-    isum_of = {}
-    shifts = (jnp.uint64(8) * jnp.arange(8, dtype=jnp.uint64))[None, :]
-    for c in int_cols:
-        s = limb_slot[c]
-        true_limb = part[:, s:s + 8] + jnp.int64(128) * cnt_of[c][:, None]
-        total_u = jnp.sum(
-            jax.lax.bitcast_convert_type(true_limb, jnp.uint64)
-            << shifts, axis=1)
-        isum_of[c] = jax.lax.bitcast_convert_type(total_u, jnp.int64)
+        # ---- exact integer sums: rebuild from offset byte limbs ----------
+        isum_of = {}
+        shifts = (jnp.uint64(8) * jnp.arange(8, dtype=jnp.uint64))[None, :]
+        for c in int_cols:
+            s = limb_slot[c]
+            true_limb = part[:, s:s + 8] + jnp.int64(128) * cnt_of[c][:, None]
+            total_u = jnp.sum(
+                jax.lax.bitcast_convert_type(true_limb, jnp.uint64)
+                << shifts, axis=1)
+            isum_of[c] = jax.lax.bitcast_convert_type(total_u, jnp.int64)
 
-    # ---- exact decimal128 sums: 256-bit rebuild with sign correction --
-    # sum = (Σ_j true_limb_j · 256^j) − 2^128 · #negatives, carried out in
-    # uint32[K+1, 8] limbs (≤ 2^158 for 2^31 rows — never wraps); overflow
-    # vs 10^min(38, p+10) nulls the group (Spark non-ANSI Sum)
-    d64_of = {}
-    if dec_cols:
-        from ..ops import decimal as D
+        # ---- exact decimal128 sums: 256-bit rebuild with sign correction --
+        # sum = (Σ_j true_limb_j · 256^j) − 2^128 · #negatives, carried out in
+        # uint32[K+1, 8] limbs (≤ 2^158 for 2^31 rows — never wraps); overflow
+        # vs 10^min(38, p+10) nulls the group (Spark non-ANSI Sum)
+        d64_of = {}
+        if dec_cols:
+            from ..ops import decimal as D
 
-        m32 = jnp.uint64(0xFFFFFFFF)
-        KP1 = K + 1
-        for c in dec_cols:
-            s = dec_slot[c]
-            true_limb = jax.lax.bitcast_convert_type(
-                part[:, s:s + 16]
-                + jnp.int64(128) * cnt_of[c][:, None], jnp.uint64)
-            # lane accumulators stay uint64 (each < 2^41 + carries);
-            # every byte sum j lands at bit 8j = 32·(j//4) + 8·(j%4)
-            lanes = [jnp.zeros((KP1,), jnp.uint64) for _ in range(9)]
-            for j in range(16):
-                q, r = divmod(8 * j, 32)
-                slo = true_limb[:, j] & m32  # < 2^33; slo<<r fits u64
-                shi = true_limb[:, j] >> jnp.uint64(32)
-                a = slo << jnp.uint64(r)
-                b = shi << jnp.uint64(r)
-                lanes[q] = lanes[q] + (a & m32)
-                lanes[q + 1] = lanes[q + 1] + (a >> jnp.uint64(32)) \
-                    + (b & m32)
-                lanes[q + 2] = lanes[q + 2] + (b >> jnp.uint64(32))
-            usum = _carry_fold_u64_lanes(jnp.stack(lanes[:8], axis=1))
-            negcnt = part[:, s + 16]  # >= 0, < 2^31: one u32 limb at 2^128
-            sub = jnp.zeros((KP1, 8), jnp.uint32).at[:, 4].set(
-                negcnt.astype(jnp.uint32))
-            d64_of[c] = D._add(usum, D._neg(sub)).astype(jnp.uint64)
+            m32 = jnp.uint64(0xFFFFFFFF)
+            KP1 = K + 1
+            for c in dec_cols:
+                s = dec_slot[c]
+                true_limb = jax.lax.bitcast_convert_type(
+                    part[:, s:s + 16]
+                    + jnp.int64(128) * cnt_of[c][:, None], jnp.uint64)
+                # lane accumulators stay uint64 (each < 2^41 + carries);
+                # every byte sum j lands at bit 8j = 32·(j//4) + 8·(j%4)
+                lanes = [jnp.zeros((KP1,), jnp.uint64) for _ in range(9)]
+                for j in range(16):
+                    q, r = divmod(8 * j, 32)
+                    slo = true_limb[:, j] & m32  # < 2^33; slo<<r fits u64
+                    shi = true_limb[:, j] >> jnp.uint64(32)
+                    a = slo << jnp.uint64(r)
+                    b = shi << jnp.uint64(r)
+                    lanes[q] = lanes[q] + (a & m32)
+                    lanes[q + 1] = lanes[q + 1] + (a >> jnp.uint64(32)) \
+                        + (b & m32)
+                    lanes[q + 2] = lanes[q + 2] + (b >> jnp.uint64(32))
+                usum = _carry_fold_u64_lanes(jnp.stack(lanes[:8], axis=1))
+                negcnt = part[:, s + 16]  # >= 0, < 2^31: one u32 limb at 2^128
+                sub = jnp.zeros((KP1, 8), jnp.uint32).at[:, 4].set(
+                    negcnt.astype(jnp.uint32))
+                d64_of[c] = D._add(usum, D._neg(sub)).astype(jnp.uint64)
 
     parts = {"star": counts_star, "cnt": cnt_of, "isum": isum_of,
              "fsum": fsum_of, "d64": d64_of}

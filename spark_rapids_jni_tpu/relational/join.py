@@ -47,6 +47,7 @@ from ..columnar.encoded import (
     RunLengthColumn,
     align_encoded_key_columns,
 )
+from ..profiler import scope
 from . import keys as K
 from .filter import compact
 from .gather import gather_batch
@@ -269,7 +270,8 @@ def hash_join(
                  for c in rcols]
 
     engine = _resolve_join_engine(engine)
-    lkeys = K.batch_radix_keys(lcols, equality=True, nulls_first=False)
+    with scope("join.probe_keys"):
+        lkeys = K.batch_radix_keys(lcols, equality=True, nulls_first=False)
     l_null = jnp.zeros((nl,), jnp.bool_)
     for c in lcols:
         l_null = l_null | ~c.validity
@@ -294,33 +296,37 @@ def hash_join(
             counts_slot, off_slot = prebuilt[3], prebuilt[4]
             rkeys = tuple(prebuilt[5:])
         else:
-            rkeys = K.batch_radix_keys(rcols, equality=True,
-                                       nulls_first=False)
-            built = _hash_build(rkeys, nr, table_engine)
+            with scope("join.hash_build"):
+                rkeys = K.batch_radix_keys(rcols, equality=True,
+                                           nulls_first=False)
+                built = _hash_build(rkeys, nr, table_engine)
             owner, rslot, rperm, counts_slot, off_slot = built[:5]
-        probe_live = ~l_null & l_live
-        found, lslot = H.probe_slot_table(
-            owner, rkeys, lkeys, probe_live,
-            max_rounds=_adaptive.bound_probe_rounds(owner, nr),
-            engine=table_engine)
-        counts = jnp.where(found, jnp.take(counts_slot, lslot),
-                           jnp.int32(0))
-        lo = jnp.take(off_slot, lslot)
+        with scope("join.hash_probe"):
+            probe_live = ~l_null & l_live
+            found, lslot = H.probe_slot_table(
+                owner, rkeys, lkeys, probe_live,
+                max_rounds=_adaptive.bound_probe_rounds(owner, nr),
+                engine=table_engine)
+            counts = jnp.where(found, jnp.take(counts_slot, lslot),
+                               jnp.int32(0))
+            lo = jnp.take(off_slot, lslot)
     else:
         if prebuilt is not None:
             sorted_rkeys, rperm = tuple(prebuilt[:-1]), prebuilt[-1]
         else:
-            rkeys = K.batch_radix_keys(rcols, equality=True,
-                                       nulls_first=False)
-            iota_r = jnp.arange(nr, dtype=jnp.int32)
-            sorted_ops = jax.lax.sort(
-                tuple(rkeys) + (iota_r,), num_keys=len(rkeys),
-                is_stable=True
-            )
+            with scope("join.build_sort"):
+                rkeys = K.batch_radix_keys(rcols, equality=True,
+                                           nulls_first=False)
+                iota_r = jnp.arange(nr, dtype=jnp.int32)
+                sorted_ops = jax.lax.sort(
+                    tuple(rkeys) + (iota_r,), num_keys=len(rkeys),
+                    is_stable=True
+                )
             sorted_rkeys, rperm = sorted_ops[:-1], sorted_ops[-1]
-        lo, hi = K.equal_range(sorted_rkeys, lkeys)
-        counts = jnp.where(l_null, 0, hi - lo).astype(jnp.int32)
-        counts = jnp.where(l_live, counts, 0)
+        with scope("join.bisect"):
+            lo, hi = K.equal_range(sorted_rkeys, lkeys)
+            counts = jnp.where(l_null, 0, hi - lo).astype(jnp.int32)
+            counts = jnp.where(l_live, counts, 0)
 
     if how == "semi":
         return compact(left, (counts > 0) & l_live)
@@ -328,35 +334,39 @@ def hash_join(
         return compact(left, (counts == 0) & l_live)
 
     outer = how in ("left", "full")
-    counts_out = jnp.where(l_live, jnp.maximum(counts, 1), 0) if outer \
-        else counts
-    cum = jnp.cumsum(counts_out)  # inclusive
-    total = cum[-1] if nl else jnp.int32(0)
-    offsets = cum - counts_out
-
     if capacity is None:
         capacity = nl
-    j = jnp.arange(capacity, dtype=jnp.int32)
-    # source left row for each output slot
-    li = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
-    li = jnp.clip(li, 0, max(nl - 1, 0))
-    k = j - offsets[li] if nl else jnp.zeros_like(j)
-    pos = jnp.clip(lo[li] + k, 0, max(nr - 1, 0))
-    ri = rperm[pos] if nr else jnp.zeros_like(j)
+    with scope("join.expand"):
+        counts_out = jnp.where(l_live, jnp.maximum(counts, 1), 0) \
+            if outer else counts
+        cum = jnp.cumsum(counts_out)  # inclusive
+        total = cum[-1] if nl else jnp.int32(0)
+        offsets = cum - counts_out
 
-    out_valid = j < total
-    matched = (counts[li] > 0) & out_valid if nl else jnp.zeros_like(out_valid)
+        j = jnp.arange(capacity, dtype=jnp.int32)
+        # source left row for each output slot
+        li = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
+        li = jnp.clip(li, 0, max(nl - 1, 0))
+        k = j - offsets[li] if nl else jnp.zeros_like(j)
+        pos = jnp.clip(lo[li] + k, 0, max(nr - 1, 0))
+        ri = rperm[pos] if nr else jnp.zeros_like(j)
 
-    lpart = gather_batch(left, li, out_valid)
+        out_valid = j < total
+        matched = (counts[li] > 0) & out_valid if nl \
+            else jnp.zeros_like(out_valid)
+
+    with scope("join.gather_left"):
+        lpart = gather_batch(left, li, out_valid)
     # full joins keep the right key columns so unmatched right rows
     # retain their key values in the appended region
     right_names = (list(right.names) if how == "full"
                    else [n for n in right.names if n not in right_on])
-    rpart = gather_batch(
-        right.select(right_names) if right_names else ColumnBatch({}),
-        ri,
-        matched if outer else out_valid,
-    )
+    with scope("join.gather_right"):
+        rpart = gather_batch(
+            right.select(right_names) if right_names else ColumnBatch({}),
+            ri,
+            matched if outer else out_valid,
+        )
 
     if how == "full":
         r_live = (jnp.ones((nr,), jnp.bool_) if right_valid is None
@@ -479,55 +489,65 @@ def join_dense_or_hash(
     K1 = int(domain)
     cap = nl if capacity is None else int(capacity)
 
-    rv = (jnp.ones((nr,), jnp.bool_) if right_valid is None
-          else right_valid.astype(jnp.bool_))
-    r_live = rcol.validity & rv
-    rk = rcol.data.astype(jnp.int32)
-    in_dom = r_live & (rk >= 0) & (rk < K1)
-    slot = jnp.where(in_dom, rk, K1)          # K1 = discard slot
-    cnt = jnp.zeros((K1 + 1,), jnp.int32).at[slot].add(1)
-    # wider-than-32-bit keys must round-trip the int32 cast exactly on
-    # BOTH sides, else a key >= 2^32 could wrap into [0, domain) and
-    # fabricate matches the general engine would never produce
-    lv_pre = (jnp.ones((nl,), jnp.bool_) if left_valid is None
-              else left_valid.astype(jnp.bool_))
-    lk32 = lcol.data.astype(jnp.int32)
-    no_wrap = (
-        jnp.all((rk.astype(rcol.data.dtype) == rcol.data) | ~r_live)
-        & jnp.all((lk32.astype(lcol.data.dtype) == lcol.data)
-                  | ~(lcol.validity & lv_pre)))
-    dense_ok = (jnp.all(in_dom | ~r_live) & jnp.all(cnt[:K1] <= 1)
-                & no_wrap)
+    with scope("join.dense_check"):
+        rv = (jnp.ones((nr,), jnp.bool_) if right_valid is None
+              else right_valid.astype(jnp.bool_))
+        r_live = rcol.validity & rv
+        rk = rcol.data.astype(jnp.int32)
+        in_dom = r_live & (rk >= 0) & (rk < K1)
+        slot = jnp.where(in_dom, rk, K1)          # K1 = discard slot
+        cnt = jnp.zeros((K1 + 1,), jnp.int32).at[slot].add(1)
+        # wider-than-32-bit keys must round-trip the int32 cast exactly
+        # on BOTH sides, else a key >= 2^32 could wrap into [0, domain)
+        # and fabricate matches the general engine would never produce
+        lv_pre = (jnp.ones((nl,), jnp.bool_) if left_valid is None
+                  else left_valid.astype(jnp.bool_))
+        lk32 = lcol.data.astype(jnp.int32)
+        no_wrap = (
+            jnp.all((rk.astype(rcol.data.dtype) == rcol.data) | ~r_live)
+            & jnp.all((lk32.astype(lcol.data.dtype) == lcol.data)
+                      | ~(lcol.validity & lv_pre)))
+        dense_ok = (jnp.all(in_dom | ~r_live) & jnp.all(cnt[:K1] <= 1)
+                    & no_wrap)
 
     def dense(_):
-        rowid = jnp.zeros((K1 + 1,), jnp.int32).at[slot].set(
-            jnp.arange(nr, dtype=jnp.int32))
-        present = cnt[:K1] > 0
-        lv = (jnp.ones((nl,), jnp.bool_) if left_valid is None
-              else left_valid.astype(jnp.bool_))
-        lk = lcol.data.astype(jnp.int32)
-        lk_ok = lcol.validity & lv & (lk >= 0) & (lk < K1)
-        lk_safe = jnp.where(lk_ok, lk, 0)
-        match = lk_ok & present[lk_safe]
-        total = jnp.sum(match, dtype=jnp.int32)
+        with scope("join.dense_build"):
+            rowid = jnp.zeros((K1 + 1,), jnp.int32).at[slot].set(
+                jnp.arange(nr, dtype=jnp.int32))
+            present = cnt[:K1] > 0
+        with scope("join.dense_probe"):
+            lv = (jnp.ones((nl,), jnp.bool_) if left_valid is None
+                  else left_valid.astype(jnp.bool_))
+            lk = lcol.data.astype(jnp.int32)
+            lk_ok = lcol.validity & lv & (lk >= 0) & (lk < K1)
+            lk_safe = jnp.where(lk_ok, lk, 0)
+            match = lk_ok & present[lk_safe]
+            total = jnp.sum(match, dtype=jnp.int32)
         from ..parallel.partition import regroup_order
 
-        order = regroup_order(jnp.where(match, 0, 1), 2)  # matches first
-        li = order[:cap] if cap <= nl else jnp.pad(
-            order, (0, cap - nl), constant_values=0)
-        out_valid = jnp.arange(cap, dtype=jnp.int32) < total
-        ri = rowid[jnp.clip(jnp.take(lk_safe, li), 0, K1)]
-        lpart = gather_batch(left, li, out_valid)
+        with scope("join.dense_compact"):
+            # matches first
+            order = regroup_order(jnp.where(match, 0, 1), 2)
+            li = order[:cap] if cap <= nl else jnp.pad(
+                order, (0, cap - nl), constant_values=0)
+            out_valid = jnp.arange(cap, dtype=jnp.int32) < total
+        with scope("join.dense_rowid"):
+            ri = rowid[jnp.clip(jnp.take(lk_safe, li), 0, K1)]
+        with scope("join.gather_left"):
+            lpart = gather_batch(left, li, out_valid)
         right_names = [n for n in right.names if n != right_on]
-        rpart = gather_batch(
-            right.select(right_names) if right_names else ColumnBatch({}),
-            ri, out_valid)
+        with scope("join.gather_right"):
+            rpart = gather_batch(
+                right.select(right_names) if right_names
+                else ColumnBatch({}), ri, out_valid)
         return _merge_parts(lpart, rpart, suffixes), total
 
     def general(_):
-        return hash_join(left, right, [left_on], [right_on], "inner",
-                         capacity=cap, suffixes=suffixes,
-                         left_valid=left_valid, right_valid=right_valid)
+        with scope("join.general"):
+            return hash_join(left, right, [left_on], [right_on], "inner",
+                             capacity=cap, suffixes=suffixes,
+                             left_valid=left_valid,
+                             right_valid=right_valid)
 
     return jax.lax.cond(dense_ok, dense, general, None)
 

@@ -34,6 +34,7 @@ import numpy as np
 
 from ..columnar import types as T
 from ..columnar.column import Column, Decimal128Column, StringColumn
+from ..profiler import scope
 from ..columnar.encoded import (
     BitPackedColumn,
     DictionaryColumn,
@@ -231,7 +232,8 @@ def _search(sorted_keys, query_keys, *, lower: bool):
         lo, hi = lohi
         active = lo < hi
         mid = (lo + hi) >> 1
-        mid_keys = [jnp.take(k, mid, mode="clip") for k in sorted_keys]
+        with scope("keys.bisect_gather"):
+            mid_keys = [jnp.take(k, mid, mode="clip") for k in sorted_keys]
         # advance when sorted[mid] < q (lower) / sorted[mid] <= q (upper)
         adv = _lex_less(mid_keys, query_keys, or_equal=not lower)
         lo = jnp.where(active & adv, mid + 1, lo)
@@ -279,10 +281,12 @@ def equal_range(sorted_keys, query_keys):
         # (XLA CSEs the duplicate takes); state stays a flat 4-tuple
         lmid = (llo + lhi) >> 1
         umid = (ulo + uhi) >> 1
-        lkeys = [jnp.take(k, lmid, mode="clip") for k in sorted_keys]
-        ukeys = [jnp.take(k, umid, mode="clip") for k in sorted_keys]
-        ladv = _lex_less(lkeys, query_keys, or_equal=False)
-        uadv = _lex_less(ukeys, query_keys, or_equal=True)
+        with scope("keys.bisect_gather"):
+            lkeys = [jnp.take(k, lmid, mode="clip") for k in sorted_keys]
+            ukeys = [jnp.take(k, umid, mode="clip") for k in sorted_keys]
+        with scope("keys.bisect_compare"):
+            ladv = _lex_less(lkeys, query_keys, or_equal=False)
+            uadv = _lex_less(ukeys, query_keys, or_equal=True)
         lact = llo < lhi
         uact = ulo < uhi
         llo = jnp.where(lact & ladv, lmid + 1, llo)

@@ -125,7 +125,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-from .. import config, faultinj
+from .. import config, faultinj, profiler
 from ..shuffle import store as store_mod
 from . import data_plane, wire
 from . import elastic as elastic_mod
@@ -191,6 +191,8 @@ class FleetMetrics:
         self._lock = threading.Lock()
         self._counts = dict.fromkeys(self.FIELDS, 0)
         self._liveness: Dict[int, str] = {}
+        self._backends: Dict[int, str] = {}
+        self._stage_ms: Dict[str, list] = {}  # stage -> [count, sum, max]
 
     def bump(self, field: str, n: int = 1):
         with self._lock:
@@ -200,10 +202,30 @@ class FleetMetrics:
         with self._lock:
             self._liveness[int(worker_id)] = state
 
+    def set_backend(self, worker_id: int, backend: str):
+        with self._lock:
+            self._backends[int(worker_id)] = backend
+
+    def add_timeline(self, timeline: Dict[str, float]):
+        """Fold one finished session's ``{stage: ms}`` in."""
+        with self._lock:
+            for stage, ms in timeline.items():
+                t = self._stage_ms.setdefault(stage, [0, 0.0, 0.0])
+                t[0] += 1
+                t[1] += ms
+                t[2] = max(t[2], ms)
+
     def snapshot(self) -> dict:
         with self._lock:
             out = dict(self._counts)
             out["liveness"] = dict(self._liveness)
+            # what each worker's hello said it runs on: "tpu TPU v5 lite"
+            out["backends"] = dict(self._backends)
+            # per stage of FrontDoorSession.timeline, over finished
+            # sessions: {count, sum_ms, max_ms}
+            out["stage_ms"] = {
+                st: {"count": c, "sum_ms": sm, "max_ms": mx}
+                for st, (c, sm, mx) in self._stage_ms.items()}
             return out
 
 
@@ -223,7 +245,20 @@ class FrontDoorSession:
     ``replacements`` counts how many worker losses it survived.
     ``replayable=False`` declares the query non-idempotent: once seen
     ``running`` it is never re-placed — a worker loss fails it with
-    :class:`WorkerLost` instead of silently re-running side effects."""
+    :class:`WorkerLost` instead of silently re-running side effects.
+
+    ``timeline`` is where the session's time went, ``{stage: ms}``: the
+    supervisor's own spans (``serve.*``) as they close, the worker's
+    stage durations (``worker.*``) off its result frame — durations
+    only, no clock of one process is ever compared with the other's.
+    Finishing adds ``total_ms`` (submit to finish) and
+    ``unaccounted_ms``: the total minus the leaf stages, which is wire
+    transit and thread wake-ups.  A session served from the result
+    cache has ``serve.cache_probe`` and the journal record of its
+    finish, and no stage of dispatch, worker or decode."""
+
+    # stages that enclose other stages: left out of the leaves' sum
+    TIMELINE_PARENTS = ("serve.submit", "serve.decode")
 
     def __init__(self, door: "FrontDoor", sid: int, kind: str,
                  params: Optional[dict], tenant, priority: int,
@@ -255,6 +290,16 @@ class FrontDoorSession:
         self._cancel_requested = False
         self._done = threading.Event()
         self.submitted_at = time.monotonic()
+        self.timeline: Dict[str, float] = {}
+        self._t0_ns = time.perf_counter_ns()
+        self._queued_ns = self._t0_ns  # when it last joined _pending
+        self._timeline_lock = threading.Lock()
+
+    def _stage(self, name: str, ms: float):
+        """Add ``ms`` under ``name``: a stage met twice (three journal
+        records; a re-placement) adds up."""
+        with self._timeline_lock:
+            self.timeline[name] = self.timeline.get(name, 0.0) + ms
 
     def done(self) -> bool:
         return self._done.is_set()
@@ -294,9 +339,20 @@ class FrontDoorSession:
             secs = 0.0
             if final == "done" and not self.served_from_cache:
                 secs = max(0.0, time.monotonic() - self.submitted_at)
-            door._jrec("result", sid=self.sid, status=final,
-                       from_cache=bool(self.served_from_cache),
-                       tenant=str(self.tenant), seconds=round(secs, 6))
+            self._stage("serve.journal", door._jrec(
+                "result", sid=self.sid, status=final,
+                from_cache=bool(self.served_from_cache),
+                tenant=str(self.tenant), seconds=round(secs, 6)))
+        with self._timeline_lock:
+            tl = self.timeline
+            total = (time.perf_counter_ns() - self._t0_ns) / 1e6
+            tl["unaccounted_ms"] = total - sum(
+                ms for st, ms in tl.items()
+                if st not in self.TIMELINE_PARENTS)
+            tl["total_ms"] = total
+            stages = dict(tl)
+        if door is not None:
+            door.metrics.add_timeline(stages)
         self.result_value = value
         self.error = error
         self.status = final
@@ -663,17 +719,22 @@ class FrontDoor:
         process is the dead supervisor now, so the death is made real
         (:meth:`_simulate_crash`) and re-raised for the caller's test
         harness to observe.  A real journal I/O failure degrades to
-        unjournaled operation rather than taking the fleet down."""
+        unjournaled operation rather than taking the fleet down.
+        Returns the append's milliseconds (the ``O_APPEND`` write and
+        its ``fsync``), for the session's timeline."""
         j = self._journal
         if j is None or j.closed:
-            return
-        try:
-            j.append(rec, **fields)
-        except (faultinj.SupervisorCrash, faultinj.JournalTornError):
-            self._simulate_crash()
-            raise
-        except OSError:
-            pass
+            return 0.0
+        with profiler.span("serve.journal", sid=fields.get("sid"),
+                           rec=rec) as sp:
+            try:
+                j.append(rec, **fields)
+            except (faultinj.SupervisorCrash, faultinj.JournalTornError):
+                self._simulate_crash()
+                raise
+            except OSError:
+                pass
+        return sp.ms
 
     def _simulate_crash(self):
         """Become a dead supervisor, abruptly: stop the loops, drop the
@@ -830,18 +891,14 @@ class FrontDoor:
             # completed work whose terminal record died with the crash:
             # the handed-over cache still holds the bytes — serve them,
             # never recompute
-            sig = result_cache_mod.query_signature(kind, sess.params)
-            fp = result_cache_mod.knob_fingerprint()
-            sess.cache_key = (sig, sess.snapshot, fp)
-            view = self.result_cache.serve(sig, sess.snapshot, fp)
-            if view is not None and self._serve_cache_hit(sess, view):
+            if self._cache_probe(sess):
                 self.metrics.bump("recovered_sessions")
                 self._adopt_stats["recovered_sessions"] += 1
                 return
         self._jrec("replayed", sid=old_sid, new_sid=sess.sid)
         self.metrics.bump("replayed_sessions")
         self._adopt_stats["replayed_sessions"] += 1
-        self._pending.append([now, sess])
+        self._enqueue_locked(now, sess)
 
     # -- public API -----------------------------------------------------
     def submit(self, kind: str, params: Optional[dict] = None, tenant=None,
@@ -862,32 +919,33 @@ class FrontDoor:
         if self._shutdown_started:  # graftlint: guarded-by(_lock)
             raise ServeError("front door is shut down")
         sid = next(self._sids)
-        sess = FrontDoorSession(
-            self, sid, kind, params,
-            tenant if tenant is not None else f"tenant-{sid}",
-            priority, est_bytes, timeout_s, replayable, snapshot=snapshot)
-        if snapshot is not None and self.result_cache.enabled():
-            sig = result_cache_mod.query_signature(kind, params)
-            fp = result_cache_mod.knob_fingerprint()
-            sess.cache_key = (sig, snapshot, fp)
-            view = self.result_cache.serve(sig, snapshot, fp)
-            if view is not None and self._serve_cache_hit(sess, view):
+        with profiler.span("serve.submit", sid=sid) as sp:
+            sess = FrontDoorSession(
+                self, sid, kind, params,
+                tenant if tenant is not None else f"tenant-{sid}",
+                priority, est_bytes, timeout_s, replayable,
+                snapshot=snapshot)
+            if snapshot is not None and self.result_cache.enabled() \
+                    and self._cache_probe(sess):
                 return sess
-        now = time.monotonic()
-        with self._lock:
-            self._charge_admission_locked(sess)
-            # write-ahead: the admission is durable before the session
-            # is queued — a quota rejection above never journals (the
-            # session was never admitted, replay must not re-charge it)
-            self._jrec("submit", sid=sid, kind=kind, params=sess.params,
-                       tenant=str(sess.tenant), priority=sess.priority,
-                       est_bytes=sess.est_bytes,
-                       timeout_s=sess.timeout_s,
-                       replayable=sess.replayable,
-                       snapshot=sess.snapshot)
-            self._pending.append([now, sess])
-            self._maybe_shed_locked()
-            self._dispatch_locked(now)
+            now = time.monotonic()
+            with self._lock:
+                self._charge_admission_locked(sess)
+                # write-ahead: the admission is durable before the
+                # session is queued — a quota rejection above never
+                # journals (the session was never admitted, replay must
+                # not re-charge it)
+                sess._stage("serve.journal", self._jrec(
+                    "submit", sid=sid, kind=kind, params=sess.params,
+                    tenant=str(sess.tenant), priority=sess.priority,
+                    est_bytes=sess.est_bytes, timeout_s=sess.timeout_s,
+                    replayable=sess.replayable, snapshot=sess.snapshot))
+                self._enqueue_locked(now, sess)
+                self._maybe_shed_locked()
+                self._dispatch_locked(now)
+        # a parent stage: it may close after a fast answer finished the
+        # session, and is in no sum
+        sess._stage("serve.submit", sp.ms)
         self._wake.set()
         return sess
 
@@ -1312,6 +1370,9 @@ class FrontDoor:
                     self.metrics.bump("reconnects")
                 w.ever_connected = True
                 w.backend = hello.get("backend")
+                self.metrics.set_backend(slot, " ".join(
+                    str(hello[k]) for k in ("platform", "device_kind")
+                    if hello.get(k)))
                 link.settimeout(0.2)  # reader poll tick (supersession)
                 old, w.link = w.link, link
                 if old is not None:
@@ -1465,8 +1526,9 @@ class FrontDoor:
             return data_plane.DataPlaneOverflow(text)
         return ServeError(f"{err}: {text}")
 
-    def _decode_data_result(self, w: WorkerHandle, desc: dict,
-                            chunks: Optional[list], fds: List[int]):
+    def _decode_data_result(self, sess: FrontDoorSession, w: WorkerHandle,
+                            desc: dict, chunks: Optional[list],
+                            fds: List[int]):
         """Verify (epoch, then per-chunk CRCs) and decode one data-plane
         payload into ``(ColumnBatch, verified payload bytes)`` — the
         bytes feed the result cache in their already-encoded form.
@@ -1484,29 +1546,51 @@ class FrontDoor:
                 raise wire.WireError(
                     f"shm descriptor for segment {desc.get('seg')} "
                     f"arrived without its fd")
-            payload = data_plane.read_segment(fds[0], desc)
-        elif plane == "frames":
-            parts = sorted(chunks or [], key=lambda e: e[0])
-            payload = b"".join(p for _seq, p in parts)
-            data_plane.verify_chunks(payload, desc)
-        elif plane == "json":
-            payload = data_plane.decode_json_payload(
-                desc.get("inline") or "")
-            data_plane.verify_chunks(payload, desc)
+            # the copy out of the mapping, then its chunk CRCs
+            with profiler.span("serve.segment_read", sink=sess._stage):
+                payload = data_plane.read_segment(fds[0], desc)
+        elif plane in ("frames", "json"):
+            with profiler.span("serve.verify", sink=sess._stage):
+                if plane == "frames":
+                    parts = sorted(chunks or [], key=lambda e: e[0])
+                    payload = b"".join(p for _seq, p in parts)
+                else:
+                    payload = data_plane.decode_json_payload(
+                        desc.get("inline") or "")
+                data_plane.verify_chunks(payload, desc)
         else:
             raise wire.WireError(f"unknown data plane {plane!r} in "
                                  f"result descriptor")
-        return arrow_mod.ipc_to_batch(
-            payload, expect_fingerprint=desc.get("schema_fp")), payload
+        with profiler.span("serve.ipc_to_batch", sink=sess._stage):
+            batch = arrow_mod.ipc_to_batch(
+                payload, expect_fingerprint=desc.get("schema_fp"))
+        return batch, payload
 
-    def _serve_cache_hit(self, sess: FrontDoorSession,
-                         view) -> bool:
-        """Serve a cached result under a FRESH descriptor, verified
+    def _cache_probe(self, sess: FrontDoorSession) -> bool:
+        """Look ``sess`` up in the result cache and, on a verified hit,
+        finish it: True says it was served here.  Lookup, re-seal,
+        verification and decode are the ``serve.cache_probe`` stage."""
+        sig = result_cache_mod.query_signature(sess.kind, sess.params)
+        fp = result_cache_mod.knob_fingerprint()
+        sess.cache_key = (sig, sess.snapshot, fp)
+        with profiler.span("serve.cache_probe", sid=sess.sid,
+                           sink=sess._stage):
+            view = self.result_cache.serve(sig, sess.snapshot, fp)
+            value = None if view is None \
+                else self._read_cache_hit(sess, view)
+        if value is None:
+            return False
+        sess.served_from_cache = True
+        sess._finish(value=value, status="done")
+        return True
+
+    def _read_cache_hit(self, sess: FrontDoorSession, view):
+        """Read a cached result under a FRESH descriptor, verified
         exactly like a live result: the stored bytes go into a new
         sealed memfd, the descriptor carries the insert-time chunk CRCs
         and the entry's snapshot id, and epoch → snapshot → CRC →
         schema-fingerprint checks all run before the session finishes.
-        Returns False on any rejection (stale snapshot, damage) — the
+        Returns None on any rejection (stale snapshot, damage) — the
         caller falls through to a live dispatch, so a bad entry costs a
         recompute, never a wrong answer."""
         from ..columnar import arrow as arrow_mod
@@ -1529,20 +1613,18 @@ class FrontDoor:
                 payload, expect_fingerprint=desc.get("schema_fp"))
         except data_plane.DataPlaneStale:
             self.result_cache.record_stale(view.key)
-            return False
+            return None
         except (data_plane.DataPlaneCorruption, wire.WireError,
                 ValueError, OSError):
             self.result_cache.quarantine(view.key)
-            return False
+            return None
         finally:
             with contextlib.suppress(OSError):
                 os.close(fd)
         self.metrics.bump("cache_hits")
         self.metrics.bump("hit_bytes_served", view.size)
         self.result_cache.record_hit(view.size)
-        sess.served_from_cache = True
-        sess._finish(value=value, status="done")
-        return True
+        return value
 
     def _requeue_data_damaged(self, sess: FrontDoorSession, w: WorkerHandle,
                               exc: BaseException):
@@ -1562,9 +1644,9 @@ class FrontDoor:
             sess.sid = new_sid
             sess.status = "pending"
             sess.worker_id = None
-            self._pending.append(
-                [time.monotonic() + self._backoff_s
-                 * (2 ** (sess.data_retries - 1)), sess])
+            self._enqueue_locked(
+                time.monotonic() + self._backoff_s
+                * (2 ** (sess.data_retries - 1)), sess)
             self._dispatch_locked(time.monotonic())
         self._wake.set()
 
@@ -1588,11 +1670,16 @@ class FrontDoor:
         try:
             if sess is None:
                 return
+            # the worker's stage durations ride the result frame
+            for stage, ms in (msg.get("stages") or {}).items():
+                sess._stage(str(stage), float(ms))
             if msg.get("ok"):
                 if desc is not None:
                     try:
-                        value, payload = self._decode_data_result(
-                            w, desc, chunks, fds)
+                        with profiler.span("serve.decode", sid=sess.sid,
+                                           sink=sess._stage):
+                            value, payload = self._decode_data_result(
+                                sess, w, desc, chunks, fds)
                     except (data_plane.DataPlaneStale,
                             data_plane.DataPlaneCorruption,
                             wire.WireError, ValueError, OSError) as e:
@@ -1779,7 +1866,7 @@ class FrontDoor:
                 sess.worker_id = None
                 not_before = now + self._backoff_s * (
                     2 ** (sess.replacements - 1))
-                self._pending.append([not_before, sess])
+                self._enqueue_locked(not_before, sess)
             else:
                 self.metrics.bump("worker_lost")
                 budget = "" if sess.status != "running" or sess.replayable \
@@ -1892,7 +1979,7 @@ class FrontDoor:
             self._jrec("requeued", sid=sess.sid)
             sess.status = "pending"
             sess.worker_id = None
-            self._pending.append([now, sess])
+            self._enqueue_locked(now, sess)
         w.sessions = {}
         w.data_stash = {}
         w.close()
@@ -1964,6 +2051,12 @@ class FrontDoor:
         self._pins[sess.tenant] = w.worker_id
         return w
 
+    def _enqueue_locked(self, not_before: float, sess: FrontDoorSession):
+        """Queue ``sess`` for placement at or after ``not_before``; its
+        ``serve.pending`` stage runs from here to its placement."""
+        sess._queued_ns = time.perf_counter_ns()
+        self._pending.append([not_before, sess])
+
     def _dispatch_locked(self, now: float):
         if self._shutdown_started:
             return
@@ -1999,19 +2092,27 @@ class FrontDoor:
             # journal over-claims a placement that never landed — safe
             # direction: adoption re-sends placed-but-unacked sessions
             # and the worker's sid dedup absorbs the duplicate.
-            self._jrec("placed", sid=sess.sid, slot=w.worker_id,
-                       gen=w.gen)
+            sess._stage("serve.pending", profiler.note(
+                "serve.pending", sess.sid, sess._queued_ns,
+                time.perf_counter_ns()))
+            sess._stage("serve.journal", self._jrec(
+                "placed", sid=sess.sid, slot=w.worker_id, gen=w.gen))
             try:
-                w.link.send({
-                    "op": "submit", "sid": sess.sid, "kind": sess.kind,
-                    "params": sess.params, "tenant": str(sess.tenant),
-                    "priority": sess.priority, "est_bytes": sess.est_bytes,
-                    "timeout_s": sess.timeout_s,
-                    "snapshot": sess.snapshot,
-                })
+                with profiler.span("serve.send", sid=sess.sid,
+                                   sink=sess._stage):
+                    w.link.send({
+                        "op": "submit", "sid": sess.sid,
+                        "kind": sess.kind, "params": sess.params,
+                        "tenant": str(sess.tenant),
+                        "priority": sess.priority,
+                        "est_bytes": sess.est_bytes,
+                        "timeout_s": sess.timeout_s,
+                        "snapshot": sess.snapshot,
+                    })
             except OSError:
                 # worker dying under us: leave it pending, the monitor's
                 # loss protocol will re-route it
+                sess._queued_ns = time.perf_counter_ns()
                 still.append(entry)
                 continue
             w.sessions[sess.sid] = sess

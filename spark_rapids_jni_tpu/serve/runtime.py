@@ -79,7 +79,7 @@ import time
 from concurrent import futures
 from typing import Callable, Optional
 
-from .. import config, faultinj
+from .. import config, faultinj, profiler
 from ..mem.executor import TaskContext, borrowed_task, run_with_retry
 from ..mem import spill as spill_mod
 from ..mem.rmm_spark import RmmSpark, ThreadStateRegistry, UnknownThreadError
@@ -214,9 +214,15 @@ class TenantSession:
     def __init__(self, runtime: "ServeRuntime", session_id: int,
                  task_id: int, tenant, query_fn: Callable,
                  est_bytes: int, timeout_s: Optional[float],
-                 priority: int = 0, store=None, epoch: int = 0):
+                 priority: int = 0, store=None, epoch: int = 0,
+                 trace_sid=None):
         self._runtime = runtime
         self.session_id = session_id
+        # the id this session's spans are recorded under (the front
+        # door's sid when a worker runs it), and where its stage
+        # milliseconds add up: {"worker.admit_wait": ms, ...}
+        self.trace_sid = session_id if trace_sid is None else trace_sid
+        self.stages: dict = {}
         self.task_id = task_id
         self.tenant = tenant if tenant is not None else f"tenant-{session_id}"
         self.query_fn = query_fn
@@ -268,6 +274,14 @@ class TenantSession:
         get_plan_cache().pin(key, self.pin_owner)
 
     # -- worker-side helpers --------------------------------------------
+    def _span(self, name: str):
+        """A span of this session's own: recorded under ``trace_sid``,
+        its milliseconds added to ``stages`` (a re-admission adds up)."""
+        return profiler.span(name, sid=self.trace_sid, sink=self._stage)
+
+    def _stage(self, name: str, ms: float):
+        self.stages[name] = self.stages.get(name, 0.0) + ms
+
     def _check_cancelled(self):
         if self._cancelled.is_set():
             reason = self._cancel_reason or "cancelled"
@@ -341,7 +355,7 @@ class ServeRuntime:
     # -- public API -----------------------------------------------------
     def submit(self, query_fn: Callable, est_bytes: int = 0, tenant=None,
                timeout_s: Optional[float] = None,
-               priority: int = 0) -> TenantSession:
+               priority: int = 0, trace_sid=None) -> TenantSession:
         """Queue ``query_fn`` for admission and return its session.
 
         ``query_fn(ctx)`` (or ``query_fn(ctx, session)``) runs on a
@@ -351,7 +365,8 @@ class ServeRuntime:
         ``serve_max_readmissions`` budget; ``priority`` is the SLA
         class — higher classes overtake the admission queue and keep
         spill-store residency longer, and the front door sheds lower
-        classes first under degradation."""
+        classes first under degradation; ``trace_sid`` is the id its
+        spans carry (default: the session id)."""
         # benign race: monotonic flag — a submit that slips past a
         # concurrent shutdown is cancelled by the drain it races
         if self._shutdown:  # graftlint: guarded-by(_lock)
@@ -360,7 +375,7 @@ class ServeRuntime:
         sess = TenantSession(self, sid, self._task_id_base + sid, tenant,
                              query_fn, est_bytes, timeout_s,
                              priority=priority, store=self.store,
-                             epoch=self.epoch)
+                             epoch=self.epoch, trace_sid=trace_sid)
         with self._lock:
             self._sessions.append(sess)
         t = threading.Thread(target=self._run_session, args=(sess,),
@@ -535,9 +550,10 @@ class ServeRuntime:
             n_params = len(inspect.signature(sess.query_fn).parameters)
         except (TypeError, ValueError):
             n_params = 1
-        if n_params >= 2:
-            return sess.query_fn(ctx, sess)
-        return sess.query_fn(ctx)
+        with sess._span("worker.run"):
+            if n_params >= 2:
+                return sess.query_fn(ctx, sess)
+            return sess.query_fn(ctx)
 
     def _admit(self, sess: TenantSession) -> AdmissionTicket:
         _admit_probe()  # chaos boundary: a kill while still queued
@@ -547,7 +563,8 @@ class ServeRuntime:
         # deadlock scan counts queued tenants as blocked.  The session
         # stays enqueued by (priority, arrival) for the whole wait —
         # re-admissions keep their original arrival rank.
-        with ThreadStateRegistry.blocked_section():
+        with sess._span("worker.admit_wait"), \
+                ThreadStateRegistry.blocked_section():
             got = self._slots.acquire(sess.priority, sess.session_id,
                                       deadline, sess._check_cancelled)
         if got:
@@ -563,19 +580,20 @@ class ServeRuntime:
         reservation (halving ``granted_bytes``).  The probe charge is
         returned on success — actual residency is accounted by the
         query's own charges."""
-        est = sess.est_bytes
-        if est <= 0:
-            sess.granted_bytes = 0
-            return
-        granted = [est]
+        with sess._span("worker.reserve"):
+            est = sess.est_bytes
+            if est <= 0:
+                sess.granted_bytes = 0
+                return
+            granted = [est]
 
-        def probe():
-            return ctx.charge(granted[0])
+            def probe():
+                return ctx.charge(granted[0])
 
-        def split():
-            granted[0] = max(granted[0] // 2, _MIN_GRANT)
+            def split():
+                granted[0] = max(granted[0] // 2, _MIN_GRANT)
 
-        n = run_with_retry(probe, split=split, max_retries=16,
-                           cancel_check=sess._check_cancelled)
-        ctx.release(n)
-        sess.granted_bytes = granted[0]
+            n = run_with_retry(probe, split=split, max_retries=16,
+                               cancel_check=sess._check_cancelled)
+            ctx.release(n)
+            sess.granted_bytes = granted[0]

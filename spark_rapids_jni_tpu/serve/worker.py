@@ -91,6 +91,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from .. import profiler
+
 _QUERY_KINDS: Dict[str, Callable] = {}
 
 _WEDGED = threading.Event()
@@ -344,7 +346,9 @@ class _SupervisorLink:
         # the backend starts before the first dial: a worker that cannot
         # have the device its environment names dies here, in worker.log,
         # instead of after it was given a query
-        extra = {"backend": jax.default_backend()}
+        dev = jax.devices()[0]
+        extra = {"backend": jax.default_backend(),
+                 "platform": dev.platform, "device_kind": dev.device_kind}
         t = self._wire.connect(self.kind, self.address, role="wk",
                                timeout_s=2.0)
         if self.active_sids_fn is not None:
@@ -401,7 +405,12 @@ class _SupervisorLink:
         itself via SCM_RIGHTS.  On success the worker's fd copy closes —
         the receiver holds the segment now.  A failed delivery requeues
         the whole job; the supervisor's sid dedup makes the eventual
-        re-send at-least-once with exactly-once effect."""
+        re-send at-least-once with exactly-once effect.
+
+        A result's ``stages`` gain ``worker.send`` here: the time the
+        payload's chunk frames took to go out, which is all of the send
+        a frame can carry about itself (0 on the ``shm`` plane, where
+        the descriptor carries an fd and nothing goes before it)."""
         with self._lock:
             t = self._t
             if t is None:
@@ -409,10 +418,15 @@ class _SupervisorLink:
                     self._unsent.append((msg, fds, chunks))
                 return False
         try:
+            t0 = time.perf_counter_ns()
             if chunks:
                 sid = int(msg["sid"])
                 for seq, c in enumerate(chunks):
                     t.send_data(sid, seq, c)
+            if "stages" in msg:
+                msg["stages"]["worker.send"] = profiler.note(
+                    "worker.send", msg.get("sid"), t0,
+                    time.perf_counter_ns())
             if fds:
                 t.send_with_fds(msg, fds)
             else:
@@ -651,10 +665,12 @@ def main(argv=None) -> int:
         except on the loud-capped ``json`` fallback."""
         from ..columnar import arrow as arrow_mod
 
-        payload, fp = arrow_mod.batch_to_ipc(batch)
+        with profiler.span("worker.batch_to_ipc"):
+            payload, fp = arrow_mod.batch_to_ipc(batch)
         view = memoryview(payload)
         chunk_bytes = max(1, int(args.segment_bytes))
-        crcs = dp.chunk_crcs(view, chunk_bytes)
+        with profiler.span("worker.crc"):
+            crcs = dp.chunk_crcs(view, chunk_bytes)
         torn_at: Optional[int] = None
         try:
             data_write_probe()
@@ -676,11 +692,12 @@ def main(argv=None) -> int:
             desc["epoch"] = stale
             desc["seg"] = dp.segment_name(args.worker_id, stale, 0)
         if plane == "shm":
-            fd = dp.make_segment(name, view)
-            if torn_at is not None:
-                b = os.pread(fd, 1, torn_at)
-                os.pwrite(fd, bytes([b[0] ^ 0xFF]), torn_at)
-            dp.seal_segment(fd)
+            with profiler.span("worker.segment"):
+                fd = dp.make_segment(name, view)
+                if torn_at is not None:
+                    b = os.pread(fd, 1, torn_at)
+                    os.pwrite(fd, bytes([b[0] ^ 0xFF]), torn_at)
+                dp.seal_segment(fd)
             desc["fds"] = 1
             return desc, [fd], None
         raw = bytearray(view)
@@ -704,10 +721,16 @@ def main(argv=None) -> int:
                 msg = {"op": "result", "sid": sid, "ok": True,
                        "status": sess.status}
                 if dp.is_batch(sess.result_value):
-                    msg["data"], fds, chunks = encode_batch_result(
-                        sid, sess.result_value)
+                    with sess._span("worker.encode"):
+                        msg["data"], fds, chunks = encode_batch_result(
+                            sid, sess.result_value)
                 else:
                     msg["value"] = sess.result_value
+                # this session's stage milliseconds, for the front
+                # door's timeline: admit_wait, reserve, run, encode (and
+                # send, added as the frame goes out)
+                msg["stages"] = {k: round(v, 4)
+                                 for k, v in sess.stages.items()}
             else:
                 msg = {"op": "result", "sid": sid, "ok": False,
                        "status": sess.status,
@@ -758,7 +781,7 @@ def main(argv=None) -> int:
             sess = runtime.submit(
                 query, est_bytes=int(msg.get("est_bytes") or 0),
                 tenant=msg.get("tenant"), timeout_s=msg.get("timeout_s"),
-                priority=int(msg.get("priority") or 0))
+                priority=int(msg.get("priority") or 0), trace_sid=sid)
         except BaseException as e:
             link.send({
                 "op": "result", "sid": sid, "ok": False, "status": "failed",

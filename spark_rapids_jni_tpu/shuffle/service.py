@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
-from .. import config, faultinj
+from .. import config, faultinj, profiler
 from ..columnar.column import ColumnBatch
 from ..columnar.encoded import (
     PACKED_COLUMNS,
@@ -134,14 +134,15 @@ class ShuffleResult:
 
 def _map_local(b: ColumnBatch, pid, P: int):
     """Shared map-side body: route OOB → regroup dest-major → count."""
-    pid, n_oob = route_out_of_range(pid, P)
-    perm = regroup_order(pid, P + 1)
-    pid_sorted = jnp.take(pid, perm)
-    counts = jax.ops.segment_sum(
-        jnp.ones(pid.shape, jnp.int32), pid_sorted, num_segments=P + 1,
-        indices_are_sorted=True,
-    )[:P]
-    return gather_batch(b, perm), counts[None], n_oob[None]
+    with profiler.scope("shuffle.map_regroup"):
+        pid, n_oob = route_out_of_range(pid, P)
+        perm = regroup_order(pid, P + 1)
+        pid_sorted = jnp.take(pid, perm)
+        counts = jax.ops.segment_sum(
+            jnp.ones(pid.shape, jnp.int32), pid_sorted,
+            num_segments=P + 1, indices_are_sorted=True,
+        )[:P]
+        return gather_batch(b, perm), counts[None], n_oob[None]
 
 
 @lru_cache(maxsize=None)
@@ -154,7 +155,8 @@ def _map_step_keys(mesh, axis_name, key_names, all_valid):
              out_specs=(spec, spec, spec), check_vma=False)
     def step(b: ColumnBatch, *rv):
         rv = jnp.ones((b.num_rows,), jnp.bool_) if all_valid else rv[0]
-        pid = spark_partition_id([b[k] for k in key_names], P, rv)
+        with profiler.scope("shuffle.map_partition_id"):
+            pid = spark_partition_id([b[k] for k in key_names], P, rv)
         return _map_local(b, pid, P)
 
     return jax.jit(step)
@@ -274,20 +276,23 @@ def _unpack_chunk_tree(out, occ, plan, treedef, capacity: int, refs):
     if plan is None:
         return out, occ
     leaves = []
-    for leaf, sp in zip(out, plan):
-        if sp is None:
-            leaves.append(leaf)
-            continue
-        kind, w, dts, ref_idx = sp
-        words = unpack_bits_rows(leaf, w, capacity).reshape(-1)
-        if dts == "bool":
-            leaves.append(words.astype(jnp.bool_))
-        elif kind == "bit":
-            leaves.append(words.astype(jnp.dtype(dts)))
-        else:
-            leaves.append((words.astype(jnp.int64)
-                           + jnp.int64(refs[ref_idx])).astype(jnp.dtype(dts)))
-    occv = unpack_bits_rows(occ, 1, capacity).reshape(-1).astype(jnp.bool_)
+    with profiler.scope("shuffle.slot_unpack"):
+        for leaf, sp in zip(out, plan):
+            if sp is None:
+                leaves.append(leaf)
+                continue
+            kind, w, dts, ref_idx = sp
+            words = unpack_bits_rows(leaf, w, capacity).reshape(-1)
+            if dts == "bool":
+                leaves.append(words.astype(jnp.bool_))
+            elif kind == "bit":
+                leaves.append(words.astype(jnp.dtype(dts)))
+            else:
+                leaves.append(
+                    (words.astype(jnp.int64)
+                     + jnp.int64(refs[ref_idx])).astype(jnp.dtype(dts)))
+        occv = unpack_bits_rows(occ, 1, capacity).reshape(-1).astype(
+            jnp.bool_)
     return jax.tree_util.tree_unflatten(treedef, leaves), occv
 
 
@@ -312,20 +317,22 @@ def _drain_step(mesh, axis_name, capacity, plan=None):
     def step(b: ColumnBatch, counts2d, r, *refs_args):
         counts = counts2d.reshape(-1)[:P]
         R = b.num_rows
-        offsets = jnp.cumsum(counts) - counts
-        p_ids = jnp.repeat(jnp.arange(P, dtype=jnp.int32), C)
-        c_ids = jnp.tile(jnp.arange(C, dtype=jnp.int32), P)
-        k = r * C + c_ids
-        slot_occ = k < jnp.take(counts, p_ids)
-        src = jnp.take(offsets, p_ids) + k
-        send_idx = jnp.clip(src, 0, max(R - 1, 0))
-        send = gather_batch(b, send_idx, valid=slot_occ)
+        with profiler.scope("shuffle.slot_pack"):
+            offsets = jnp.cumsum(counts) - counts
+            p_ids = jnp.repeat(jnp.arange(P, dtype=jnp.int32), C)
+            c_ids = jnp.tile(jnp.arange(C, dtype=jnp.int32), P)
+            k = r * C + c_ids
+            slot_occ = k < jnp.take(counts, p_ids)
+            src = jnp.take(offsets, p_ids) + k
+            send_idx = jnp.clip(src, 0, max(R - 1, 0))
+            send = gather_batch(b, send_idx, valid=slot_occ)
 
         def a2a(x):
-            grid = x.reshape((P, C) + x.shape[1:])
-            out = jax.lax.all_to_all(
-                grid, axis_name, split_axis=0, concat_axis=0)
-            return out.reshape((P * C,) + x.shape[1:])
+            with profiler.scope("shuffle.all_to_all"):
+                grid = x.reshape((P, C) + x.shape[1:])
+                out = jax.lax.all_to_all(
+                    grid, axis_name, split_axis=0, concat_axis=0)
+                return out.reshape((P * C,) + x.shape[1:])
 
         residual = jnp.maximum(counts - (r + 1) * C, 0).sum(dtype=jnp.int32)
         if plan is None:
@@ -351,12 +358,16 @@ def _pack_leaf_a2a(leaf, sp, refs, axis_name, P, C):
     through the collective (each row's lanes stay with its destination,
     so ``all_to_all`` still splits axis 0)."""
     kind, w, _dts, ref_idx = sp
-    if kind == "bit":
-        words = leaf.astype(jnp.uint32)
-    else:
-        words = (leaf.astype(jnp.int64) - refs[ref_idx]).astype(jnp.uint32)
-    lanes = pack_bits_rows(words.reshape(P, C), w)
-    return jax.lax.all_to_all(lanes, axis_name, split_axis=0, concat_axis=0)
+    with profiler.scope("shuffle.slot_pack"):
+        if kind == "bit":
+            words = leaf.astype(jnp.uint32)
+        else:
+            words = (leaf.astype(jnp.int64)
+                     - refs[ref_idx]).astype(jnp.uint32)
+        lanes = pack_bits_rows(words.reshape(P, C), w)
+    with profiler.scope("shuffle.all_to_all"):
+        return jax.lax.all_to_all(lanes, axis_name, split_axis=0,
+                                  concat_axis=0)
 
 
 # traces of the streaming drain program, bumped INSIDE the traced body
@@ -443,17 +454,19 @@ def _scatter_step(mesh, axis_name, capacity, engine="lax"):
                 my_base.astype(jnp.int32), r, P, C)
             return jax.tree_util.tree_unflatten(treedef, new_leaves), new_occ
         M = morsel.num_rows
-        ends = jnp.cumsum(cnts)
-        offs = ends - cnts
-        i = jnp.arange(M, dtype=jnp.int32)
-        d = jnp.searchsorted(ends, i, side="right").astype(jnp.int32)
-        d_c = jnp.minimum(d, P - 1)
-        k = jnp.take(my_base, d_c) + (i - jnp.take(offs, d_c))
-        in_round = (d < P) & (k >= r * C) & (k < (r + 1) * C)
-        t = jnp.where(in_round, d_c * C + (k - r * C), P * C)
-        new_chunk = jax.tree_util.tree_map(
-            lambda acc, x: acc.at[t].set(x, mode="drop"), chunk, morsel)
-        new_occ = occv.at[t].set(True, mode="drop")
+        with profiler.scope("shuffle.slot_pack"):
+            ends = jnp.cumsum(cnts)
+            offs = ends - cnts
+            i = jnp.arange(M, dtype=jnp.int32)
+            d = jnp.searchsorted(ends, i, side="right").astype(jnp.int32)
+            d_c = jnp.minimum(d, P - 1)
+            k = jnp.take(my_base, d_c) + (i - jnp.take(offs, d_c))
+            in_round = (d < P) & (k >= r * C) & (k < (r + 1) * C)
+            t = jnp.where(in_round, d_c * C + (k - r * C), P * C)
+            new_chunk = jax.tree_util.tree_map(
+                lambda acc, x: acc.at[t].set(x, mode="drop"), chunk,
+                morsel)
+            new_occ = occv.at[t].set(True, mode="drop")
         return new_chunk, new_occ
 
     return jax.jit(step)
@@ -479,10 +492,11 @@ def _stream_drain_step(mesh, axis_name, capacity, plan=None):
         _STREAM_DRAIN_TRACES[0] += 1
 
         def a2a(x):
-            grid = x.reshape((P, C) + x.shape[1:])
-            out = jax.lax.all_to_all(
-                grid, axis_name, split_axis=0, concat_axis=0)
-            return out.reshape((P * C,) + x.shape[1:])
+            with profiler.scope("shuffle.all_to_all"):
+                grid = x.reshape((P, C) + x.shape[1:])
+                out = jax.lax.all_to_all(
+                    grid, axis_name, split_axis=0, concat_axis=0)
+                return out.reshape((P * C,) + x.shape[1:])
 
         if plan is None:
             out = jax.tree_util.tree_map(a2a, chunk)
@@ -655,7 +669,8 @@ class ShuffleService:
                 f"(strict mode; ids must lie in [0, {P}])")
 
         # 2. plan: static (rounds, capacity) from the exact counts
-        plan = plan_rounds(counts_np, round_rows=round_rows)
+        with profiler.span("shuffle.plan_rounds"):
+            plan = plan_rounds(counts_np, round_rows=round_rows)
 
         # 2b. wire plan: which leaves cross the collective bit-packed
         compress = str(config.get("shuffle_compress") or "auto").lower()
@@ -946,6 +961,10 @@ class ShuffleService:
             contribs[rr] = []
 
         def _drain_round(rr):
+            with profiler.span("shuffle.round", round=rr):
+                _drain_round_body(rr)
+
+        def _drain_round_body(rr):
             nonlocal received, bytes_moved, compressed_saved
             chunk = send_chunks[rr]
 
@@ -1199,10 +1218,12 @@ class ShuffleService:
             res_n = int(np.asarray(jax.device_get(residual)).sum())
             return out, occ, got_n, res_n
 
-        for attempt in range(_IO_RETRIES + 1):
-            try:
-                return run_with_retry(round_step)
-            except faultinj.ShuffleIOError:
-                self.registry.metrics.record_io_failure()
-                if attempt == _IO_RETRIES:
-                    raise
+        # one host-driven drain round, retries and all
+        with profiler.span("shuffle.round", round=r):
+            for attempt in range(_IO_RETRIES + 1):
+                try:
+                    return run_with_retry(round_step)
+                except faultinj.ShuffleIOError:
+                    self.registry.metrics.record_io_failure()
+                    if attempt == _IO_RETRIES:
+                        raise

@@ -103,9 +103,9 @@ class TestXplaneDecode:
         assert any(e["dur_us"] > 0 and not e["name"].startswith("event:")
                    for e in xev), xev[:5]
 
-    def test_trace_range_names_appear(self, tmp_path):
-        """with trace_range(name): ... must annotate the capture (the
-        NVTX-range analogue, SURVEY §5 tracing)."""
+    def test_span_names_appear(self, tmp_path):
+        """with span(name, sid=...): ... must annotate the capture (the
+        NVTX-range analogue, SURVEY §5 tracing) and carry the query id."""
         import jax
         import jax.numpy as jnp
 
@@ -113,17 +113,19 @@ class TestXplaneDecode:
             FileWriter,
             Profiler,
             convert_profile,
-            trace_range,
+            span,
         )
 
         cap = str(tmp_path / "cap2.bin")
         w = FileWriter(cap)
         Profiler.init(w)
         Profiler.start()
-        with trace_range("srj_stage_filter"):
+        with span("srj_stage_filter", sid=5):
             jax.block_until_ready(jax.jit(lambda x: x + 1)(jnp.arange(64)))
         Profiler.stop()
         Profiler.shutdown()
         w.close()
         events = convert_profile(cap)
         assert any("srj_stage_filter" in e["name"] for e in events)
+        assert any(e["name"] == "srj_stage_filter" and e.get("sid") == 5
+                   for e in events)
