@@ -375,7 +375,8 @@ def phase_b(args, digest_a, arrow_a):
     # The default q6_float_mode (f32x3) splits price into three f32 limbs
     # exactly and sums each in f32 on the MXU: avg(price) read 1.5804e-05
     # relative off numpy on the chip at 2^24 rows, seed 0 (chip run, PR 24).
-    # Spark's own answer is f64: that is q6_float_mode=f64, checked last.
+    # Spark's own answer is the exact double sum: q6_float_mode=f64 (the
+    # doubles as fixed-point digits on the int8 contraction), checked last.
     q6_plan_against_numpy("B_plan_q6", 1e-4)
 
     nq = 1 << args.log2["q95"]

@@ -255,9 +255,11 @@ _register("adaptive_execution", True, _parse_bool,
           "ShuffleMetrics.  Off = the static defaults everywhere "
           "(shuffled joins, knob-resolved engines).")
 _register("q6_float_mode", "f32x3", str,
-          "Float-sum mode for the q6 onehot path: 'f32x3' (exact Dekker "
-          "split, MXU-native, order-nondeterministic rounding) or 'f64' "
-          "(emulated f64 contraction, sort-path-compatible rounding).")
+          "Float-sum mode for the q6 onehot path: 'f64' (the exact sum: "
+          "the doubles ride the int8 contraction as fixed-point digits, "
+          "Spark's double average to the last places) or 'f32x3' (Dekker "
+          "split accumulated in f32 on the MXU, about 5e-5 relative off; "
+          "no faster since the digits, kept as the benchmark's control).")
 _register("serve_max_concurrent", 4, int,
           "Admission slots of the serving runtime (serve/runtime.py): "
           "how many tenant queries may hold a TaskContext at once; the "

@@ -209,7 +209,7 @@ def fleet_summary() -> dict:
 # the tracer: host spans under a query id, device scopes
 # ---------------------------------------------------------------------------
 
-RING_SPANS = 4096
+RING_SPANS = 131072
 
 Span = collections.namedtuple("Span", "name sid parent t0_ns t1_ns")
 

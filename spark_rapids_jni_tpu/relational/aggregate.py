@@ -763,11 +763,24 @@ def group_by_onehot(
       limbs ``b_l - 128`` (exact: true limb sums are rebuilt with
       ``+128*count`` and recombined in uint64 with Spark's non-ANSI
       wraparound).  One HBM pass over the one-hot instead of one per agg;
-    * float sums ride ONE f32 contraction in ``f32x3`` mode (exact 3-way
-      Dekker split of the f64 mantissa — MXU-native, accumulation
-      rounding inside Spark's order-nondeterminism) or one emulated-f64
-      contraction in ``f64`` mode (slow on TPU but rounding-compatible
-      with the sort-scan path);
+    * float sums in ``f64`` mode ride the SAME int8 contraction as exact
+      fixed-point digits: per column the grid is ``2^(emax - 1075 - 38)``
+      (``emax`` the largest live exponent), a row is 13 signed digits of
+      7 bits plus three NaN/+inf/-inf flags (16 more slots), and the
+      double is put together from the digit sums in
+      ``agg.onehot_rebuild``.  Integer sums are exact on the MXU in any
+      order, so the bucket's sum is the exact sum of its rows, each
+      truncated toward zero on the grid (less than one grid unit a row
+      and limb: over n <= 2^24 rows under 2^-13 ulp of the column's
+      largest magnitude), rounded once — tighter than any order of f64
+      additions and bit-identical under any permutation of the rows.
+      Where nothing is truncated (a column within 2^38 of its largest
+      value, q6's prices) it is ``math.fsum``.  Unlike IEEE addition a
+      bucket of nothing but ``-0.0`` sums to ``+0.0``; one NaN or
+      infinite row touches its own bucket only;
+    * float sums in ``f32x3`` mode ride ONE f32 contraction (exact 3-way
+      Dekker split of the f64 mantissa — MXU-native, but accumulated in
+      f32: about 5e-5 relative off at q6's size);
     * mean: sum / count in f64.
 
     min/max and multi-column keys stay on the sort-scan path.  Returns
@@ -918,18 +931,11 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
             dec_slot[c] = len(cols8)
             cols8.extend(x[:, j] for j in range(16))
             cols8.append(neg)
-        X8 = jnp.stack(cols8, axis=1)  # [n, m8]
 
     def dekker_limbs(c):
-        """Exact 3-way split of a masked f64 column into f32 (hi, mid, lo)."""
         vcol = batch[c]
-        vvalid = vcol.validity & row_live
-        v = jnp.where(vvalid, vcol.data.astype(jnp.float64), 0.0)
-        hi = v.astype(jnp.float32)
-        r1 = v - hi.astype(jnp.float64)
-        mid = r1.astype(jnp.float32)
-        lo_ = (r1 - mid.astype(jnp.float64)).astype(jnp.float32)
-        return [hi, mid, lo_]
+        return _dekker_limbs(jnp.where(vcol.validity & row_live,
+                                       vcol.data.astype(jnp.float64), 0.0))
 
     if engine not in ("xla", "pallas"):
         raise ValueError(f"unknown engine {engine!r} "
@@ -942,16 +948,32 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
     use_f32x3 = float_mode == "f32x3" or engine == "pallas"
 
     F = None
+    digits_of = {}  # f64 mode: column -> (int8[n, _FX_SLOTS], emax)
     with scope("agg.onehot_payload"):
-        if float_cols:
-            if use_f32x3:
-                F = jnp.stack(
-                    sum((dekker_limbs(c) for c in float_cols), []), axis=1)
-            else:
-                F = jnp.stack(
-                    [jnp.where(batch[c].validity & row_live,
-                               batch[c].data.astype(jnp.float64), 0.0)
-                     for c in float_cols], axis=1)
+        if float_cols and use_f32x3:
+            F = jnp.stack(
+                sum((dekker_limbs(c) for c in float_cols), []), axis=1)
+        elif float_cols:
+            # f64 mode: a double is a fixed-point number on a grid chosen
+            # from the column, and its digits are more int8 slots
+            with scope("agg.onehot_digits"):
+                for c in float_cols:
+                    digits_of[c] = _float_digit_slots(
+                        batch[c].data.astype(jnp.float64),
+                        batch[c].validity & row_live)
+        X8 = jnp.stack(cols8, axis=1)  # [n, m8]
+        digit_slot = {c: len(cols8) + _FX_SLOTS * i
+                      for i, c in enumerate(digits_of)}
+        if digits_of:
+            # side by side as zero-padded terms of one sum, which the TPU
+            # compiler folds into the contraction's operand; a concatenate
+            # of int8 pieces is a relayout pass of its own there
+            m8 = len(cols8) + _FX_SLOTS * len(digits_of)
+            X8 = jnp.pad(X8, ((0, 0), (0, m8 - len(cols8))))
+            for c, (digits, _) in digits_of.items():
+                s = digit_slot[c]
+                X8 = X8 + jnp.pad(digits,
+                                  ((0, 0), (s, m8 - s - _FX_SLOTS)))
 
     if engine == "pallas":
         from ..ops.pallas_kernels import onehot_groupby_parts
@@ -963,21 +985,14 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
         # Chunked contractions with the one-hot built PER CHUNK: int32
         # partials hold |x| <= 128 summed over a block, so blocks stay
         # under 2^31/128 = 2^24 rows — and only one [B, K+1] one-hot is
-        # ever live (a full-width [n, K+1] float one-hot is multi-GB at
-        # bench row counts; the f64-emulated contraction of one OOM'd
-        # real v5e HBM at 16M rows in round 3).  Static n means static
-        # slices, combined in int64/float64 across chunks.  The f64
-        # contraction is emulated with eight f32 [K+1, B] operands: at
-        # K=100 a 2^23-row block of them is 27.9 GB, which the v5e
-        # compiler refuses; 2^20-row blocks need 6.87 GB at 2^24 rows.
-        fdt = jnp.float32 if use_f32x3 else jnp.float64
-        B = 1 << 23 if fdt == jnp.float32 or not float_cols else 1 << 20
+        # ever live (a full-width [n, K+1] one-hot is multi-GB at bench
+        # row counts).  Static n means static slices, combined in
+        # int64/float64 across chunks.
+        B = 1 << 23
         kids = jnp.arange(K + 1, dtype=jnp.int32)[None, :]
         part = jnp.zeros((K + 1, X8.shape[1]), jnp.int64)
         fpart = (jnp.zeros((K + 1, F.shape[1]), jnp.float64)
-                 if float_cols else None)
-        fscope = ("agg.onehot_contract_f32x3" if use_f32x3
-                  else "agg.onehot_contract_f64")
+                 if F is not None else None)
         for lo in range(0, n, B):
             with scope("agg.onehot_build"):
                 ohc = ((bucket[lo:lo + B, None] == kids)
@@ -988,12 +1003,12 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.int32,
                 ).astype(jnp.int64)
-            if float_cols:
-                with scope(fscope):
+            if F is not None:
+                with scope("agg.onehot_contract_f32x3"):
                     fpart = fpart + jax.lax.dot_general(
-                        ohc.astype(fdt).T, F[lo:lo + B],
+                        ohc.astype(jnp.float32).T, F[lo:lo + B],
                         (((1,), (0,)), ((), ())),
-                        preferred_element_type=fdt,
+                        preferred_element_type=jnp.float32,
                     ).astype(jnp.float64)
 
     with scope("agg.onehot_rebuild"):
@@ -1003,7 +1018,9 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
                 fsum_of[c] = (fpart[:, 3 * i] + fpart[:, 3 * i + 1]
                               + fpart[:, 3 * i + 2])
             else:
-                fsum_of[c] = fpart[:, i]
+                s = digit_slot[c]
+                fsum_of[c] = _float_sums_from_digits(
+                    part[:, s:s + _FX_SLOTS], digits_of[c][1])
 
         counts_star = part[:, 0]
         cnt_of = {c: part[:, s] for c, s in valid_slot.items()}
@@ -1056,6 +1073,189 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
     parts = {"star": counts_star, "cnt": cnt_of, "isum": isum_of,
              "fsum": fsum_of, "d64": d64_of}
     return parts, overflow
+
+
+# Fixed-point layout of one float sum column on the int8 contraction: the
+# largest live value's 53-bit significand sits at the top of a field of
+# _FX_GUARD + 53 = 91 bits, cut into 13 digits of 7 bits; three more slots
+# count the column's NaN, +inf and -inf rows.
+_FX_GUARD = 38
+_FX_DIGITS = 13
+_FX_SLOTS = _FX_DIGITS + 3
+
+
+def _dekker_limbs(v):
+    """Exact 3-way split of float64 rows into f32 (hi, mid, lo)."""
+    hi = v.astype(jnp.float32)
+    r1 = v - hi.astype(jnp.float64)
+    mid = r1.astype(jnp.float32)
+    lo = (r1 - mid.astype(jnp.float64)).astype(jnp.float32)
+    return [hi, mid, lo]
+
+
+def _f64_fields(v):
+    """float64 rows read from their IEEE bits: ``(neg, e, top, lo, special,
+    nan)`` with ``e`` the biased exponent (a subnormal counts as 1) and
+    ``top:lo`` the 53-bit significand as 21 + 32 bits."""
+    u32 = jnp.uint32
+    w = jax.lax.bitcast_convert_type(v, jnp.uint64)
+    lo, hi = w.astype(u32), (w >> jnp.uint64(32)).astype(u32)
+    e = ((hi >> u32(20)) & u32(0x7FF)).astype(jnp.int32)
+    frac = hi & u32(0xFFFFF)
+    special = e == 0x7FF
+    top = frac | jnp.where(e != 0, u32(1 << 20), u32(0))
+    return ((hi >> u32(31)) != 0, jnp.maximum(e, 1), top, lo, special,
+            special & ((frac | lo) != 0))
+
+
+def _f32_fields(x):
+    """An f32 limb in the fields of the double it equals (:func:`_f64_fields`):
+    its 24-bit significand at the top of the 53, its exponent rebased."""
+    u32 = jnp.uint32
+    b = jax.lax.bitcast_convert_type(x, u32)
+    e = ((b >> u32(23)) & u32(0xFF)).astype(jnp.int32)
+    frac = b & u32(0x7FFFFF)
+    special = e == 0xFF
+    m = frac | jnp.where(e != 0, u32(1 << 23), u32(0))
+    return ((b >> u32(31)) != 0, jnp.maximum(e, 1) + (1023 - 127),
+            m >> u32(3), m << u32(29), special, special & (frac != 0))
+
+
+def _place_on_grid(top, lo, s):
+    """Three u32 words (low first) of the 96-bit field that holds the
+    significand ``top:lo`` with its hidden bit at bit 90, shifted right by
+    ``s >= 0``; what falls off the bottom is dropped."""
+    u32 = jnp.uint32
+    a2 = (top << u32(6)) | (lo >> u32(26))
+    a1 = lo << u32(6)
+    q, r = s >> 5, (s & 31).astype(u32)
+    b2 = jnp.where(q == 0, a2, u32(0))
+    b1 = jnp.where(q == 0, a1, jnp.where(q == 1, a2, u32(0)))
+    b0 = jnp.where(q == 1, a1, jnp.where(q == 2, a2, u32(0)))
+    up = u32(31) - r                                    # x << (32 - r), r = 0 safe
+    return ((b0 >> r) | ((b1 << up) << u32(1)),
+            (b1 >> r) | ((b2 << up) << u32(1)), b2 >> r)
+
+
+def _add_words(a, b, sub):
+    """``a + b``, or ``a - b`` where ``sub`` (``a >= b`` there), over u32
+    words, low first; a carry is a sum smaller than its operand."""
+    u32 = jnp.uint32
+    carry = sub.astype(u32)                             # a - b = a + ~b + 1
+    out = []
+    for x, y in zip(a, b):
+        t = x + jnp.where(sub, ~y, y)
+        r = t + carry
+        carry = ((t < x) | (r < t)).astype(u32)
+        out.append(r)
+    return tuple(out)
+
+
+def _float_digit_slots(v, ok):
+    """float64[n] -> (int8[n, ``_FX_SLOTS``], ``emax``): the rows'
+    fixed-point digits on the grid ``2^(emax - 1075 - _FX_GUARD)``.
+
+    ``emax`` is the largest biased exponent among the ``ok`` finite rows
+    (a subnormal counts as 1).  A row's significand, shifted right by
+    ``emax - e`` below the top of the field (bits that fall off the
+    bottom are dropped: truncation toward zero), is cut into 7-bit
+    digits, each times the row's sign: int8 in [-127, 127].  Rows that
+    are not ``ok`` or not finite give zeros; the last three slots flag
+    the ``ok`` rows that are NaN, +inf, -inf.  All of it is 32-bit integer
+    work on the halves of the double.
+
+    Where the backend keeps IEEE doubles their bits are read as they are.
+    The TPU's compiler keeps a double as f32 parts and refuses
+    ``bitcast f64 -> u64``: there the exact Dekker limbs (native f32, so
+    their bits can be read) are placed on the grid one by one and added
+    as 96-bit integers; each limb truncates on its own.
+    """
+    u32 = jnp.uint32
+    if jax.default_backend() == "tpu":
+        limbs = [_f32_fields(x) for x in _dekker_limbs(v)]
+    else:
+        limbs = [_f64_fields(v)]
+    neg, e, _, _, special, is_nan = limbs[0]
+    fin = ok & ~special
+    emax = jnp.max(jnp.where(fin, e, 1), initial=1)
+    words = None
+    for lneg, le, top, lo, _, _ in limbs:
+        w = _place_on_grid(jnp.where(fin, top, u32(0)),
+                           jnp.where(fin, lo, u32(0)), emax - le)
+        words = w if words is None else _add_words(words, w, lneg != neg)
+    # one [n, _FX_SLOTS] expression, no column is made on its own: slot j
+    # < 13 is bits [7j, 7j + 7) of the field (word 7j // 32, which may
+    # run into the next one), the last three are the flags
+    j = jnp.arange(_FX_SLOTS, dtype=jnp.int32)[None, :]
+    k, b = (7 * j) >> 5, ((7 * j) & 31).astype(u32)
+    w0, w1, w2 = (w[:, None] for w in words)
+    here = jnp.where(k == 0, w0, jnp.where(k == 1, w1, w2))
+    above = jnp.where(k == 0, w1, jnp.where(k == 1, w2, u32(0)))
+    d = ((here >> b) | ((above << (u32(31) - b)) << u32(1))) & u32(0x7F)
+    d = d.astype(jnp.int32)
+    d = jnp.where(neg[:, None], -d, d)
+    inf = ok & special & ~is_nan
+    flags = jnp.where(j == _FX_DIGITS, (ok & is_nan)[:, None],
+                      jnp.where(j == _FX_DIGITS + 1, (inf & ~neg)[:, None],
+                                (inf & neg)[:, None]))
+    return jnp.where(j < _FX_DIGITS, d, flags.astype(jnp.int32)).astype(
+        jnp.int8), emax
+
+
+def _float_sums_from_digits(part, emax):
+    """int64[K+1, ``_FX_SLOTS``] digit sums and counts -> float64[K+1]:
+    the exact sum of the truncated rows, rounded to nearest even once.
+
+    The digit sums are carry-normalised in int64 into ``hi * 2^63 + lo``
+    and the double is put together from the magnitude's bits with integer
+    operations alone (the top 62 bits, a sticky bit for what lies below
+    them, the grid's exponent), so the result is the same on every
+    backend whatever its float arithmetic does with subnormals.  NaN
+    rows, or +inf with -inf, make the bucket NaN; +inf or -inf alone
+    make it that.
+    """
+    i64, u64 = jnp.int64, jnp.uint64
+    carry = jnp.zeros(part.shape[:1], i64)
+    lo = jnp.zeros(part.shape[:1], i64)
+    hi = jnp.zeros(part.shape[:1], i64)
+    for j in range(_FX_DIGITS):
+        t = part[:, j] + carry
+        d, carry = t & i64(0x7F), t >> i64(7)           # floor: 0 <= d < 128
+        if j < 9:
+            lo = lo | (d << i64(7 * j))
+        else:
+            hi = hi | (d << i64(7 * (j - 9)))
+    hi = hi + (carry << i64(7 * (_FX_DIGITS - 9)))      # signed, |hi| < 2^60
+    neg = hi < 0                                        # total = hi * 2^63 + lo
+    mlo = jnp.where(neg, (-lo) & i64((1 << 63) - 1), lo).astype(u64)
+    mhi = jnp.where(neg, -hi - (lo != 0).astype(i64), hi).astype(u64)
+    # magnitude = top * 2^k (+ sticky), top its leading 62 bits
+    k = jnp.where(mhi != 0, i64(65) - jax.lax.clz(mhi).astype(i64),
+                  (mlo >> u64(62)).astype(i64))
+    ku = k.astype(u64)
+    top = jnp.where(mhi != 0, mhi << (u64(63) - ku), u64(0)) | (mlo >> ku)
+    sticky = (mlo & ((u64(1) << ku) - u64(1))) != 0
+    # the leading bit's exponent, and how many of top's bits lie under
+    # the double's last place (2^-1074 at the least): drop > 0 rounds
+    ex = k + emax.astype(i64) - i64(1075 + _FX_GUARD)
+    be = ex + (i64(64) - jax.lax.clz(top).astype(i64)) - i64(1) + i64(1023)
+    drop = jnp.maximum(be - i64(1075), i64(-1074)) - ex
+    dr = jnp.clip(drop, 0, 63).astype(u64)
+    q = jnp.where(drop > 0, top >> dr,
+                  top << jnp.clip(-drop, 0, 63).astype(u64))
+    rem, half = top & ((u64(1) << dr) - u64(1)), (u64(1) << dr) >> u64(1)
+    up = (drop > 0) & ((rem > half)
+                       | ((rem == half) & (sticky | ((q & u64(1)) != 0))))
+    # the hidden bit of q carries into the exponent field, and so does a
+    # mantissa that rounds up to the next power of two (or to inf)
+    bits = (((jnp.maximum(be, 1) - i64(1)).astype(u64) << u64(52))
+            + q + up.astype(u64))
+    bits = jnp.where(be >= 2047, u64(0x7FF << 52), bits)
+    bits = jnp.where(top == 0, u64(0), bits | (neg.astype(u64) << u64(63)))
+    f = jax.lax.bitcast_convert_type(bits, jnp.float64)
+    nan, pinf, ninf = (part[:, _FX_DIGITS + i] > 0 for i in range(3))
+    f = jnp.where(pinf, jnp.inf, jnp.where(ninf, -jnp.inf, f))
+    return jnp.where(nan | (pinf & ninf), jnp.nan, f)
 
 
 def _domain_bucket_overflow(col, live, K):

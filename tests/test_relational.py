@@ -845,6 +845,147 @@ class TestGroupByOnehot:
             assert math.isclose(a, b, rel_tol=1e-5)
 
 
+_EXACT_SUM_CASES = [
+    "q6_shape", "spread_2e80_both_signs", "cancels_to_near_zero",
+    "subnormals_only", "float32_column", "nulls_null_keys_row_valid",
+    "nan_and_infs_in_their_own_buckets", "permuted_rows_same_bits",
+    "all_null_and_all_dead"]
+
+
+def _exact_sum_case(name, rng):
+    """(keys, values, value validity, key validity, row_valid, dtype) of one
+    case of :class:`TestOnehotExactFloatSums`; 10 keys, every bucket holds
+    some of the column's largest magnitudes."""
+    n = 3000
+    k = rng.integers(0, 10, n)
+    ones = np.ones(n, bool)
+    if name == "q6_shape":
+        x = rng.random(n) * 100
+        return k, x, ones, ones, x < 50.0, T.FLOAT64
+    if name == "spread_2e80_both_signs":
+        x = (rng.uniform(1, 2, n) * np.exp2(rng.integers(-40, 41, n))
+             * rng.choice([-1.0, 1.0], n))
+        return k, x, ones, ones, None, T.FLOAT64
+    if name == "cancels_to_near_zero":
+        h = n // 2
+        x = rng.uniform(-100, 100, n)
+        x[h:] = -x[:h]
+        k[h:] = k[:h]
+        x[::7] += rng.uniform(-1e-9, 1e-9, len(x[::7]))
+        return k, x, ones, ones, None, T.FLOAT64
+    if name == "subnormals_only":
+        x = (rng.integers(0, 1 << 52, n).astype(np.uint64).view(np.float64)
+             * rng.choice([-1.0, 1.0], n))
+        return k, x, ones, ones, None, T.FLOAT64
+    if name == "float32_column":
+        x = (rng.random(n) * 1e4).astype(np.float32)
+        return k, x, ones, ones, None, T.FLOAT32
+    if name == "nulls_null_keys_row_valid":
+        x = rng.normal(0, 1e6, n)
+        return (k, x, rng.random(n) > 0.2, rng.random(n) > 0.1,
+                rng.random(n) > 0.3, T.FLOAT64)
+    if name == "nan_and_infs_in_their_own_buckets":
+        x = rng.random(n) * 100
+        k = rng.integers(0, 6, n)
+        x[:8] = [np.nan, 1.0, np.inf, 2.0, -np.inf, 3.0, np.inf, -np.inf]
+        k[:8] = [6, 6, 7, 7, 8, 8, 9, 9]
+        return k, x, ones, ones, None, T.FLOAT64
+    raise KeyError(name)
+
+
+def _onehot_float_sums(k, x, xvalid, kvalid, row_valid, dtype):
+    """group_by_onehot(engine="xla", float_mode="f64") -> {key: (sum or
+    None, count(x), count(*))}; a null key is ``None``."""
+    from spark_rapids_jni_tpu.relational.aggregate import group_by_onehot
+
+    batch = ColumnBatch({
+        "k": Column(jnp.asarray(np.asarray(k, np.int32)),
+                    jnp.asarray(kvalid), T.INT32),
+        "x": Column(jnp.asarray(x), jnp.asarray(xvalid), dtype)})
+    res, ng, ovf = group_by_onehot(
+        batch, "k", [AggSpec("sum", "x", "s"), AggSpec("count", "x", "cx"),
+                     AggSpec("count", None, "c")], 10,
+        row_valid=None if row_valid is None else jnp.asarray(row_valid),
+        float_mode="f64", engine="xla")
+    assert not bool(ovf)
+    g = int(ng)
+    cols = {c: res[c].to_pylist()[:g] for c in ("k", "s", "cx", "c")}
+    return {cols["k"][i]: (cols["s"][i], cols["cx"][i], cols["c"][i])
+            for i in range(g)}
+
+
+class TestOnehotExactFloatSums:
+    """``float_mode="f64"`` on the one-hot engine: a double sum is fixed-point
+    digits on the int8 contraction, so a bucket's sum is ``math.fsum`` of
+    its rows (to the last place: rows far under the column's largest are
+    truncated on the grid) whatever the order.  ``bits`` reads IEEE doubles
+    as the CPU keeps them; ``limbs`` is the route the TPU takes, where a
+    double is f32 parts (so nothing outside f32's exponent range)."""
+
+    # a double of f32 parts has no f64 subnormals: not a case of "limbs"
+    @pytest.mark.parametrize("route,case", [
+        (r, c) for r in ("bits", "limbs") for c in _EXACT_SUM_CASES
+        if (r, c) != ("limbs", "subnormals_only")])
+    def test_bucket_sums_equal_fsum(self, route, case, monkeypatch):
+        if route == "limbs":
+            import jax
+
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        rng = np.random.default_rng(27)
+        if case == "permuted_rows_same_bits":
+            k, x, xv, kv, rv, dt = _exact_sum_case(
+                "spread_2e80_both_signs", rng)
+            a = _onehot_float_sums(k, x, xv, kv, rv, dt)
+            p = rng.permutation(len(k))
+            b = _onehot_float_sums(k[p], x[p], xv[p], kv[p], rv, dt)
+            assert a.keys() == b.keys()
+            for key in a:
+                assert np.float64(a[key][0]).tobytes() \
+                    == np.float64(b[key][0]).tobytes(), key
+            return
+        if case == "all_null_and_all_dead":
+            k, x, xv, kv, rv, dt = _exact_sum_case("q6_shape", rng)
+            got = _onehot_float_sums(k, x, ~xv, kv, None, dt)
+            assert len(got) == 10
+            assert all(v[0] is None and v[1] == 0 and v[2] > 0
+                       for v in got.values())
+            from spark_rapids_jni_tpu.relational.aggregate import (
+                _domain_partials)
+
+            batch = ColumnBatch({
+                "k": Column(jnp.asarray(k, jnp.int32), jnp.asarray(kv),
+                            T.INT32),
+                "x": Column(jnp.asarray(x), jnp.asarray(xv), dt)})
+            parts, _ = _domain_partials(
+                batch, "k", [AggSpec("sum", "x", "s")], 10,
+                jnp.zeros((len(k),), jnp.bool_), "xla", "f64")
+            assert np.asarray(parts["fsum"]["x"]).tobytes() \
+                == np.zeros(11).tobytes()
+            assert not np.asarray(parts["star"]).any()
+            return
+        k, x, xv, kv, rv, dt = _exact_sum_case(case, rng)
+        got = _onehot_float_sums(k, x, xv, kv, rv, dt)
+        live = np.ones(len(k), bool) if rv is None else rv
+        keys = {None if not kv[i] else int(k[i])
+                for i in range(len(k)) if live[i]}
+        assert set(got) == keys
+        for key in keys:
+            rows = live & (~kv if key is None else kv & (k == key))
+            vals = [float(v) for v in x[rows & xv]]
+            s, cx, c = got[key]
+            assert (cx, c) == (len(vals), int(rows.sum())), key
+            if not vals:
+                assert s is None
+            elif any(math.isnan(v) for v in vals) or (
+                    math.inf in vals and -math.inf in vals):
+                assert math.isnan(s), (key, s)
+            elif math.inf in vals or -math.inf in vals:
+                assert s == (math.inf if math.inf in vals else -math.inf)
+            else:
+                want = math.fsum(vals)
+                assert abs(s - want) <= math.ulp(want), (key, s, want)
+
+
 class TestOuterJoins:
     """right/full outer joins vs a pandas-style python oracle."""
 

@@ -197,10 +197,26 @@ class TestScopes:
             config.reset()
         want = {"plan.filter.price", "plan.aggregate.k",
                 "agg.onehot_bucket", "agg.onehot_payload",
-                "agg.onehot_build", "agg.onehot_contract_int8",
-                "agg.onehot_contract_f64", "agg.onehot_rebuild",
+                "agg.onehot_digits", "agg.onehot_build",
+                "agg.onehot_contract_int8", "agg.onehot_rebuild",
                 "agg.finalize"}
         assert want <= got, sorted(want - got)
+        assert "agg.onehot_contract_f64" not in got
+
+    def test_q6_plan_under_f64_contracts_no_f64_operand(self):
+        """The double sum rides the int8 contraction as digits: the q6
+        plan holds dot_generals, and none of them sees an f64."""
+        config.set("q6_float_mode", "f64")
+        config.set("q6_onehot_engine", "xla")
+        try:
+            inputs = _q6_inputs()
+            cp = plan.compile_plan(queries.q6_plan(), inputs)
+            text = cp.fn.lower(inputs, ()).as_text()
+        finally:
+            config.reset()
+        dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+        assert dots
+        assert not [ln for ln in dots if "f64" in ln], dots
 
     def test_q95_plan_names_every_node_and_the_join_phases(self):
         config.set("join_engine", "sort")       # what auto is on the chip

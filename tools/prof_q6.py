@@ -75,7 +75,7 @@ AGGS = [AggSpec("sum", "v", "sum_v"), AggSpec("count", None, "cnt"),
 bench("onehot_xla_f32x3", lambda b: group_by_onehot(
     b, "k", AGGS, 100, row_valid=b["price"].data < 50.0,
     float_mode="f32x3"))
-bench("onehot_xla_f64", lambda b: group_by_onehot(
+bench("onehot_xla_f64_digits", lambda b: group_by_onehot(
     b, "k", AGGS, 100, row_valid=b["price"].data < 50.0,
     float_mode="f64"))
 bench("onehot_pallas", lambda b: group_by_onehot(
