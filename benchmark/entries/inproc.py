@@ -27,6 +27,7 @@ def run(ctx):
         trace.start(trace_dir)
     spans = lib.Spans()
     traces0 = plan.trace_count()
+    lib.settle_gc()
     t_setup_done = time.monotonic()
     records, t0, t1 = window.run_window(
         traffic, ctx["seconds"], order,
@@ -36,10 +37,8 @@ def run(ctx):
     counters = {"plan_retraces": plan.trace_count() - traces0,
                 "plan_cache": plan.plan_cache_metrics()}
     dev = lib.device_report(devs, lib.peak_bytes(devs))
-    parts = sorted({r["part"] for r in records})
-    tables = {p: state.host_tables(p) for p in parts}
-    state.free()
-    return {"records": records, "window_s": t1 - t0,
+    tables = state.tables_in_turn(sorted({r["part"] for r in records}))
+    return {"records": records, "window_s": t1 - t0, "t0": t0,
             "t_setup_done": t_setup_done, "device": dev,
             "spans": spans.export(), "counters": counters, "tables": tables,
             "table_bytes": table_bytes, "notes": {}}

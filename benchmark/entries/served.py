@@ -118,12 +118,13 @@ def _supervise(spec):
         if trace_dir:
             ask("bench_trace_start", {"dir": trace_dir})
         ask("bench_mark", {})
+        lib.settle_gc()
         out["t_setup_done"] = time.monotonic()
         records, t0, t1 = window.run_window(traffic, spec["seconds"], order,
                                             one_query)
         if trace_dir:
             ask("bench_trace_stop", {})
-        out.update(records=records, window_s=t1 - t0, order=order)
+        out.update(records=records, window_s=t1 - t0, t0=t0, order=order)
         out["finish"] = ask("bench_finish", {})
         out["fleet"] = door.metrics.snapshot()
     except Exception as e:   # reported by run(), with the worker's log
@@ -168,10 +169,9 @@ def run(ctx):
                              f"{devs[0]}")
     lib.apply_knobs(cfg)
     state = mod.build(cfg, mod, ctx["seed"], devs)
-    parts = sorted({r["part"] for r in out["records"]})
-    tables = {p: state.host_tables(p) for p in parts}
-    state.free()
+    tables = state.tables_in_turn(sorted({r["part"] for r in out["records"]}))
     return {"records": out["records"], "window_s": out["window_s"],
+            "t0": out["t0"],
             "t_setup_done": out["t_setup_done"], "device": dev,
             "spans": out["finish"]["spans"],
             "counters": out["finish"]["counters"], "tables": tables,

@@ -51,6 +51,7 @@ def _mark(ctx, params, sess):
 
     _S["spans"] = lib.Spans()
     _S["traces0"] = plan.trace_count()
+    lib.settle_gc()
     return True
 
 

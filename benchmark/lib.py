@@ -14,6 +14,7 @@ so a later PR adds a cell, a mix or a metric by adding files and entries.
 """
 
 import contextlib
+import gc
 import importlib.util
 import json
 import os
@@ -132,6 +133,21 @@ class Spans:
     def export(self):
         with self._lock:
             return {str(q): dict(v) for q, v in self.rows.items()}
+
+
+def settle_gc():
+    """Before a window opens, in every process that takes part in it: one
+    full collection, and what is left (all that the imports and the set-up
+    made) taken out of the collector's sight.  A full collection walks every
+    tracked object of the process and the process stands still for it: 38
+    ms inside a rehearsal's window on a sandbox core, which showed as that
+    window's slowest query and was gone with this.  After it a collection
+    walks what the window itself made, as in an executor that has been up
+    for hours; nothing is switched off.  (The pauses of 105-125 ms on the
+    chip's host are not collections: every process of the machine stands
+    still in them, PERF.md section 6, PR 30.)"""
+    gc.collect()
+    gc.freeze()
 
 
 def device_report(devs, peak=None):

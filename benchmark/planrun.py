@@ -58,28 +58,52 @@ class PlanState:
         with spans.span(q, "execute"):
             res, ng = jax.block_until_ready(cp(inputs))
         with spans.span(q, "result"):
-            n = int(ng)
             cap = int(self.cfg["result_capacity"])
+            # a result the plan left longer than the capacity is cut on the
+            # device first.  One that fits leaves as it is: a second program
+            # would queue behind the other caller's query, and two callers
+            # would then answer together and leave the device idle together
+            if any(a.shape[0] > cap for a in jax.tree_util.tree_leaves(res)):
+                res = self._head(res)
+            # the head and the group count in one transfer (the count handed
+            # through _head cost q95 1.3%: PERF.md section 6, PR 30)
+            small, n = jax.device_get((res, ng))
+            n = int(n)
             if n > cap:
                 raise lib.BenchError(f"{n} groups, result_capacity {cap}")
-            small = jax.device_get(self._head(res))
             return {c: (np.asarray(small[c].data)[:n],
                         np.asarray(small[c].validity)[:n], small[c].dtype)
                     for c in self.mod.RESULT_COLUMNS}
 
-    def host_tables(self, part):
-        """Partition ``part`` as numpy columns, for the reference."""
+    def _start_copies(self, part):
         import jax
 
+        for leaf in jax.tree_util.tree_leaves(self.inputs[part]):
+            leaf.copy_to_host_async()
+
+    def host_tables(self, part):
+        """Partition ``part`` as numpy columns, for the reference."""
+        self._start_copies(part)   # all under way before the first is read
         out = {}
         for name, batch in self.inputs[part].items():
             for col in batch.names:
-                out[f"{name}.{col}"] = np.asarray(
-                    jax.device_get(batch[col].data))
-                valid = np.asarray(jax.device_get(batch[col].validity))
-                if not valid.all():
+                out[f"{name}.{col}"] = np.asarray(batch[col].data)
+                if not np.asarray(batch[col].validity).all():
                     raise lib.BenchError(f"null in generated {name}.{col}")
         return out
+
+    def tables_in_turn(self, parts):
+        """(part, its numpy columns) for each of ``parts``, and the device's
+        tables freed after the last.  One partition's host copy at a time,
+        the next one's on its way while the reference reads this one: eight
+        batches of the plugin's size are 6 GB that no run needs on the host
+        at once."""
+        for i, part in enumerate(parts):
+            for nxt in parts[i + 1:i + 2]:
+                self._start_copies(nxt)
+            yield part, self.host_tables(part)
+            self.inputs[part] = None   # the array keeps its host copy
+        self.free()
 
     def free(self):
         self.inputs = None

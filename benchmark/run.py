@@ -97,13 +97,11 @@ def run_cell(args):
     limits = dict(cfg["limits"])
     compared = {"answers_missing": len(records) - len(done),
                 "null_values": 0}
-    wants = {}
     t_ref = time.perf_counter()
+    # each partition's reference once, its host copy dropped before the next
+    reference = mod.control if control.get("reference") else mod.reference
+    wants = {part: reference(cfg, tables) for part, tables in out["tables"]}
     for r in done:
-        if r["part"] not in wants:
-            tables = out["tables"][r["part"]]
-            wants[r["part"]] = (mod.control if control.get("reference")
-                                else mod.reference)(cfg, tables)
         got, nulls = planrun.plain(r.pop("result"))
         compared["null_values"] += nulls
         for name, v in mod.compare(cfg, got, wants[r["part"]]).items():
@@ -135,6 +133,11 @@ def run_cell(args):
     say(f"  slowest queries ms {[round(x, 1) for x in sorted(lat_ms)[-3:]]}, "
         f"longest waits between a caller's queries ms "
         f"{[round(x, 1) for x in sorted(gaps)[-3:]]}")
+    for r in sorted(done, key=lambda r: r["t1"] - r["t0"])[-3:]:
+        split = {k: round(v, 1) for k, v in
+                 out["spans"].get(str(r["q"]), {}).items()}
+        say(f"    q{r['q']} at {r['t0'] - out['t0']:.2f} s of the window: "
+            f"{(r['t1'] - r['t0']) * 1e3:.1f} ms, the benchmark's spans {split}")
     line = {"correct": correct, "attempted": len(records),
             "failed": len(records) - len(done), "metrics": {}, "device": dev}
     if args.rows is not None:
