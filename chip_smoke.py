@@ -69,7 +69,7 @@ ANSWER_TIMEOUT_S = 600.0  # longest wait for one answer
 HEARTBEAT_MS = 10000.0
 # what every phase runs at: log2 of the q6 batch rows, of the q95 fact rows
 # (bench_rows_tpu), and of the rows per device with --chips 4
-REAL_ROWS = {"q6": 24, "q95": 24, "shard": 22}
+REAL_ROWS = {"q6": 24, "q95": 24, "shard": 22, "tpch_q1": 22}
 
 
 def q6_request(args):
@@ -242,6 +242,54 @@ def _q95_reference(fact, dim1, dim2):
     return np.flatnonzero(live), orders[live], net[live]
 
 
+def _q1_lineitem(rows, seed):
+    """The seven LINEITEM columns TPC-H Q1 reads, by dbgen's rules (as
+    ``benchmark/configs/tpch-q1.py`` makes them on the device), in numpy:
+    name -> (values, spark type).  Decimals are unscaled at scale 2."""
+    from spark_rapids_jni_tpu.columnar import types as T
+
+    r = np.random.default_rng(seed)
+    qty = r.integers(1, 51, rows)
+    part = r.integers(1, 2_000_001, rows)
+    retail = 90000 + (part // 10) % 20001 + 100 * (part % 1000)
+    ship = r.integers(8035, 10441, rows) + r.integers(1, 122, rows)
+    receipt = ship + r.integers(1, 31, rows)
+    dec = T.SparkType.decimal(12, 2)
+    return {"l_returnflag": (np.where(receipt <= 9298,
+                                      2 * r.integers(0, 2, rows), 1),
+                             T.INT32),
+            "l_linestatus": ((ship > 9298).astype(np.int64), T.INT32),
+            "l_quantity": (qty * 100, dec),
+            "l_extendedprice": (qty * retail, dec),
+            "l_discount": (r.integers(0, 11, rows), dec),
+            "l_tax": (r.integers(0, 9, rows), dec),
+            "l_shipdate": (ship, T.DATE)}
+
+
+def _q1_reference(cols):
+    """TPC-H Q1 in int64 numpy (a row's charge is under 1.14e11, so 2^22
+    rows sum under 2^63), averages HALF_UP at scale 6 in Python ints: the
+    ten columns as lists, in ORDER BY order."""
+    c = {k: v for k, (v, _t) in cols.items()}
+    keep = c["l_shipdate"] <= 10471            # 1998-12-01 less 90 days
+    group = (c["l_returnflag"] * 2 + c["l_linestatus"])[keep]
+    qty, ext, disc, tax = (c[k][keep] for k in (
+        "l_quantity", "l_extendedprice", "l_discount", "l_tax"))
+    disc_price = ext * (100 - disc)
+    charge = disc_price * (100 + tax)
+    out = []
+    for g in np.unique(group):
+        m = group == g
+        n = int(m.sum())
+        check(int(charge[m].max()) * n < 2**63, "B_plan_tpch_q1: the "
+              "reference's int64 sum would wrap")
+        sums = [int(x[m].sum()) for x in (qty, ext, disc_price, charge)]
+        avgs = [(2 * int(x[m].sum()) * 10**4 + n) // (2 * n)
+                for x in (qty, ext, disc)]
+        out.append([int(g) // 2, int(g) % 2] + sums + avgs + [n])
+    return [list(col) for col in zip(*out)]
+
+
 def _live_columns(res, ng, names):
     """Host copies of a plan result's live rows, ordered by the first name."""
     n = int(ng)
@@ -398,6 +446,48 @@ def phase_b(args, digest_a, arrow_a):
          peak_bytes=_peak_bytes(dev))
     cp.close()
     del fact, dim1, dim2, res, ng, cp
+
+    # TPC-H Q1: expressions typed by Spark's rules, the two-key bucket, exact
+    # decimal128 sums and averages, against int64 numpy
+    n1 = 1 << args.log2["tpch_q1"]
+    cols = _q1_lineitem(n1, args.seed + 31)
+    import jax.numpy as jnp
+    from spark_rapids_jni_tpu import plan as plan_mod
+    from spark_rapids_jni_tpu.columnar.column import Column, ColumnBatch
+
+    lineitem = ColumnBatch({
+        name: Column(jnp.asarray(v.astype(np.dtype(t.jnp_dtype))),
+                     jnp.ones((n1,), jnp.bool_), t)
+        for name, (v, t) in cols.items()})
+    cp, (res, ng), first_s, second_s = _run_plan_twice(
+        "B_plan_tpch_q1", queries.tpch_q1_plan(), {"lineitem": lineitem})
+    names = ("l_returnflag", "l_linestatus", "sum_qty", "sum_base_price",
+             "sum_disc_price", "sum_charge", "avg_qty", "avg_price",
+             "avg_disc", "count_order")
+    types = {c: repr(res[c].dtype) for c in names}
+    check(list(types.values()) == [
+        "int32", "int32", "decimal(22,2)", "decimal(22,2)", "decimal(36,4)",
+        "decimal(38,6)", "decimal(16,6)", "decimal(16,6)", "decimal(16,6)",
+        "int64"], f"B_plan_tpch_q1: result types {types}")
+    got = [(res[c].to_unscaled_pylist() if hasattr(res[c], "limbs")
+            else res[c].to_pylist())[:int(ng)] for c in names]
+    want = _q1_reference(cols)
+    check(got == want, f"B_plan_tpch_q1: result differs from the numpy "
+                       f"reference: {got} vs {want}")
+    routes = {k: v for k, v in cp.decisions.items()
+              if k.startswith(("project", "sort"))}
+    check(len(routes) == 3 and "elided" in routes.get(
+        "sort0:l_returnflag,l_linestatus", {}),
+        f"B_plan_tpch_q1: decisions {cp.decisions}")
+    emit("B_plan_tpch_q1", rows=n1, groups=int(ng),
+         first_s=round(first_s, 3), second_s=round(second_s, 3),
+         compile_s=round(first_s - second_s, 3), second_lookup="hit",
+         retraces=0, result_types=types, decisions=cp.decisions,
+         counters={k: plan_mod.plan_cache_metrics()[k] for k in (
+             "mul_exact", "mul_rounded", "onehot_slots")},
+         peak_bytes=_peak_bytes(dev))
+    cp.close()
+    del lineitem, res, ng, cp
 
     # after q95, so that the peak q95 reports is not this one's
     config.set("q6_float_mode", "f64")

@@ -180,20 +180,32 @@ def _divmod_u(num, den) -> Tuple[jax.Array, jax.Array]:
     return q, r
 
 
-def _divmod_u_small(u, den) -> Tuple[jax.Array, jax.Array]:
-    """Unsigned 256-bit / u32 long division -> (quotient, remainder).
+def _divmod_u_small(u, den, limbs: int = 8) -> Tuple[jax.Array, jax.Array]:
+    """Unsigned 256-bit / small divisor -> (quotient, remainder).
 
-    ``den``: uint64[n], 0 < den < 2^32.  Schoolbook base-2^32 from the top
-    limb — 8 u64 divmods instead of :func:`_divmod_u`'s 256 shift-subtract
-    steps (group-average divides by a row count, always a small divisor).
+    ``den``: uint64[n], 0 < den <= 2^31 (group-average divides by a row
+    count); ``limbs`` says how many low u32 limbs of ``u`` can be nonzero
+    (the caller knows from the type).  Restoring division a bit at a time,
+    written out: the running remainder stays under 2^32, so every step is a
+    shift, a compare and a subtract on native u32 lanes, and the whole
+    division is one elementwise fusion.  (A u64 ``//`` and ``%`` a limb, the
+    schoolbook form, is eight software divisions that the TPU's compiler
+    takes 40 seconds over; :func:`_divmod_u`'s loop is 256 launches.)
     """
-    rem = jnp.zeros(u.shape[:1], jnp.uint64)
-    qs = []
-    for i in range(7, -1, -1):
-        cur = (rem << jnp.uint64(32)) | u[:, i].astype(jnp.uint64)
-        qs.append((cur // den).astype(jnp.uint32))
-        rem = cur % den
-    return jnp.stack(qs[::-1], axis=1), rem
+    d = den.astype(jnp.uint32)
+    rem = jnp.zeros(u.shape[:1], jnp.uint32)
+    one = jnp.uint32(1)
+    qs = [jnp.zeros(u.shape[:1], jnp.uint32)] * 8
+    for i in range(limbs - 1, -1, -1):
+        limb = u[:, i]
+        q = jnp.zeros(u.shape[:1], jnp.uint32)
+        for b in range(31, -1, -1):
+            rem = (rem << one) | ((limb >> jnp.uint32(b)) & one)
+            ge = rem >= d
+            rem = jnp.where(ge, rem - d, rem)
+            q = (q << one) | ge.astype(jnp.uint32)
+        qs[i] = q
+    return jnp.stack(qs, axis=1), rem.astype(jnp.uint64)
 
 
 def _precision10(u_abs) -> jax.Array:
@@ -357,6 +369,92 @@ def multiply_decimal128(
     valid = _both_valid(a, b)
     overflow = up_overflow | _overflow_38(product)
     return Column(overflow, valid, T.BOOLEAN), _result(product, valid, product_scale)
+
+
+def limbs_for_precision(precision: int) -> int:
+    """u32 limbs that hold every magnitude under ``10^precision``."""
+    return -(-(10**precision - 1).bit_length() // 32)
+
+
+def _magnitude_limbs(col, nlimbs: int):
+    """``|unscaled|`` as ``nlimbs`` little-endian u32 arrays, and the sign:
+    of a :class:`Decimal128Column`, or of a :class:`Column` whose decimal
+    (or integer) lives in 32- or 64-bit storage."""
+    if isinstance(col, Decimal128Column):
+        lo, hi = col.limbs[:, 0], col.limbs[:, 1]
+        neg = (hi >> jnp.uint64(63)) != 0
+        nlo = ~lo + jnp.uint64(1)
+        nhi = ~hi + (nlo == 0).astype(jnp.uint64)
+        lo, hi = jnp.where(neg, nlo, lo), jnp.where(neg, nhi, hi)
+        words = [lo & _MASK32, lo >> jnp.uint64(32),
+                 hi & _MASK32, hi >> jnp.uint64(32)]
+    else:
+        v = col.data.astype(jnp.int64)
+        neg = v < 0
+        mag = jax.lax.bitcast_convert_type(jnp.where(neg, -v, v), jnp.uint64)
+        words = [mag & _MASK32, mag >> jnp.uint64(32)]
+    return [w.astype(jnp.uint32) for w in words[:nlimbs]], neg
+
+
+def _limbs_to_column(limbs, neg, valid, out_dtype: T.SparkType):
+    """Sign and magnitude (little-endian u32 limbs) -> the column that
+    stores ``out_dtype``: int64 up to 18 digits, two 64-bit limbs past."""
+    w = [l.astype(jnp.uint64) for l in limbs[:4]]
+    w += [jnp.zeros_like(w[0])] * (4 - len(w))
+    lo = w[0] | (w[1] << jnp.uint64(32))
+    hi = w[2] | (w[3] << jnp.uint64(32))
+    nlo = ~lo + jnp.uint64(1)
+    nhi = ~hi + (nlo == 0).astype(jnp.uint64)
+    lo, hi = jnp.where(neg, nlo, lo), jnp.where(neg, nhi, hi)
+    if out_dtype.decimal_storage_bits < 128:
+        return Column(jax.lax.bitcast_convert_type(lo, jnp.int64), valid,
+                      out_dtype)
+    return Decimal128Column(jnp.stack([lo, hi], axis=1), valid, out_dtype)
+
+
+def multiply_exact(a, b, a_precision: int, b_precision: int,
+                   out_dtype: T.SparkType):
+    """``a * b`` where the typed product keeps every digit: its scale is the
+    sum of the operands' scales, so nothing rounds and nothing divides.
+
+    Sign and magnitude; the magnitudes are as many u32 limbs as the
+    operands' precisions need (a ``decimal(12,2)`` takes two, a
+    ``decimal(26,4)`` three), multiplied schoolbook with uint64 partial
+    products: four of them for ``decimal(12,2) x decimal(13,2)``, six for
+    ``decimal(26,4) x decimal(13,2)``, where the 256-bit route of
+    :func:`multiply_decimal128` takes 36 and two long divisions.  Returns
+    ``(overflow Column<bool>, result)`` like the other operations here;
+    overflow is ``|product| >= 10^precision`` of ``out_dtype`` (Spark's
+    ``CheckOverflow``), the result a :class:`Column` of int64 up to 18
+    digits and a :class:`Decimal128Column` past them.
+    """
+    la = limbs_for_precision(a_precision)
+    lb = limbs_for_precision(b_precision)
+    am, aneg = _magnitude_limbs(a, la)
+    bm, bneg = _magnitude_limbs(b, lb)
+    la, lb = len(am), len(bm)
+    n = am[0].shape[0]
+    a64 = [x.astype(jnp.uint64) for x in am]
+    b64 = [x.astype(jnp.uint64) for x in bm]
+    res = [jnp.zeros((n,), jnp.uint32) for _ in range(la + lb)]
+    for j in range(lb):
+        carry = jnp.zeros((n,), jnp.uint64)
+        for i in range(la):
+            t = a64[i] * b64[j] + res[i + j].astype(jnp.uint64) + carry
+            res[i + j] = (t & _MASK32).astype(jnp.uint32)
+            carry = t >> jnp.uint64(32)
+        res[j + la] = carry.astype(jnp.uint32)
+    bound = 10**out_dtype.precision
+    overflow = jnp.zeros((n,), jnp.bool_)
+    if bound < 1 << (32 * (la + lb)):
+        lt = jnp.zeros((n,), jnp.bool_)   # magnitude < bound, LSB first
+        for i in range(la + lb):
+            c = jnp.uint32((bound >> (32 * i)) & 0xFFFFFFFF)
+            lt = jnp.where(res[i] == c, lt, res[i] < c)
+        overflow = ~lt
+    valid = a.validity & b.validity
+    return (Column(overflow, valid, T.BOOLEAN),
+            _limbs_to_column(res, aneg ^ bneg, valid, out_dtype))
 
 
 def _div_prepare(a: Decimal128Column, b: Decimal128Column, quotient_scale: int):

@@ -6,8 +6,9 @@ query used to mean hand-writing another.  This package makes a query
 DATA instead:
 
 * :mod:`ir` — a small logical IR (Scan/Filter/Project/Join/Aggregate/
-  Exchange/Sort over ``ColumnBatch``), hashable and canonicalized so a
-  plan SHAPE is a dict key;
+  Exchange/Sort over ``ColumnBatch``, expressions ``Col``/``Lit``/``+ - *``
+  inside a Project), hashable and canonicalized so a plan SHAPE is a
+  dict key;
 * :mod:`compile` — lowers a whole plan into ONE jitted program, fusing
   adjacent exchange + group-by stages exactly the way the hand paths do
   (``regroup_order(secondary=)``), dispatching into the existing
@@ -27,9 +28,10 @@ bit-identical to the hand-fused paths on plain AND encoded inputs,
 under both engine knob settings.
 """
 
-from .ir import (Aggregate, Agg, Exchange, Filter, Join, Project, Scan,
-                 Sort)
-from .compile import CompiledPlan, compile_plan, execute, trace_count
+from .ir import (Aggregate, Agg, Arith, Col, DateLit, Exchange, Filter, Join,
+                 Lit, Project, Scan, Sort)
+from .compile import (CompiledPlan, compile_plan, execute, expr_type,
+                      trace_count)
 from .cache import get_plan_cache, plan_cache_metrics, reset_plan_cache
 from .adaptive import (choose_exchange_capacity, choose_groupby_engine,
                        choose_join_engine, choose_join_strategy,
@@ -38,8 +40,8 @@ from . import queries
 
 __all__ = [
     "Scan", "Filter", "Project", "Join", "Aggregate", "Agg", "Exchange",
-    "Sort",
-    "CompiledPlan", "compile_plan", "execute", "trace_count",
+    "Sort", "Col", "Lit", "Arith", "DateLit",
+    "CompiledPlan", "compile_plan", "execute", "expr_type", "trace_count",
     "get_plan_cache", "plan_cache_metrics", "reset_plan_cache",
     "choose_join_strategy", "choose_join_engine", "choose_groupby_engine",
     "choose_exchange_capacity", "plan_decisions",

@@ -42,6 +42,17 @@ class PlanCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        # products the compiler lowered, by route (exact: int64 or typed
+        # limbs; rounded: the 256-bit multiply with its long division)
+        self.routes = {"mul_exact": 0, "mul_rounded": 0}
+
+    def note_routes(self, routes) -> None:
+        """Count a newly compiled plan's ``route:arithmetic:type``s."""
+        with self._lock:
+            for r in routes:
+                name = r.split(":")[0]
+                if name in self.routes:
+                    self.routes[name] += 1
 
     def _capacity(self) -> int:
         if self._maxsize is not None:
@@ -133,6 +144,8 @@ class PlanCache:
             return len(self._entries)
 
     def metrics(self) -> dict:
+        from ..relational.aggregate import onehot_slots
+
         with self._lock:
             return {
                 "hits": self.hits,
@@ -141,6 +154,9 @@ class PlanCache:
                 "size": len(self._entries),
                 "capacity": self._capacity(),
                 "pinned": len(self._pins),
+                **self.routes,
+                # int8 slots of the newest one-hot contraction traced
+                "onehot_slots": onehot_slots(),
             }
 
     def clear(self) -> None:

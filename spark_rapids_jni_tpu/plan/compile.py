@@ -29,6 +29,14 @@ The lowering rules are the hand-fused flagship pipelines, factored:
   general ``group_by`` by exactly the hand paths' dispatch (domain
   hints apply only to plain int keys; string/encoded keys run the
   general engine).
+* Project -> its expressions typed by Spark's decimal rules
+  (:func:`expr_type`) and lowered to the narrowest exact arithmetic the
+  type allows (int64 up to 18 digits, typed-width limbs past them, the
+  256-bit rounding multiply only where a product loses scale).  Under
+  an Aggregate that takes the one-hot engine the computed columns are
+  handed over unevaluated and computed inside its row slices.
+* Sort on exactly the keys of a composite-domain Aggregate below it is
+  elided: that engine emits key order, nulls first.
 
 One ``jax.jit`` wraps the whole lowered pipeline, so XLA sees every
 stage together.  Programs are cached in :mod:`cache` keyed on
@@ -46,7 +54,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import config, profiler
-from ..columnar.column import Column, ColumnBatch
+from ..columnar import types as T
+from ..columnar.column import Column, ColumnBatch, Decimal128Column
 from ..columnar.encoded import PACKED_COLUMNS, is_encoded, \
     packed_filter_mask, predicate_mask
 from . import adaptive, ir
@@ -154,11 +163,214 @@ def _filter_mask(col, op: str, value):
     literal transformed once per frame, bit-identical to
     decode-then-compare, zero decodes on the fast path)."""
     fn = _FILTER_OPS[op]
+    if isinstance(value, ir.DateLit):
+        value = jnp.int32(value.days)   # what a DATE column holds
     if isinstance(col, PACKED_COLUMNS):
         return packed_filter_mask(col, op, value)
     if is_encoded(col) and hasattr(col, "codes"):
         return predicate_mask(col, lambda d: fn(d.data, value))
-    return fn(col.data, value)
+    # a comparison with a null is null, and the row goes (as on codes)
+    return fn(col.data, value) & col.validity
+
+
+# ---------------------------------------------------------------------------
+# expressions: Spark's decimal typing and the arithmetic that carries it
+# ---------------------------------------------------------------------------
+
+_MAX_PRECISION = 38
+_MINIMUM_ADJUSTED_SCALE = 6
+# DecimalType.forType: what an integer column is beside a decimal
+_INT_AS_DECIMAL = {T.Kind.INT8: (3, 0), T.Kind.INT16: (5, 0),
+                   T.Kind.INT32: (10, 0), T.Kind.INT64: (20, 0)}
+# DecimalPrecision: (p1, s1, p2, s2) -> the result's (precision, scale)
+# before adjustPrecisionScale
+_RAW_RESULT = {
+    "+": lambda p1, s1, p2, s2: (max(p1 - s1, p2 - s2) + max(s1, s2) + 1,
+                                 max(s1, s2)),
+    "-": lambda p1, s1, p2, s2: (max(p1 - s1, p2 - s2) + max(s1, s2) + 1,
+                                 max(s1, s2)),
+    "*": lambda p1, s1, p2, s2: (p1 + p2 + 1, s1 + s2),
+}
+
+
+def adjust_precision_scale(precision: int, scale: int) -> tuple:
+    """``DecimalType.adjustPrecisionScale`` (``allowPrecisionLoss``, the
+    default): past 38 digits the integral digits are kept and the scale
+    gives way, down to ``min(scale, 6)``."""
+    if precision <= _MAX_PRECISION:
+        return precision, scale
+    int_digits = precision - scale
+    return _MAX_PRECISION, max(_MAX_PRECISION - int_digits,
+                               min(scale, _MINIMUM_ADJUSTED_SCALE))
+
+
+def _as_decimal(t: T.SparkType) -> tuple:
+    if t.kind is T.Kind.DECIMAL:
+        return t.precision, t.scale
+    if t.kind in _INT_AS_DECIMAL:
+        return _INT_AS_DECIMAL[t.kind]
+    raise NotImplementedError(f"arithmetic over {t!r}")
+
+
+def expr_type(expr: ir.Expr, schema: dict) -> T.SparkType:
+    """Spark's result type of ``expr`` over columns typed by ``schema``
+    (name -> SparkType): the one typing pass, read by the lowering, by
+    the plan's decisions and by the tests."""
+    if isinstance(expr, ir.Col):
+        return schema[expr.name]
+    if isinstance(expr, ir.Lit):
+        digits = len(str(abs(int(expr.unscaled))))
+        return T.SparkType.decimal(max(digits, expr.scale), expr.scale)
+    lt, rt = expr_type(expr.left, schema), expr_type(expr.right, schema)
+    if T.Kind.DECIMAL not in (lt.kind, rt.kind):
+        raise NotImplementedError(
+            f"{lt!r} {expr.op} {rt!r}: only decimal arithmetic is typed")
+    return T.SparkType.decimal(*adjust_precision_scale(
+        *_RAW_RESULT[expr.op](*_as_decimal(lt), *_as_decimal(rt))))
+
+
+def _route(expr: ir.Arith, lt, rt, out) -> str:
+    """The arithmetic that carries ``expr`` exactly: chosen from the types."""
+    _p, raw_scale = _RAW_RESULT[expr.op](*_as_decimal(lt), *_as_decimal(rt))
+    if expr.op == "*":
+        if out.scale < raw_scale:
+            return "mul_rounded:dec256"
+        return "mul_exact:int64" if out.precision <= 18 \
+            else "mul_exact:limbs"
+    return "add:int64" if out.precision <= 18 and out.scale == raw_scale \
+        else "add:dec256"
+
+
+def expr_routes(expr: ir.Expr, schema: dict, seen=None) -> list:
+    """``route:arithmetic:type`` of every operation under ``expr``, operands
+    first; ``seen`` (a set) leaves out what an earlier output of the same
+    Project already computes."""
+    seen = set() if seen is None else seen
+    if not isinstance(expr, ir.Arith) or expr in seen:
+        return []
+    seen.add(expr)
+    lt, rt = expr_type(expr.left, schema), expr_type(expr.right, schema)
+    t = expr_type(expr, schema)
+    return (expr_routes(expr.left, schema, seen)
+            + expr_routes(expr.right, schema, seen)
+            + [f"{_route(expr, lt, rt, t)}:{t!r}"])
+
+
+def _batch_schema(b: ColumnBatch) -> dict:
+    """name -> SparkType of the columns an expression can read: plain
+    fixed-width and decimal128 columns."""
+    return {n: c.dtype for n, c in zip(b.names, b.columns)
+            if isinstance(c, (Column, Decimal128Column))}
+
+
+def _as_dec128(col, dt: T.SparkType) -> Decimal128Column:
+    from ..relational.aggregate import _widen_decimal
+
+    wide = _widen_decimal(col)
+    return Decimal128Column(wide.limbs, wide.validity,
+                            T.SparkType.decimal(*_as_decimal(dt)))
+
+
+class _ExprEval:
+    """Evaluates expressions over the rows of one batch (a whole table, or
+    one slice inside the aggregate's loop); an expression met twice (Q1's
+    ``l_extendedprice * (1 - l_discount)`` under two outputs) is computed
+    once, under the scope of the output that met it first."""
+
+    def __init__(self, batch: ColumnBatch):
+        self.batch = batch
+        self.schema = _batch_schema(batch)
+        self.memo = {}
+
+    def __call__(self, expr: ir.Expr):
+        if expr not in self.memo:
+            self.memo[expr] = self._eval(expr)
+        return self.memo[expr]
+
+    def _eval(self, expr):
+        from ..ops import decimal as D
+
+        if isinstance(expr, ir.Col):
+            if expr.name not in self.schema:
+                raise NotImplementedError(
+                    f"expression over column {expr.name!r}: "
+                    f"{type(self.batch[expr.name]).__name__}")
+            return self.batch[expr.name]
+        n = self.batch.num_rows
+        t = expr_type(expr, self.schema)
+        if isinstance(expr, ir.Lit):
+            if t.decimal_storage_bits == 128:
+                raise NotImplementedError("a literal past 18 digits")
+            return Column(jnp.full((n,), expr.unscaled, jnp.int64),
+                          jnp.ones((n,), jnp.bool_), t)
+        a, b = self(expr.left), self(expr.right)
+        lt = expr_type(expr.left, self.schema)
+        rt = expr_type(expr.right, self.schema)
+        route = _route(expr, lt, rt, t)
+        valid = a.validity & b.validity
+        with profiler.scope("expr." + route.split(":")[0]):
+            if route == "add:int64":
+                x, y = (c.data.astype(jnp.int64)
+                        * 10 ** (t.scale - _as_decimal(ct)[1])
+                        for c, ct in ((a, lt), (b, rt)))
+                return Column(x + y if expr.op == "+" else x - y, valid, t)
+            if route == "mul_exact:int64":
+                return Column(a.data.astype(jnp.int64)
+                              * b.data.astype(jnp.int64), valid, t)
+            if route == "mul_exact:limbs":
+                over, res = D.multiply_exact(a, b, _as_decimal(lt)[0],
+                                             _as_decimal(rt)[0], t)
+            elif route == "mul_rounded:dec256":
+                over, res = D.multiply_decimal128(
+                    _as_dec128(a, lt), _as_dec128(b, rt), t.scale,
+                    cast_interim_result=False)
+            else:
+                op = D.add_decimal128 if expr.op == "+" else D.sub_decimal128
+                over, res = op(_as_dec128(a, lt), _as_dec128(b, rt), t.scale)
+            # non-ANSI: a result past its type's precision is null
+            return Decimal128Column(res.limbs, valid & ~over.data, t)
+
+
+def _project_columns(node: ir.Project, b: ColumnBatch) -> dict:
+    """Every output of ``node`` over the rows of ``b``; a computed one
+    under ``plan.project.<output>``."""
+    ev = _ExprEval(b)
+    out = {}
+    for name, expr in node.outputs():
+        if isinstance(expr, ir.Col):
+            out[name] = b[expr.name]
+        else:
+            with profiler.scope("plan.project." + profiler.scope_name(name)):
+                out[name] = ev(expr)
+    return out
+
+
+def _derived(node: ir.Project, b: ColumnBatch):
+    """``b`` with ``node``'s kept columns under their output names, and its
+    computed columns as a :class:`Derived` for a consumer that evaluates
+    them itself, over its own row slices (``fn`` enters no scope of the
+    consumer's: it is called under an empty path)."""
+    from ..relational.aggregate import Derived
+
+    schema = _batch_schema(b)
+    cols = dict(zip(b.names, b.columns))
+    computed = []
+    for name, expr in node.outputs():
+        if not isinstance(expr, ir.Col):
+            computed.append(name)
+        elif name != expr.name and name in cols:
+            return b, None   # a kept column renamed over one still read
+        else:
+            cols[name] = b[expr.name]
+    dtypes = {name: expr_type(expr, schema)
+              for name, expr in node.outputs() if name in computed}
+    only = ir.Project(node.child, [c for c in node.columns
+                                   if not isinstance(c, str)])
+
+    def fn(blk):
+        return _project_columns(only, blk)
+
+    return ColumnBatch(cols), Derived(dtypes, fn)
 
 
 def _exchange_local(b: ColumnBatch, key: str, live, partitions: int,
@@ -195,15 +407,19 @@ class _State:
         self.agg_hints = agg_hints
         self.join_i = 0
         self.agg_i = 0
+        # Aggregate nodes (by id) lowered onto an engine that emits its
+        # groups in key order, nulls first: a Sort on the same keys
+        # above one has nothing left to do
+        self.key_ordered = set()
 
 
 def node_scope(node: ir.PlanNode) -> str:
     """The named scope a node's own operations are lowered under:
     ``plan.filter.<column>``, ``plan.exchange.<key>``, ``plan.join.<right
-    scan name>``, ``plan.aggregate.<first key>``, ``plan.sort``,
-    ``plan.project``.  A child is lowered before and outside its parent's
-    scope, so a device operation's path starts at the one node it
-    belongs to."""
+    scan name>``, ``plan.aggregate.<first key>``, ``plan.sort``; a
+    Project's computed outputs each have ``plan.project.<output>``.  A
+    child is lowered before and outside its parent's scope, so a device
+    operation's path starts at the one node it belongs to."""
     if isinstance(node, ir.Filter):
         return "plan.filter." + profiler.scope_name(node.column)
     if isinstance(node, ir.Exchange):
@@ -218,8 +434,9 @@ def node_scope(node: ir.PlanNode) -> str:
 
 
 def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
-    """Returns ``(batch, live, prefix)``: ``live`` is a bool row mask or
-    None (statically all-live); ``prefix`` records that the mask is of
+    """Returns ``(batch, live, prefix)``: ``live`` is a bool row mask,
+    None (statically all-live) or, from an Aggregate up, the scalar count
+    of live rows in front; ``prefix`` records that the mask is of
     arange<count form (live rows compacted in front), which is what
     lets it pass through an exchange untouched — a scattered filter
     mask instead becomes ``arange < sum(live)`` on the far side."""
@@ -235,8 +452,7 @@ def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
 
     if isinstance(node, ir.Project):
         b, live, pfx = _lower(node.child, env, prebuilts, st)
-        with profiler.scope(node_scope(node)):
-            return b.select(list(node.columns)), live, pfx
+        return ColumnBatch(_project_columns(node, b)), live, pfx
 
     if isinstance(node, ir.Exchange):
         b, live, pfx = _lower(node.child, env, prebuilts, st)
@@ -269,6 +485,12 @@ def _lower_sort(node: ir.Sort, env, prebuilts, st):
     from ..relational.sort import SortKey, sort_by
 
     b, live, _pfx = _lower(node.child, env, prebuilts, st)
+    # from an Aggregate: the count of live rows, which are in front
+    count = live if live is not None and live.ndim == 0 else None
+    if count is not None:
+        if id(node.child) in st.key_ordered:
+            return b, count, True   # already in this order: elided
+        live = jnp.arange(b.num_rows, dtype=jnp.int32) < count
     keys = [SortKey(k) for k in node.keys]
     with profiler.scope(node_scope(node)):
         if live is None:
@@ -282,7 +504,7 @@ def _lower_sort(node: ir.Sort, env, prebuilts, st):
         new_live = jnp.arange(n, dtype=jnp.int32) < jnp.sum(
             live.astype(jnp.int32))
         return (out.select([nm for nm in out.names if nm != "__occ"]),
-                new_live, True)
+                new_live if count is None else count, True)
 
 
 def _lower_join(node: ir.Join, env, prebuilts, st):
@@ -322,16 +544,42 @@ def _lower_aggregate(node: ir.Aggregate, env, prebuilts, st):
     child = node.child
     fuse = (isinstance(child, ir.Exchange) and len(node.keys) == 1
             and child.key == node.keys[0])
-    # a fused Exchange is lowered here, under the aggregate's scope (as
-    # the regroup that orders its rows, or not at all)
-    b, live, pfx = _lower(child.child if fuse else child, env, prebuilts,
-                          st)
+    derive = None
+    if isinstance(child, ir.Project) and any(
+            not isinstance(c, str) for c in child.columns):
+        # a computed column is evaluated where its consumer reads it: by
+        # the one-hot engine inside its row slices; any other engine
+        # reads whole columns, so there they are made whole
+        b, live, pfx = _lower(child.child, env, prebuilts, st)
+        pb, derive = _derived(child, b)
+        if derive is None or not _takes_onehot(node, _batch_schema(pb)):
+            b, derive = ColumnBatch(_project_columns(child, b)), None
+        else:
+            b = pb
+    else:
+        # a fused Exchange is lowered here, under the aggregate's scope
+        # (as the regroup that orders its rows, or not at all)
+        b, live, pfx = _lower(child.child if fuse else child, env,
+                              prebuilts, st)
     with profiler.scope(node_scope(node)):
         return _aggregate(node, aggs, hint, child if fuse else None,
-                          b, live, pfx)
+                          b, live, pfx, derive, st)
 
 
-def _aggregate(node: ir.Aggregate, aggs, hint, fused, b, live, pfx):
+def _takes_onehot(node: ir.Aggregate, schema: dict) -> bool:
+    """Whether ``node`` over columns typed by ``schema`` (plain columns
+    only) is lowered onto ``group_by_onehot``: every key a plain integer
+    column with a domain, and the knob says so."""
+    ints = (T.Kind.INT8, T.Kind.INT16, T.Kind.INT32, T.Kind.INT64)
+    return (node.onehot and node.domain is not None
+            and (isinstance(node.domain, tuple) or len(node.keys) == 1)
+            and all(k in schema and schema[k].kind in ints
+                    for k in node.keys)
+            and config.get("q6_group_path") == "onehot")
+
+
+def _aggregate(node: ir.Aggregate, aggs, hint, fused, b, live, pfx,
+               derive=None, st=None):
     from ..relational import keys as _rk
     from ..relational.aggregate import (_resolve_groupby_engine, group_by,
                                         group_by_domain_or_sort,
@@ -360,19 +608,24 @@ def _aggregate(node: ir.Aggregate, aggs, hint, fused, b, live, pfx):
         # scatter/auto engines and encoded keys: the single-chip
         # exchange feeds a complete local aggregation — elide it
 
-    key_col = b[node.keys[0]] if len(node.keys) == 1 else None
-    domain_ok = (node.domain is not None and key_col is not None
-                 and _plain_int_key(key_col))
+    composite = isinstance(node.domain, tuple)
+    domain_ok = (node.domain is not None
+                 and (composite or len(node.keys) == 1)
+                 and all(_plain_int_key(b[k]) for k in node.keys))
     if node.onehot and domain_ok:
         if config.get("q6_group_path") == "onehot":
             res, ng, _overflow = group_by_onehot(
-                b, node.keys[0], aggs, domain=int(node.domain),
+                b, node.keys if composite else node.keys[0], aggs,
+                domain=node.domain if composite else int(node.domain),
                 row_valid=live, float_mode=config.get("q6_float_mode"),
-                engine=config.get("q6_onehot_engine"))
+                engine=config.get("q6_onehot_engine"), derive=derive)
+            if composite and st is not None:
+                st.key_ordered.add(id(node))
             return res, ng, True
         res, ng = group_by(b, list(node.keys), aggs, row_valid=live)
         return res, ng, True
-    if domain_ok and not node.onehot:
+    assert derive is None   # _takes_onehot said so, or it was made whole
+    if domain_ok and not node.onehot and not composite:
         res, ng = group_by_domain_or_sort(b, node.keys[0], aggs,
                                           int(node.domain), row_valid=live)
         return res, ng, True
@@ -512,6 +765,7 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
             if stats is None:
                 stats = _default_stats()
             decisions = adaptive.plan_decisions(plan, inputs, stats)
+            decisions.update(_typed_decisions(plan, inputs))
         with profiler.span("plan.key"):
             key = plan_cache_key(plan, inputs, decisions)
         cache = get_plan_cache()
@@ -531,17 +785,72 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
             with profiler.span("plan.trace"):
                 _TRACE_COUNT[0] += 1
                 st = _State(join_plans, agg_hints)
-                out = _lower(plan, env, prebuilts, st)
-                if isinstance(plan, ir.Aggregate):
-                    res, ng, _pfx = out
-                    return res, ng
-                batch, live, _pfx = out
+                batch, live, _pfx = _lower(plan, env, prebuilts, st)
+                # from an Aggregate up ``live`` is the group count
                 return batch if live is None else (batch, live)
 
         compiled = CompiledPlan(plan, key, jax.jit(run), input_names,
                                 handles, decisions)
         cache.put(key, compiled)
+        cache.note_routes(r for k, d in decisions.items()
+                          if k.startswith("project")
+                          for r in d["routes"])
         return compiled
+
+
+def _schema_at(node: ir.PlanNode, inputs: dict) -> Optional[dict]:
+    """name -> SparkType of ``node``'s output where it can be told from
+    the plan and the inputs alone (Scan, Filter, Project); else None."""
+    if isinstance(node, ir.Scan):
+        return _batch_schema(inputs[node.name]) if node.name in inputs \
+            else None
+    if isinstance(node, ir.Filter):
+        return _schema_at(node.child, inputs)
+    if isinstance(node, ir.Project):
+        below = _schema_at(node.child, inputs)
+        if below is None:
+            return None
+        try:
+            return {name: expr_type(expr, below)
+                    for name, expr in node.outputs()}
+        except (KeyError, NotImplementedError):
+            return None
+    return None
+
+
+def _typed_decisions(plan: ir.PlanNode, inputs: dict) -> dict:
+    """What the compiler decides from types: for each computed output of a
+    Project its Spark type and the arithmetic of every operation under
+    it (``project<i>:<output>``), and a Sort that the Aggregate below it
+    makes redundant (``sort<i>:<keys>``).  Plans with neither get nothing,
+    so their cache keys are what they were."""
+    out = {}
+    pi = si = 0
+    for node in plan.walk():
+        if isinstance(node, ir.Project):
+            below = _schema_at(node.child, inputs)
+            seen = set()
+            for name, expr in node.outputs():
+                if below is None or isinstance(expr, ir.Col):
+                    continue
+                try:
+                    out[f"project{pi}:{name}"] = {
+                        "type": repr(expr_type(expr, below)),
+                        "routes": tuple(expr_routes(expr, below, seen))}
+                except (KeyError, NotImplementedError):
+                    pass   # the lowering says what it cannot do
+            pi += 1
+        elif isinstance(node, ir.Sort):
+            agg = node.child
+            if (isinstance(agg, ir.Aggregate) and node.keys == agg.keys
+                    and isinstance(agg.domain, tuple)):
+                schema = _schema_at(agg.child, inputs)
+                if schema is not None and _takes_onehot(agg, schema):
+                    out[f"sort{si}:{','.join(node.keys)}"] = {
+                        "elided": "the composite-domain aggregate below "
+                                  "emits key order, nulls first"}
+            si += 1
+    return out
 
 
 def _maybe_execute_streaming(plan: ir.PlanNode, inputs: dict, ctx=None):

@@ -22,22 +22,28 @@ from typing import Optional, Tuple
 
 FILTER_OPS = ("<", "<=", ">", ">=", "==", "!=")
 JOIN_STRATEGIES = ("shuffled", "broadcast", "auto")
+ARITH_OPS = ("+", "-", "*")
 
 
-class PlanNode:
-    """Base for IR nodes; subclasses are frozen dataclasses."""
-
-    def children(self) -> tuple:
-        return tuple(getattr(self, f.name) for f in dataclasses.fields(self)
-                     if isinstance(getattr(self, f.name), PlanNode))
+class _Signed:
+    """What plan nodes and the expressions and literals inside them
+    share: a canonical nested-tuple identity."""
 
     def signature(self) -> tuple:
         """Canonical nested-tuple identity of this plan shape."""
         out = [type(self).__name__]
         for f in dataclasses.fields(self):
             v = getattr(self, f.name)
-            out.append(v.signature() if isinstance(v, PlanNode) else v)
+            out.append(v.signature() if isinstance(v, _Signed) else v)
         return tuple(out)
+
+
+class PlanNode(_Signed):
+    """Base for IR nodes; subclasses are frozen dataclasses."""
+
+    def children(self) -> tuple:
+        return tuple(getattr(self, f.name) for f in dataclasses.fields(self)
+                     if isinstance(getattr(self, f.name), PlanNode))
 
     def walk(self):
         """Depth-first (children before self) node iterator."""
@@ -48,6 +54,94 @@ class PlanNode:
 
 def _tup(v):
     return tuple(v) if v is not None else None
+
+
+# ---------------------------------------------------------------------------
+# expressions (the values a Project computes, a Filter compares against)
+# ---------------------------------------------------------------------------
+
+class Expr(_Signed):
+    """Base for expression nodes: frozen, hashable, part of the owning
+    node's :meth:`PlanNode.signature`.  An expression says WHAT is
+    computed; its Spark result type and the arithmetic that carries it
+    are the compiler's (``plan/compile.py:expr_type``)."""
+
+    def __add__(self, other):
+        return Arith("+", self, _expr(other))
+
+    def __radd__(self, other):
+        return Arith("+", _expr(other), self)
+
+    def __sub__(self, other):
+        return Arith("-", self, _expr(other))
+
+    def __rsub__(self, other):
+        return Arith("-", _expr(other), self)
+
+    def __mul__(self, other):
+        return Arith("*", self, _expr(other))
+
+    def __rmul__(self, other):
+        return Arith("*", _expr(other), self)
+
+
+@dataclass(frozen=True)
+class Col(Expr):
+    """A column of the child's output."""
+
+    name: str
+
+
+@dataclass(frozen=True)
+class Lit(Expr):
+    """An exact decimal literal ``unscaled * 10^-scale``.  Typed as Spark
+    types a literal beside a decimal (``DecimalType.fromLiteral``): as
+    many digits as it has, so ``Lit(1)`` is ``decimal(1,0)``."""
+
+    unscaled: int
+    scale: int = 0
+
+
+@dataclass(frozen=True)
+class Arith(Expr):
+    """``left <op> right`` with ``op`` in ``+ - *``."""
+
+    op: str
+    left: Expr
+    right: Expr
+
+    def __post_init__(self):
+        if self.op not in ARITH_OPS:
+            raise ValueError(f"unknown arithmetic op {self.op!r}; "
+                             f"known: {ARITH_OPS}")
+
+
+def _expr(v) -> Expr:
+    if isinstance(v, Expr):
+        return v
+    if isinstance(v, str):
+        return Col(v)
+    if isinstance(v, int) and not isinstance(v, bool):
+        return Lit(v)
+    raise TypeError(f"not an expression: {v!r}")
+
+
+@dataclass(frozen=True)
+class DateLit(_Signed):
+    """A ``DATE`` literal for a :class:`Filter`: an ISO day plus a whole
+    number of days (``date '1998-12-01' - interval '90' day`` is
+    ``DateLit("1998-12-01", -90)``), compared as int32 days since the
+    epoch, which is what a ``DATE`` column holds."""
+
+    iso: str
+    plus_days: int = 0
+
+    @property
+    def days(self) -> int:
+        import datetime
+
+        return (datetime.date.fromisoformat(self.iso)
+                - datetime.date(1970, 1, 1)).days + int(self.plus_days)
 
 
 @dataclass(frozen=True)
@@ -83,7 +177,7 @@ class Filter(PlanNode):
     child: PlanNode
     column: str
     op: str
-    value: object  # hashable scalar literal
+    value: object  # hashable scalar literal, or a DateLit
 
     def __post_init__(self):
         if self.op not in FILTER_OPS:
@@ -93,13 +187,28 @@ class Filter(PlanNode):
 
 @dataclass(frozen=True)
 class Project(PlanNode):
-    """Keep only the named columns (order defines output order)."""
+    """The output columns, in order: a name keeps that column of the
+    child, a ``(name, expression)`` pair computes one.  Where the
+    consumer is a domain :class:`Aggregate` the computed columns are
+    evaluated inside its row blocks and never exist whole."""
 
     child: PlanNode
-    columns: Tuple[str, ...]
+    columns: Tuple[object, ...]  # str | (str, Expr)
 
     def __post_init__(self):
-        object.__setattr__(self, "columns", _tup(self.columns))
+        object.__setattr__(self, "columns", tuple(
+            c if isinstance(c, str) else (str(c[0]), _expr(c[1]))
+            for c in self.columns))
+
+    def outputs(self) -> Tuple[Tuple[str, Expr], ...]:
+        """Every output as ``(name, expression)``."""
+        return tuple((c, Col(c)) if isinstance(c, str) else c
+                     for c in self.columns)
+
+    def signature(self) -> tuple:
+        return ("Project", self.child.signature(), tuple(
+            c if isinstance(c, str) else (c[0], c[1].signature())
+            for c in self.columns))
 
 
 @dataclass(frozen=True)
@@ -154,16 +263,28 @@ class Aggregate(PlanNode):
     string or encoded key column ignores them and runs the general
     engine-selectable ``group_by``, which is exactly what the
     hand-fused paths do.
+
+    A tuple gives one domain per key (TPC-H Q1: ``(3, 2)``): with
+    ``onehot=True`` the keys become one composite bucket of the same
+    engine, a null of either key a bucket of its own, and the groups
+    come out in key order, nulls first (what a :class:`Sort` on the
+    same keys would give, so the compiler elides one).
     """
 
     child: PlanNode
     keys: Tuple[str, ...]
     aggs: Tuple[Agg, ...]
-    domain: Optional[int] = None
+    domain: object = None  # None | int | one int per key
     onehot: bool = False
 
     def __post_init__(self):
         object.__setattr__(self, "keys", _tup(self.keys))
+        if isinstance(self.domain, (list, tuple)):
+            object.__setattr__(self, "domain",
+                               tuple(int(d) for d in self.domain))
+            if len(self.domain) != len(self.keys):
+                raise ValueError(f"{len(self.domain)} domains for "
+                                 f"{len(self.keys)} keys")
         aggs = tuple(a if isinstance(a, Agg) else Agg(*a)
                      for a in self.aggs)
         object.__setattr__(self, "aggs", aggs)

@@ -7,12 +7,15 @@ tests/test_plan.py gates the outputs bit-identical on plain AND
 encoded inputs under both engine knob settings.  ``q9_plan`` is the
 proof that new queries are now data, not code: a q9-shaped pipeline
 (multi-join + conditional aggregate) that exists ONLY as IR — there is
-no hand-fused ``_q9_step`` anywhere.
+no hand-fused ``_q9_step`` anywhere.  ``tpch_q1_plan`` is TPC-H Q1 as
+Spark SQL types it: expressions in a Project, two low-cardinality keys,
+decimal sums and averages.
 """
 
 from __future__ import annotations
 
-from .ir import Agg, Aggregate, Exchange, Filter, Join, Scan
+from .ir import (Agg, Aggregate, Col, DateLit, Exchange, Filter, Join,
+                 Project, Scan, Sort)
 
 # the q9 conditional: high-value orders only (the WHEN net > threshold
 # arm of q9's conditional aggregate, expressed as filter -> row_valid)
@@ -72,3 +75,48 @@ def q9_plan() -> Aggregate:
               Agg("count", None, "orders_hi"),
               Agg("mean", "v", "avg_hi")),
         domain=Q95_SEG)
+
+
+# TPC-H Q1's keys as a dictionary-encoded scan hands them over:
+# l_returnflag A/N/R -> 0/1/2, l_linestatus F/O -> 0/1
+TPCH_Q1_DOMAINS = (3, 2)
+
+
+def tpch_q1_plan(delta_days: int = 90) -> Sort:
+    """TPC-H Q1, the pricing summary report (specification clause 2.4.1,
+    validation parameter DELTA = 90)::
+
+        select l_returnflag, l_linestatus, sum(l_quantity),
+               sum(l_extendedprice), sum(l_extendedprice * (1 - l_discount)),
+               sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)),
+               avg(l_quantity), avg(l_extendedprice), avg(l_discount), count(*)
+        from lineitem
+        where l_shipdate <= date '1998-12-01' - interval '90' day
+        group by l_returnflag, l_linestatus
+        order by l_returnflag, l_linestatus
+
+    over ``decimal(12,2)`` measures, a ``DATE`` and two int32 dictionary
+    codes.  Spark types ``disc_price`` ``decimal(26,4)`` and ``charge``
+    ``decimal(38,6)`` (raw ``(40,6)``, adjusted with the scale kept); the
+    sums come out ``decimal(22,2)``, ``(22,2)``, ``(36,4)``, ``(38,6)``,
+    the averages ``decimal(16,6)``."""
+    disc_price = Col("l_extendedprice") * (1 - Col("l_discount"))
+    lineitem = Project(
+        Filter(Scan("lineitem"), "l_shipdate", "<=",
+               DateLit("1998-12-01", -int(delta_days))),
+        ("l_returnflag", "l_linestatus", "l_quantity", "l_extendedprice",
+         "l_discount",
+         ("disc_price", disc_price),
+         ("charge", disc_price * (1 + Col("l_tax")))))
+    keys = ("l_returnflag", "l_linestatus")
+    return Sort(Aggregate(
+        lineitem, keys=keys,
+        aggs=(Agg("sum", "l_quantity", "sum_qty"),
+              Agg("sum", "l_extendedprice", "sum_base_price"),
+              Agg("sum", "disc_price", "sum_disc_price"),
+              Agg("sum", "charge", "sum_charge"),
+              Agg("mean", "l_quantity", "avg_qty"),
+              Agg("mean", "l_extendedprice", "avg_price"),
+              Agg("mean", "l_discount", "avg_disc"),
+              Agg("count", None, "count_order")),
+        domain=TPCH_Q1_DOMAINS, onehot=True), keys)

@@ -33,6 +33,7 @@ capture explains its own anomalies.
 from __future__ import annotations
 
 import collections
+import contextlib
 import glob
 import gzip
 import io
@@ -331,6 +332,20 @@ def scope(name: str):
         raise ValueError(f"scope name {name!r}: want <layer>.<what> of "
                          "letters, digits, '_' and '.'")
     return jax.named_scope(name)
+
+
+@contextlib.contextmanager
+def detached():
+    """Lower what is inside under an empty scope path, and yield ``rejoin``,
+    a context that re-enters the caller's path.  A loop whose body holds
+    operations of more than one plan node is built under this (the loop's
+    own path is put before every operation of its body), so each
+    operation's path still starts at the one node it belongs to."""
+    from jax._src import source_info_util as siu
+
+    outer = siu.current_name_stack()
+    with siu.reset_name_stack():
+        yield lambda: siu.set_name_stack(outer)
 
 
 def scope_name(text) -> str:

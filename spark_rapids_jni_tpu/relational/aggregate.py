@@ -54,7 +54,7 @@ from cudf groupby + Spark's type promotion):
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +68,7 @@ from ..columnar.encoded import (
     materialize_batch,
     materialize_column,
 )
+from .. import profiler
 from ..profiler import scope
 from . import keys as K
 from .gather import gather_column
@@ -112,6 +113,48 @@ class AggSpec:
             raise ValueError(f"unknown agg op {self.op!r}")
         if self.column is None and self.op != "count":
             raise ValueError("only count supports column=None (count(*))")
+
+
+@dataclasses.dataclass(frozen=True)
+class Derived:
+    """Columns computed from a batch's own (a plan's ``Project``), handed to
+    a domain engine unevaluated: ``dtypes`` names each and its Spark type,
+    ``fn(batch)`` gives the columns over whatever rows ``batch`` holds.
+    The one-hot engine calls it on one row block at a time, so a wide
+    product never exists for the whole table; the engines that read whole
+    columns call it once."""
+
+    dtypes: dict
+    fn: Callable
+
+    def over(self, batch: ColumnBatch) -> ColumnBatch:
+        """``batch`` with the computed columns beside its own."""
+        cols = dict(zip(batch.names, batch.columns))
+        cols.update(self.fn(batch))
+        return ColumnBatch(cols)
+
+
+def _is_decimal(dtype: T.SparkType) -> bool:
+    return dtype.kind is T.Kind.DECIMAL
+
+
+def _dtype_lookup(batch, dtypes):
+    """name -> SparkType: of ``dtypes`` (a :class:`Derived`'s columns, which
+    the batch does not hold) first, of the batch's own columns else."""
+    dtypes = dtypes or {}
+    return lambda c: dtypes[c] if c in dtypes else batch[c].dtype
+
+
+def _widen_decimal(col):
+    """A decimal in 32- or 64-bit storage as a :class:`Decimal128Column`
+    (sign-extended), for the engines whose exact sums run on its limbs."""
+    if isinstance(col, Decimal128Column):
+        return col
+    lo = jax.lax.bitcast_convert_type(col.data.astype(jnp.int64), jnp.uint64)
+    hi = jnp.where(col.data < 0, jnp.uint64(0xFFFFFFFFFFFFFFFF),
+                   jnp.uint64(0))
+    return Decimal128Column(jnp.stack([lo, hi], axis=1), col.validity,
+                            col.dtype)
 
 
 def _sum_dtype(dtype: T.SparkType) -> T.SparkType:
@@ -213,9 +256,14 @@ def _decimal_avg(s256, cnt, in_dtype):
         if d else s256
     mag, neg = D._abs(scaled)
     den = jnp.maximum(cnt, 1).astype(jnp.uint64)
-    q, rem = D._divmod_u_small(mag, den)
+    # a quotient that fits the result type has a numerator under
+    # 10^p_res x 2^31 rows: only that many limbs are divided, and a
+    # numerator past them is an overflow whatever its quotient
+    limbs = min(8, -(-((10**p_res) << 31).bit_length() // 32))
+    q, rem = D._divmod_u_small(mag, den, limbs)
     q = D._add_small(q, ((rem * 2) >= den).astype(jnp.int32))  # HALF_UP
-    ok = D._lt_u(q, jnp.broadcast_to(D._pow10(p_res), q.shape))
+    ok = D._lt_u(q, jnp.broadcast_to(D._pow10(p_res), q.shape)) \
+        & (mag[:, limbs:] == 0).all(axis=1)
     signed = jnp.where(neg[:, None], D._neg(q), q)
     return (D._to_i128(signed), ok,
             T.SparkType.decimal(p_res, s_res))
@@ -425,7 +473,12 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                                             out_valid, T.INT64)
                 continue
 
-            if isinstance(batch[spec.column], Decimal128Column):
+            dcol = batch[spec.column]
+            if _is_decimal(dcol.dtype) and spec.op in ("sum", "mean"):
+                # Spark types sum(decimal(p,s)) decimal(p+10,s) whatever
+                # stores the column: a 64-bit one sums on the same limbs
+                dcol = _widen_decimal(dcol)
+            if isinstance(dcol, Decimal128Column):
                 # Decimal128 aggregation over sorted runs.  sum/mean: exact
                 # 256-bit segmented sums (values sign-extend to uint32[n,8]; a
                 # 2^31-row group of |v|<2^127 stays < 2^158, never wraps) —
@@ -438,7 +491,6 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                 # aggregate expressions.)
                 from ..ops import decimal as D
 
-                dcol = batch[spec.column]
                 svalid = sorted_valid(spec.column)
                 slimbs = jnp.take(dcol.limbs, sperm, axis=0)
                 nn_d = at_ends_diff(jnp.cumsum(svalid.astype(jnp.int32)))
@@ -636,6 +688,8 @@ def _scatter_groups(batch, key_names, aggs, karr, row_live, owner, slot, S):
             continue
 
         col = batch[spec.column]
+        if _is_decimal(col.dtype) and spec.op in ("sum", "mean"):
+            col = _widen_decimal(col)   # typed by Spark whatever stores it
         valid = col.validity & row_live
         nn = seg_sum(valid.astype(jnp.int32))
         has_any = nn > 0
@@ -745,10 +799,11 @@ def group_by_onehot(
     batch: ColumnBatch,
     key_name: str,
     aggs: Sequence[AggSpec],
-    domain: int,
+    domain,
     row_valid=None,
     float_mode: str = "f64",
     engine: str = "xla",
+    derive: Optional[Derived] = None,
 ):
     """Hash-aggregate as matmuls: the TPU-first alternative to the
     sort-scan path when one integer key column has a small static domain
@@ -781,9 +836,23 @@ def group_by_onehot(
     * float sums in ``f32x3`` mode ride ONE f32 contraction (exact 3-way
       Dekker split of the f64 mantissa — MXU-native, but accumulated in
       f32: about 5e-5 relative off at q6's size);
+    * decimal sums (any storage width) are exact: the two's-complement
+      bytes a ``decimal(p, s)`` needs (6 for p=12, 11 for p=26, 16 for
+      p=38) as ``b_l - 128`` limbs plus one negative-flag slot, rebuilt in
+      256 bits, typed ``decimal(min(38, p+10), s)`` and judged against
+      ``10^precision`` (past it the group is null, Spark's non-ANSI
+      ``Sum``); the average is ``decimal(p+4, s+4)``, HALF_UP;
     * mean: sum / count in f64.
 
-    min/max and multi-column keys stay on the sort-scan path.  Returns
+    ``key_name`` and ``domain`` may be tuples, one domain per key: the
+    keys become one composite bucket (each key's null a bucket of its
+    own, first), and the groups come out in key order, nulls first.
+    ``derive`` (:class:`Derived`) names aggregated columns that are
+    computed from the batch's own: the XLA engine then builds payload and
+    one-hot one slice of ``_ONEHOT_SLICE`` rows at a time inside a loop
+    (``agg.onehot_slice``) and evaluates them there.
+
+    min/max stay on the sort-scan path.  Returns
     ``(result, num_groups, overflow)`` — ``overflow`` is a device bool
     that is True if any non-null key fell outside ``[0, domain)`` (result
     is then invalid; callers assert or fall back).
@@ -804,15 +873,17 @@ def group_by_onehot(
     across a mesh) followed by :func:`_finalize_domain`.
     """
     parts, overflow = _domain_partials(batch, key_name, aggs, domain,
-                                       row_valid, engine, float_mode)
+                                       row_valid, engine, float_mode,
+                                       derive)
     with scope("agg.finalize"):
-        res, ng = _finalize_domain(batch, key_name, int(domain), aggs,
-                                   parts)
+        res, ng = _finalize_domain(
+            batch, key_name, domain, aggs, parts,
+            dtypes=derive.dtypes if derive is not None else None)
     return res, ng, overflow
 
 
 def _domain_partials(batch, key_name, aggs, domain, row_valid=None,
-                     engine="auto", float_mode="f64"):
+                     engine="auto", float_mode="f64", derive=None):
     """Additive per-bucket partial aggregates over a static key domain.
 
     Returns ``(parts, overflow)`` where ``parts`` is a pytree of
@@ -824,7 +895,7 @@ def _domain_partials(batch, key_name, aggs, domain, row_valid=None,
       merging, exactly Spark's non-ANSI overflow)
     * ``fsum``  {col: float64[K+1]} — float sums (merge-order rounding
       sits inside Spark's shuffle nondeterminism)
-    * ``d64``   {col: uint64[K+1, 8]} — decimal128 sums as 256-bit
+    * ``d64``   {col: uint64[K+1, 8]} — decimal sums as 256-bit
       two's-complement u32 limbs widened to u64, so a psum over P
       devices cannot carry out of a lane (P·2^32 < 2^64); the merged
       lanes re-fold in :func:`_finalize_domain`
@@ -837,36 +908,108 @@ def _domain_partials(batch, key_name, aggs, domain, row_valid=None,
     # columns materialize here (their late point of need)
     batch = materialize_batch(batch)
     engine = _resolve_onehot_engine(engine)
+    if derive is not None and engine != "xla":
+        # these engines read whole columns: so are the computed ones
+        batch, derive = derive.over(batch), None
     if engine == "scatter":
         return _domain_partials_scatter(batch, key_name, aggs, domain,
                                         row_valid)
     return _domain_partials_onehot(batch, key_name, aggs, domain,
-                                   row_valid, float_mode, engine)
+                                   row_valid, float_mode, engine, derive)
 
 
-def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
-                            float_mode, engine):
-    K = int(domain)
-    col = batch[key_name]
-    if col.dtype.kind not in (T.Kind.INT8, T.Kind.INT16, T.Kind.INT32,
-                              T.Kind.INT64):
-        raise TypeError("group_by_onehot needs an integer key column")
-    n = col.num_rows
-    row_live = jnp.ones((n,), jnp.bool_) if row_valid is None else row_valid
-    live = col.validity & row_live
+# Rows to a block of the one-hot contraction.  int32 partials hold
+# |x| <= 128 a row, so a block stays under 2^31/128 = 2^24 rows.  A payload
+# built whole is contracted in _ONEHOT_BLOCK rows at a time; one built slice
+# by slice (computed columns) takes _ONEHOT_SLICE rows, payload and all.
+_ONEHOT_BLOCK = 1 << 23
+_ONEHOT_SLICE = 1 << 19
+_ONEHOT_SLOTS = [0]
 
-    # null keys form their own group (bucket K), like the sort-scan path;
-    # dead padding rows are dropped from the onehot entirely (callers
-    # rely on the overflow flag to fall back to sort-scan)
-    with scope("agg.onehot_bucket"):
-        bucket, overflow = _domain_bucket_overflow(col, live, K)
 
-    # ---- plan the stacked payload ------------------------------------
-    # int8 slots: [0]=ones(count*), then per referenced column one valid
-    # flag, then 8 byte limbs per integer sum column
-    is_float = {}
-    int_cols, float_cols, dec_cols = [], [], []
+def onehot_slots() -> int:
+    """The int8 slots of the newest one-hot contraction this process
+    traced (q6 under ``f64``: 27): the width its payload's assembly and the
+    contraction scale with.  ``plan.plan_cache_metrics()`` carries it."""
+    return _ONEHOT_SLOTS[0]
+
+
+def _keys_domains(key_name, domain):
+    """One key and an int domain is the single-key layout (null keys in
+    bucket K, last); tuples are one domain per key of a composite bucket
+    (each key's null first)."""
+    if isinstance(key_name, str):
+        return (key_name,), int(domain)
+    keys = tuple(key_name)
+    domains = tuple(int(d) for d in domain) \
+        if isinstance(domain, (list, tuple)) else (int(domain),)
+    if len(keys) != len(domains):
+        raise ValueError(f"{len(domains)} domains for {len(keys)} keys")
+    return keys, domains
+
+
+def _bucket_count(domains) -> int:
+    if isinstance(domains, int):
+        return domains + 1
+    g = 1
+    for K_ in domains:
+        g *= K_ + 1
+    return g
+
+
+def _domain_buckets(batch, keys, domains, row_live):
+    """Bucket id per row and the out-of-domain flag, for either layout.
+    Composite: key i contributes ``0`` for a null and ``k + 1`` for ``k``,
+    most significant key first, so bucket order is key order with nulls
+    first.  Dead rows get some bucket; callers mask them by ``row_live``."""
+    for k in keys:
+        if batch[k].dtype.kind not in (T.Kind.INT8, T.Kind.INT16,
+                                       T.Kind.INT32, T.Kind.INT64):
+            raise TypeError("the domain engines need integer key columns")
+    if isinstance(domains, int):
+        col = batch[keys[0]]
+        return _domain_bucket_overflow(col, col.validity & row_live, domains)
+    bucket = jnp.zeros(row_live.shape, jnp.int32)
+    overflow = jnp.zeros((), jnp.bool_)
+    for k, K_ in zip(keys, domains):
+        col = batch[k]
+        # the bounds check at the key's own width (int64 only for an
+        # int64 key: a narrower one cannot hold what would wrap)
+        wide = jnp.int64 if col.data.dtype == jnp.int64 else jnp.int32
+        kv = col.data.astype(wide)
+        overflow = overflow | jnp.any(col.validity & row_live
+                                      & ((kv < 0) | (kv >= K_)))
+        idx = jnp.where(col.validity,
+                        jnp.clip(kv, 0, K_ - 1).astype(jnp.int32) + 1, 0)
+        bucket = bucket * jnp.int32(K_ + 1) + idx
+    return bucket, overflow
+
+
+def _decimal_sum_bytes(precision: int) -> int:
+    """Two's-complement bytes that hold every ``|v| < 10^precision``."""
+    return -(-((10**precision - 1).bit_length() + 1) // 8)
+
+
+@dataclasses.dataclass
+class _SlotPlan:
+    """The int8 slots of the stacked payload: ``[0]`` the count(*) ones,
+    one valid flag per referenced column, eight byte limbs per integer
+    sum column, then per decimal sum column the bytes its precision needs
+    and a negative flag (``m8`` slots; float digits, where the mode has
+    them, follow)."""
+
+    valid_slot: dict
+    int_cols: list
+    float_cols: list
+    dec_cols: list
+    limb_slot: dict
+    dec_slot: dict   # column -> (first slot, bytes)
+    m8: int
+
+
+def _plan_onehot_slots(aggs, dtype_of) -> _SlotPlan:
     valid_slot = {}
+    int_cols, float_cols, dec_cols = [], [], []
     for spec in aggs:
         if spec.op not in ("sum", "mean", "count"):
             raise NotImplementedError(
@@ -874,68 +1017,169 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
         if spec.column is None:
             continue
         c = spec.column
-        if isinstance(batch[c], Decimal128Column):
-            if spec.op not in ("sum", "count", "mean"):
-                raise NotImplementedError(
-                    f"group_by_onehot: {spec.op} over decimal groups "
-                    "stays on the sort-scan path")
-            valid_slot.setdefault(c, 0)
-            is_float[c] = False
-            if spec.op in ("sum", "mean") and c not in dec_cols:
-                dec_cols.append(c)
-            continue
         valid_slot.setdefault(c, 0)  # slot index assigned below
         if spec.op in ("sum", "mean"):
-            fl = batch[c].dtype.kind in (T.Kind.FLOAT32, T.Kind.FLOAT64)
-            is_float[c] = fl
-            target = float_cols if fl else int_cols
+            dt = dtype_of(c)
+            if _is_decimal(dt):
+                target = dec_cols
+            elif dt.kind in (T.Kind.FLOAT32, T.Kind.FLOAT64):
+                target = float_cols
+            else:
+                target = int_cols
             if c not in target:
                 target.append(c)
+    m = 1  # slot 0: count(*)
+    for c in valid_slot:
+        valid_slot[c] = m
+        m += 1
+    limb_slot = {}
+    for c in int_cols:
+        limb_slot[c] = m
+        m += 8
+    dec_slot = {}
+    for c in dec_cols:
+        nb = _decimal_sum_bytes(dtype_of(c).precision)
+        dec_slot[c] = (m, nb)
+        m += nb + 1
+    return _SlotPlan(valid_slot, int_cols, float_cols, dec_cols, limb_slot,
+                     dec_slot, m)
 
-    with scope("agg.onehot_payload"):
-        cols8 = [jnp.ones((n,), jnp.int8)]  # slot 0: count(*)
-        for c in valid_slot:
-            valid_slot[c] = len(cols8)
-            cols8.append((batch[c].validity & row_live).astype(jnp.int8))
-        limb_slot = {}
-        for c in int_cols:
-            vcol = batch[c]
-            vvalid = vcol.validity & row_live
-            u = jax.lax.bitcast_convert_type(
-                jnp.where(vvalid, vcol.data.astype(jnp.int64), jnp.int64(0)),
-                jnp.uint64)
-            bytes8 = jax.lax.bitcast_convert_type(u, jnp.uint8)  # [n, 8]
-            x = jnp.where(vvalid[:, None],
-                          bytes8.astype(jnp.int16) - jnp.int16(128),
-                          jnp.int16(0)).astype(jnp.int8)
-            limb_slot[c] = len(cols8)
-            cols8.extend(x[:, j] for j in range(8))
-        # decimal128 sum columns: 16 byte limbs of the two's-complement
-        # unscaled value + one negative-flag slot (the signed sum is the
-        # unsigned-representation sum minus 2^128 x #negatives — unlike the
-        # int64 path that correction does NOT wrap away, since decimal
-        # overflow is judged exactly against 10^precision)
-        dec_slot = {}
-        for c in dec_cols:
-            vcol = batch[c]
-            vvalid = vcol.validity & row_live
-            limbs = jnp.where(vvalid[:, None], vcol.limbs,
-                              jnp.zeros((), jnp.uint64))
-            bytes16 = jax.lax.bitcast_convert_type(
-                limbs, jnp.uint8).reshape(n, 16)
-            x = jnp.where(vvalid[:, None],
-                          bytes16.astype(jnp.int16) - jnp.int16(128),
-                          jnp.int16(0)).astype(jnp.int8)
-            neg = (vvalid
-                   & ((limbs[:, 1] >> jnp.uint64(63)) != 0)).astype(jnp.int8)
-            dec_slot[c] = len(cols8)
-            cols8.extend(x[:, j] for j in range(16))
-            cols8.append(neg)
 
-    def dekker_limbs(c):
-        vcol = batch[c]
-        return _dekker_limbs(jnp.where(vcol.validity & row_live,
-                                       vcol.data.astype(jnp.float64), 0.0))
+def _onehot_payload8(cols, row_live, lay: _SlotPlan):
+    """The int8 columns of ``lay``'s count, valid-flag and integer-limb
+    slots over the rows ``cols`` holds."""
+    n = row_live.shape[0]
+    cols8 = [jnp.ones((n,), jnp.int8)]  # slot 0: count(*)
+    for c in lay.valid_slot:
+        cols8.append((cols[c].validity & row_live).astype(jnp.int8))
+    for c in lay.int_cols:
+        vcol = cols[c]
+        vvalid = vcol.validity & row_live
+        u = jax.lax.bitcast_convert_type(
+            jnp.where(vvalid, vcol.data.astype(jnp.int64), jnp.int64(0)),
+            jnp.uint64)
+        bytes8 = jax.lax.bitcast_convert_type(u, jnp.uint8)  # [n, 8]
+        x = jnp.where(vvalid[:, None],
+                      bytes8.astype(jnp.int16) - jnp.int16(128),
+                      jnp.int16(0)).astype(jnp.int8)
+        cols8.extend(x[:, j] for j in range(8))
+    return cols8
+
+
+def _decimal_pieces(vcol, vvalid, nb):
+    """The int8 slots of one decimal sum column as 2-D pieces ``[n, k]`` in
+    slot order: the low ``nb`` bytes of the two's-complement unscaled value
+    as ``b - 128``, four to a u32 word of the value, then one negative-flag
+    slot (the signed sum is the unsigned-representation sum minus
+    2^(8 nb) x #negatives — unlike the int64 path that correction does NOT
+    wrap away, since decimal overflow is judged exactly against
+    10^precision).  A value past its type's precision is not a value of the
+    type.  ``b - 128`` in int8 is ``b`` with its top bit flipped,
+    reinterpreted: a word's four bytes are one xor and one bitcast."""
+    m32 = jnp.uint64(0xFFFFFFFF)
+    if isinstance(vcol, Decimal128Column):
+        halves = [vcol.limbs[:, 0], vcol.limbs[:, 1]]
+    else:
+        halves = [jax.lax.bitcast_convert_type(
+            vcol.data.astype(jnp.int64), jnp.uint64)]
+    words = [w.astype(jnp.uint32) for h in halves
+             for w in (h & m32, h >> jnp.uint64(32))]
+    pieces = [jnp.where(vvalid[:, None], jax.lax.bitcast_convert_type(
+        w ^ jnp.uint32(0x80808080), jnp.int8)[:, :min(4, nb - 4 * i)],
+        jnp.int8(0)) for i, w in enumerate(words[:-(-nb // 4)])]
+    neg = vvalid & ((halves[-1] >> jnp.uint64(63)) != 0)
+    return pieces + [neg.astype(jnp.int8)[:, None]]
+
+
+def _onehot_payload(cols, row_live, lay: _SlotPlan):
+    """``X8``, the stacked payload: int8 ``[n, lay.m8]`` over the rows
+    ``cols`` holds (a whole batch, or one slice of it with its computed
+    columns)."""
+    head = _onehot_payload8(cols, row_live, lay)
+    if not lay.dec_cols:
+        return jnp.stack(head, axis=1)
+    # with decimal columns every slot group is a 2-D piece, and the pieces
+    # stand side by side as zero-padded terms of one sum, which the TPU
+    # compiler folds into the contraction's operand.  A stack of 1-D slot
+    # vectors is a relayout and a concatenate there: 84 of the 102 ms a
+    # TPC-H Q1 query took on the chip, where this takes 49 of 62
+    pieces = [h[:, None] for h in head]
+    for c in lay.dec_cols:
+        pieces += _decimal_pieces(cols[c], cols[c].validity & row_live,
+                                  lay.dec_slot[c][1])
+    X8, at = None, 0
+    for piece in pieces:
+        term = jnp.pad(piece, ((0, 0),
+                               (at, lay.m8 - at - piece.shape[1])))
+        X8 = term if X8 is None else X8 + term
+        at += piece.shape[1]
+    return X8
+
+
+def _onehot_contract_int8(bucket, live, X8, kids):
+    """One block: the one-hot of ``bucket`` over ``kids`` contracted with
+    the payload's rows, in int32 on the MXU."""
+    with scope("agg.onehot_build"):
+        ohc = (bucket[:, None] == kids) & live[:, None]
+    with scope("agg.onehot_contract_int8"):
+        return jax.lax.dot_general(
+            ohc.astype(jnp.int8).T, X8, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32).astype(jnp.int64)
+
+
+def _onehot_sliced(batch, derive, lay, keys, domains, row_live):
+    """The contraction with payload, bucket and one-hot built one slice of
+    ``_ONEHOT_SLICE`` rows at a time, inside one loop: where aggregated
+    columns are computed (``derive``) they are computed for the slice and
+    consumed there, so a wide product never exists for the whole table.
+    The last slice starts where a whole one still fits and leaves out the
+    rows the slice before it took, so every slice has one shape."""
+    n = batch.num_rows
+    rows = min(_ONEHOT_SLICE, n)
+    kids = jnp.arange(_bucket_count(domains), dtype=jnp.int32)[None, :]
+
+    with profiler.detached() as rejoin:
+        # the loop itself is lowered under no scope, and its body re-enters
+        # the caller's path for the aggregate's phases: the computed
+        # columns' operations then keep the paths of their own plan node
+        def body(i, carry):
+            part, overflow = carry
+            start = jnp.minimum(i * rows, n - rows)
+            with rejoin(), scope("agg.onehot_slice"):
+                def cut(a):
+                    return jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
+
+                blk = jax.tree_util.tree_map(cut, batch)
+                live = cut(row_live) & (
+                    start + jnp.arange(rows, dtype=jnp.int32) >= i * rows)
+            blk = derive.over(blk)
+            with rejoin():
+                with scope("agg.onehot_bucket"):
+                    bucket, ovf = _domain_buckets(blk, keys, domains, live)
+                with scope("agg.onehot_payload"):
+                    X8 = _onehot_payload(blk, live, lay)
+                part = part + _onehot_contract_int8(bucket, live, X8, kids)
+            return part, overflow | ovf
+
+        init = (jnp.zeros((kids.shape[1], lay.m8), jnp.int64),
+                jnp.zeros((), jnp.bool_))
+        return jax.lax.fori_loop(jnp.int32(0), jnp.int32(-(-n // rows)),
+                                 body, init)
+
+
+def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
+                            float_mode, engine, derive=None):
+    keys, domains = _keys_domains(key_name, domain)
+    G = _bucket_count(domains)
+    n = batch.num_rows
+    row_live = jnp.ones((n,), jnp.bool_) if row_valid is None else row_valid
+
+    # ---- plan the stacked payload ------------------------------------
+    lay = _plan_onehot_slots(aggs, _dtype_lookup(
+        batch, derive.dtypes if derive is not None else None))
+    int_cols, float_cols, dec_cols = (lay.int_cols, lay.float_cols,
+                                      lay.dec_cols)
+    valid_slot, limb_slot = lay.valid_slot, lay.limb_slot
 
     if engine not in ("xla", "pallas"):
         raise ValueError(f"unknown engine {engine!r} "
@@ -947,69 +1191,18 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
             "float_mode='f32x3' to acknowledge the non-bit-stable rounding")
     use_f32x3 = float_mode == "f32x3" or engine == "pallas"
 
-    F = None
-    digits_of = {}  # f64 mode: column -> (int8[n, _FX_SLOTS], emax)
-    with scope("agg.onehot_payload"):
-        if float_cols and use_f32x3:
-            F = jnp.stack(
-                sum((dekker_limbs(c) for c in float_cols), []), axis=1)
-        elif float_cols:
-            # f64 mode: a double is a fixed-point number on a grid chosen
-            # from the column, and its digits are more int8 slots
-            with scope("agg.onehot_digits"):
-                for c in float_cols:
-                    digits_of[c] = _float_digit_slots(
-                        batch[c].data.astype(jnp.float64),
-                        batch[c].validity & row_live)
-        X8 = jnp.stack(cols8, axis=1)  # [n, m8]
-        digit_slot = {c: len(cols8) + _FX_SLOTS * i
-                      for i, c in enumerate(digits_of)}
-        if digits_of:
-            # side by side as zero-padded terms of one sum, which the TPU
-            # compiler folds into the contraction's operand; a concatenate
-            # of int8 pieces is a relayout pass of its own there
-            m8 = len(cols8) + _FX_SLOTS * len(digits_of)
-            X8 = jnp.pad(X8, ((0, 0), (0, m8 - len(cols8))))
-            for c, (digits, _) in digits_of.items():
-                s = digit_slot[c]
-                X8 = X8 + jnp.pad(digits,
-                                  ((0, 0), (s, m8 - s - _FX_SLOTS)))
-
-    if engine == "pallas":
-        from ..ops.pallas_kernels import onehot_groupby_parts
-
-        bucket_pl = jnp.where(row_live, bucket, jnp.int32(-1))
-        Fp = F if F is not None else jnp.zeros((n, 0), jnp.float32)
-        part, fpart = onehot_groupby_parts(bucket_pl, X8, Fp, K + 1)
+    fpart, digits_of, digit_slot = None, {}, {}
+    if derive is not None:
+        if float_cols:
+            raise NotImplementedError(
+                "group_by_onehot: a float sum beside computed columns (its "
+                "digit grid is set from the whole column)")
+        part, overflow = _onehot_sliced(batch, derive, lay, keys, domains,
+                                        row_live)
+        _ONEHOT_SLOTS[0] = lay.m8
     else:
-        # Chunked contractions with the one-hot built PER CHUNK: int32
-        # partials hold |x| <= 128 summed over a block, so blocks stay
-        # under 2^31/128 = 2^24 rows — and only one [B, K+1] one-hot is
-        # ever live (a full-width [n, K+1] one-hot is multi-GB at bench
-        # row counts).  Static n means static slices, combined in
-        # int64/float64 across chunks.
-        B = 1 << 23
-        kids = jnp.arange(K + 1, dtype=jnp.int32)[None, :]
-        part = jnp.zeros((K + 1, X8.shape[1]), jnp.int64)
-        fpart = (jnp.zeros((K + 1, F.shape[1]), jnp.float64)
-                 if F is not None else None)
-        for lo in range(0, n, B):
-            with scope("agg.onehot_build"):
-                ohc = ((bucket[lo:lo + B, None] == kids)
-                       & row_live[lo:lo + B, None])
-            with scope("agg.onehot_contract_int8"):
-                part = part + jax.lax.dot_general(
-                    ohc.astype(jnp.int8).T, X8[lo:lo + B],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32,
-                ).astype(jnp.int64)
-            if F is not None:
-                with scope("agg.onehot_contract_f32x3"):
-                    fpart = fpart + jax.lax.dot_general(
-                        ohc.astype(jnp.float32).T, F[lo:lo + B],
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    ).astype(jnp.float64)
+        part, fpart, overflow, digits_of, digit_slot = _onehot_whole(
+            batch, lay, keys, domains, row_live, use_f32x3, engine)
 
     with scope("agg.onehot_rebuild"):
         fsum_of = {}
@@ -1036,25 +1229,24 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
                 << shifts, axis=1)
             isum_of[c] = jax.lax.bitcast_convert_type(total_u, jnp.int64)
 
-        # ---- exact decimal128 sums: 256-bit rebuild with sign correction --
-        # sum = (Σ_j true_limb_j · 256^j) − 2^128 · #negatives, carried out in
-        # uint32[K+1, 8] limbs (≤ 2^158 for 2^31 rows — never wraps); overflow
-        # vs 10^min(38, p+10) nulls the group (Spark non-ANSI Sum)
+        # ---- exact decimal sums: 256-bit rebuild with sign correction -----
+        # sum = (Σ_j true_limb_j · 256^j) − 2^(8·bytes) · #negatives, carried
+        # out in uint32[G, 8] limbs (≤ 2^158 for 2^31 rows — never wraps);
+        # overflow vs 10^min(38, p+10) nulls the group (Spark non-ANSI Sum)
         d64_of = {}
         if dec_cols:
             from ..ops import decimal as D
 
             m32 = jnp.uint64(0xFFFFFFFF)
-            KP1 = K + 1
             for c in dec_cols:
-                s = dec_slot[c]
+                s, nb = lay.dec_slot[c]
                 true_limb = jax.lax.bitcast_convert_type(
-                    part[:, s:s + 16]
+                    part[:, s:s + nb]
                     + jnp.int64(128) * cnt_of[c][:, None], jnp.uint64)
                 # lane accumulators stay uint64 (each < 2^41 + carries);
                 # every byte sum j lands at bit 8j = 32·(j//4) + 8·(j%4)
-                lanes = [jnp.zeros((KP1,), jnp.uint64) for _ in range(9)]
-                for j in range(16):
+                lanes = [jnp.zeros((G,), jnp.uint64) for _ in range(9)]
+                for j in range(nb):
                     q, r = divmod(8 * j, 32)
                     slo = true_limb[:, j] & m32  # < 2^33; slo<<r fits u64
                     shi = true_limb[:, j] >> jnp.uint64(32)
@@ -1065,14 +1257,107 @@ def _domain_partials_onehot(batch, key_name, aggs, domain, row_valid,
                         + (b & m32)
                     lanes[q + 2] = lanes[q + 2] + (b >> jnp.uint64(32))
                 usum = _carry_fold_u64_lanes(jnp.stack(lanes[:8], axis=1))
-                negcnt = part[:, s + 16]  # >= 0, < 2^31: one u32 limb at 2^128
-                sub = jnp.zeros((KP1, 8), jnp.uint32).at[:, 4].set(
-                    negcnt.astype(jnp.uint32))
+                # #negatives >= 0, < 2^31, at bit 8·bytes: two u32 limbs
+                q, r = divmod(8 * nb, 32)
+                negs = part[:, s + nb].astype(jnp.uint64) << jnp.uint64(r)
+                sub = jnp.zeros((G, 8), jnp.uint32) \
+                    .at[:, q].set((negs & m32).astype(jnp.uint32)) \
+                    .at[:, q + 1].set(
+                        (negs >> jnp.uint64(32)).astype(jnp.uint32))
                 d64_of[c] = D._add(usum, D._neg(sub)).astype(jnp.uint64)
 
     parts = {"star": counts_star, "cnt": cnt_of, "isum": isum_of,
              "fsum": fsum_of, "d64": d64_of}
     return parts, overflow
+
+
+def _onehot_whole(batch, lay, keys, domains, row_live, use_f32x3, engine):
+    """The payload built for the whole batch and contracted in
+    ``_ONEHOT_BLOCK``-row blocks (every column is the batch's own).
+    Returns ``(part, fpart, overflow, digits_of, digit_slot)``: the last
+    two say where the float digits it laid out are (f64 mode: column ->
+    (int8[n, _FX_SLOTS], emax), column -> first slot)."""
+    n = batch.num_rows
+    digits_of = {}
+    G = _bucket_count(domains)
+    float_cols = lay.float_cols
+
+    # null keys form their own group (bucket K), like the sort-scan path;
+    # dead padding rows are dropped from the onehot entirely (callers
+    # rely on the overflow flag to fall back to sort-scan)
+    with scope("agg.onehot_bucket"):
+        bucket, overflow = _domain_buckets(batch, keys, domains, row_live)
+
+    with scope("agg.onehot_payload"):
+        X8 = _onehot_payload(batch, row_live, lay)  # [n, lay.m8]
+
+    def dekker_limbs(c):
+        vcol = batch[c]
+        return _dekker_limbs(jnp.where(vcol.validity & row_live,
+                                       vcol.data.astype(jnp.float64), 0.0))
+
+    F = None
+    with scope("agg.onehot_payload"):
+        if float_cols and use_f32x3:
+            F = jnp.stack(
+                sum((dekker_limbs(c) for c in float_cols), []), axis=1)
+        elif float_cols:
+            # f64 mode: a double is a fixed-point number on a grid chosen
+            # from the column, and its digits are more int8 slots
+            with scope("agg.onehot_digits"):
+                for c in float_cols:
+                    digits_of[c] = _float_digit_slots(
+                        batch[c].data.astype(jnp.float64),
+                        batch[c].validity & row_live)
+        digit_slot = {c: lay.m8 + _FX_SLOTS * i
+                      for i, c in enumerate(digits_of)}
+        if digits_of:
+            # side by side as zero-padded terms of one sum, which the TPU
+            # compiler folds into the contraction's operand; a concatenate
+            # of int8 pieces is a relayout pass of its own there
+            m8 = lay.m8 + _FX_SLOTS * len(digits_of)
+            X8 = jnp.pad(X8, ((0, 0), (0, m8 - lay.m8)))
+            for c, (digits, _) in digits_of.items():
+                s = digit_slot[c]
+                X8 = X8 + jnp.pad(digits,
+                                  ((0, 0), (s, m8 - s - _FX_SLOTS)))
+    _ONEHOT_SLOTS[0] = X8.shape[1]
+
+    if engine == "pallas":
+        from ..ops.pallas_kernels import onehot_groupby_parts
+
+        bucket_pl = jnp.where(row_live, bucket, jnp.int32(-1))
+        Fp = F if F is not None else jnp.zeros((n, 0), jnp.float32)
+        part, fpart = onehot_groupby_parts(bucket_pl, X8, Fp, G)
+        return part, fpart, overflow, digits_of, digit_slot
+
+    # Chunked contractions with the one-hot built PER CHUNK: only one
+    # [B, G] one-hot is ever live (a full-width [n, G] one-hot is multi-GB
+    # at bench row counts).  Static n means static slices, combined in
+    # int64/float64 across chunks.
+    B = _ONEHOT_BLOCK
+    kids = jnp.arange(G, dtype=jnp.int32)[None, :]
+    part = jnp.zeros((G, X8.shape[1]), jnp.int64)
+    fpart = (jnp.zeros((G, F.shape[1]), jnp.float64)
+             if F is not None else None)
+    for lo in range(0, n, B):
+        with scope("agg.onehot_build"):
+            ohc = ((bucket[lo:lo + B, None] == kids)
+                   & row_live[lo:lo + B, None])
+        with scope("agg.onehot_contract_int8"):
+            part = part + jax.lax.dot_general(
+                ohc.astype(jnp.int8).T, X8[lo:lo + B],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            ).astype(jnp.int64)
+        if F is not None:
+            with scope("agg.onehot_contract_f32x3"):
+                fpart = fpart + jax.lax.dot_general(
+                    ohc.astype(jnp.float32).T, F[lo:lo + B],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ).astype(jnp.float64)
+    return part, fpart, overflow, digits_of, digit_slot
 
 
 # Fixed-point layout of one float sum column on the int8 contraction: the
@@ -1287,41 +1572,61 @@ def _carry_fold_u64_lanes(lanes):
     return jnp.stack(out32, axis=1)
 
 
-def _finalize_domain(batch, key_name, K, aggs, parts):
+def _finalize_domain(batch, key_name, K, aggs, parts, dtypes=None):
     """Turn (possibly psum-merged) :func:`_domain_partials` into the
     group-by result.  Decimal lanes re-fold their carries here — after
     merging — and the overflow-vs-10^p check runs on the GLOBAL sum, so
     a per-device overflow that cancels across devices does not null the
     group (matching what a single-chip aggregation of the union would
-    produce)."""
+    produce).  ``dtypes`` types the aggregated columns the batch does
+    not hold (a :class:`Derived`'s)."""
     from ..ops import decimal as D
 
+    keys, domains = _keys_domains(key_name, K)
+    dtype_of = _dtype_lookup(batch, dtypes)
     dsum_of, dover_of, draw_of = {}, {}, {}
     for c, d64 in parts["d64"].items():
         s256 = _carry_fold_u64_lanes(d64)
-        out_p = min(38, batch[c].dtype.precision + 10)
+        out_p = min(38, dtype_of(c).precision + 10)
         mag, _ = D._abs(s256)
         dover_of[c] = ~D._lt_u(mag, jnp.broadcast_to(D._pow10(out_p),
                                                      mag.shape))
         dsum_of[c] = (D._to_i128(s256),
-                      T.SparkType.decimal(out_p, batch[c].dtype.scale))
+                      T.SparkType.decimal(out_p, dtype_of(c).scale))
         draw_of[c] = s256
     return _assemble_domain_result(
-        batch, key_name, K, aggs, parts["star"], parts["cnt"],
-        parts["isum"], parts["fsum"], dsum_of, dover_of, draw_of)
+        batch, keys, domains, aggs, parts["star"], parts["cnt"],
+        parts["isum"], parts["fsum"], dsum_of, dover_of, draw_of, dtype_of)
 
 
-def _assemble_domain_result(batch, key_name, K, aggs, counts_star, cnt_of,
-                            isum_of, fsum_of, dsum_of, dover_of, draw_of):
+def _domain_key_columns(batch, keys, domains, counts_star):
+    """The key columns of every bucket, in bucket order."""
+    if isinstance(domains, int):
+        col = batch[keys[0]]
+        key_valid = jnp.arange(domains + 1) < domains
+        return {keys[0]: Column(
+            jnp.arange(domains + 1, dtype=col.dtype.jnp_dtype),
+            key_valid & (counts_star > 0), col.dtype)}
+    out = {}
+    g = jnp.arange(_bucket_count(domains), dtype=jnp.int32)
+    stride = _bucket_count(domains)
+    for k, K_ in zip(keys, domains):
+        stride //= K_ + 1
+        idx = (g // stride) % (K_ + 1)   # 0: the key's null
+        dt = batch[k].dtype
+        out[k] = Column(jnp.maximum(idx - 1, 0).astype(dt.jnp_dtype),
+                        (idx > 0) & (counts_star > 0), dt)
+    return out
+
+
+def _assemble_domain_result(batch, keys, domains, aggs, counts_star, cnt_of,
+                            isum_of, fsum_of, dsum_of, dover_of, draw_of,
+                            dtype_of):
     """Shared tail of the domain-key engines (onehot / scatter): turn the
     per-bucket reductions into a result batch with live groups compacted
-    to the front in key order (null-key bucket K last among live)."""
-    col = batch[key_name]
-    out_cols = {}
-    key_valid = jnp.arange(K + 1) < K
-    out_cols[key_name] = Column(
-        jnp.arange(K + 1, dtype=col.dtype.jnp_dtype),
-        key_valid & (counts_star > 0), col.dtype)
+    to the front in bucket order (one key: key order, the null-key bucket
+    K last among live; a composite bucket: key order, nulls first)."""
+    out_cols = _domain_key_columns(batch, keys, domains, counts_star)
 
     for spec in aggs:
         if spec.op == "count" and spec.column is None:
@@ -1336,7 +1641,7 @@ def _assemble_domain_result(batch, key_name, K, aggs, counts_star, cnt_of,
         if spec.column in dsum_of:
             if spec.op == "mean":
                 limbs128, ok, out_t = _decimal_avg(
-                    draw_of[spec.column], cnt_v, batch[spec.column].dtype)
+                    draw_of[spec.column], cnt_v, dtype_of(spec.column))
                 out_cols[spec.out_name] = Decimal128Column(
                     limbs128, (cnt_v > 0) & ok, out_t)
             else:
@@ -1407,7 +1712,7 @@ def group_by_scatter(
     """
     parts, overflow = _domain_partials_scatter(batch, key_name, aggs,
                                                domain, row_valid)
-    res, ng = _finalize_domain(batch, key_name, int(domain), aggs, parts)
+    res, ng = _finalize_domain(batch, key_name, domain, aggs, parts)
     return res, ng, overflow
 
 
@@ -1416,22 +1721,18 @@ def _domain_partials_scatter(batch, key_name, aggs, domain, row_valid=None):
     from jax.ops import segment_sum
 
     batch = materialize_batch(batch)  # direct group_by_scatter entry
-    K = int(domain)
-    col = batch[key_name]
-    if col.dtype.kind not in (T.Kind.INT8, T.Kind.INT16, T.Kind.INT32,
-                              T.Kind.INT64):
-        raise TypeError("group_by_scatter needs an integer key column")
-    n = col.num_rows
+    keys, domains = _keys_domains(key_name, domain)
+    G = _bucket_count(domains)
+    n = batch.num_rows
     row_live = jnp.ones((n,), jnp.bool_) if row_valid is None else \
         row_valid.astype(jnp.bool_)
-    live = col.validity & row_live
 
-    bucket, overflow = _domain_bucket_overflow(col, live, K)
-    # dead rows land in bucket K with all-zero contributions (their
+    bucket, overflow = _domain_buckets(batch, keys, domains, row_live)
+    # dead rows land in some bucket with all-zero contributions (their
     # count/valid/value weights below are masked by row_live)
 
     counts_star = segment_sum(
-        row_live.astype(jnp.int64), bucket, num_segments=K + 1)
+        row_live.astype(jnp.int64), bucket, num_segments=G)
 
     cnt_of, isum_of, fsum_of, d64_of = {}, {}, {}, {}
     for spec in aggs:
@@ -1445,13 +1746,15 @@ def _domain_partials_scatter(batch, key_name, aggs, domain, row_valid=None):
         vvalid = vcol.validity & row_live
         if c not in cnt_of:
             cnt_of[c] = segment_sum(
-                vvalid.astype(jnp.int64), bucket, num_segments=K + 1)
+                vvalid.astype(jnp.int64), bucket, num_segments=G)
         if spec.op not in ("sum", "mean"):
             continue
-        if isinstance(vcol, Decimal128Column):
+        if _is_decimal(vcol.dtype):
             if c in d64_of:
                 continue
             from ..ops import decimal as D
+
+            vcol = _widen_decimal(vcol)
 
             # _from_i128 sign-extends to 256-bit two's complement, so the
             # per-lane sums are already correct mod 2^256 (same argument
@@ -1462,19 +1765,19 @@ def _domain_partials_scatter(batch, key_name, aggs, domain, row_valid=None):
             # each u32 lane sums in uint64: n <= 2^31 rows of < 2^32
             # stays under 2^63; carry-propagate once at the end
             lanes = segment_sum(u.astype(jnp.uint64), bucket,
-                                num_segments=K + 1)  # [K+1, 8]
+                                num_segments=G)  # [G, 8]
             d64_of[c] = _carry_fold_u64_lanes(lanes).astype(jnp.uint64)
         elif vcol.dtype.kind in (T.Kind.FLOAT32, T.Kind.FLOAT64):
             if c not in fsum_of:
                 fsum_of[c] = segment_sum(
                     jnp.where(vvalid, vcol.data.astype(jnp.float64), 0.0),
-                    bucket, num_segments=K + 1)
+                    bucket, num_segments=G)
         else:
             if c not in isum_of:
                 isum_of[c] = segment_sum(
                     jnp.where(vvalid, vcol.data.astype(jnp.int64),
                               jnp.int64(0)),
-                    bucket, num_segments=K + 1)
+                    bucket, num_segments=G)
 
     return {"star": counts_star, "cnt": cnt_of, "isum": isum_of,
             "fsum": fsum_of, "d64": d64_of}, overflow

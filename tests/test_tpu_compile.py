@@ -82,6 +82,51 @@ def test_q6_step_compiles_and_fits(one_chip, monkeypatch, float_mode):
         assert mem.temp_size_in_bytes < 2 << 30
 
 
+def _plan_memory(config_name, one_chip, monkeypatch):
+    """The memory the v5e compiler gives a benchmark configuration's IR plan
+    over one partition of the configuration's own size, under its knobs;
+    and the configuration."""
+    from benchmark import lib
+    from spark_rapids_jni_tpu import plan
+
+    cfg, mod = lib.load_config(config_name)
+    inputs = jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: mod.make_partition(
+            cfg, jax.random.PRNGKey(0), mod.rows_per_query(cfg))))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for k, v in cfg["knobs"].items():
+        config.set(k, v)
+    try:
+        cp = plan.compile_plan(mod.plan(cfg), inputs)
+        lowered = cp.fn.lower({n: inputs[n] for n in cp.input_names}, ())
+    finally:
+        config.reset()
+        plan.reset_plan_cache()
+    mem = lowered.compile().memory_analysis()
+    # what a query reads, and the tiles a row count that is no power of
+    # two is padded to
+    assert 0 <= mem.argument_size_in_bytes - mod.query_bytes(cfg) < 1 << 20
+    return mem, cfg
+
+
+@pytest.mark.parametrize("config_name", ["q6-scan-agg", "tpch-q1"])
+def test_benchmark_plans_fit_two_in_flight(one_chip, monkeypatch,
+                                           config_name):
+    """``q6_plan`` under ``f64`` at 2^25 rows and ``tpch_q1_plan`` at
+    59,986,052: under 2 GB of temporaries each, so that two queries in
+    flight (``served-2callers``) beside the resident partitions stay under
+    what the compiler calls the chip's memory.  Of Q1's, 1.79 GiB are the
+    halves the compiler splits the four 64-bit columns into before the
+    first operation; what a 2^19-row slice of the aggregate's loop takes
+    is the rest, and no 128-bit column is ever whole."""
+    mem, cfg = _plan_memory(config_name, one_chip, monkeypatch)
+    assert mem.temp_size_in_bytes < 2 << 30
+    resident = int(cfg["partitions"]) * mem.argument_size_in_bytes
+    in_flight = 2 * (mem.temp_size_in_bytes + mem.output_size_in_bytes)
+    assert resident + in_flight < HBM_BYTES, (resident, in_flight)
+
+
 def _lower_slot_build(s):
     n, S = 1 << 16, 4096
     return PK._slot_build_call.lower(
