@@ -45,6 +45,9 @@ class PlanCache:
         # products the compiler lowered, by route (exact: int64 or typed
         # limbs; rounded: the 256-bit multiply with its long division)
         self.routes = {"mul_exact": 0, "mul_rounded": 0}
+        # the newest plan traced: its joins by output form (a row mask
+        # handed on, or the matches compacted in front)
+        self.joins = {"joins_masked": 0, "joins_compacted": 0}
 
     def note_routes(self, routes) -> None:
         """Count a newly compiled plan's ``route:arithmetic:type``s."""
@@ -53,6 +56,12 @@ class PlanCache:
                 name = r.split(":")[0]
                 if name in self.routes:
                     self.routes[name] += 1
+
+    def note_joins(self, masked: int, compacted: int) -> None:
+        """A plan was traced: how many of its joins took each form."""
+        with self._lock:
+            self.joins = {"joins_masked": int(masked),
+                          "joins_compacted": int(compacted)}
 
     def _capacity(self) -> int:
         if self._maxsize is not None:
@@ -155,6 +164,7 @@ class PlanCache:
                 "capacity": self._capacity(),
                 "pinned": len(self._pins),
                 **self.routes,
+                **self.joins,
                 # int8 slots of the newest one-hot contraction traced
                 "onehot_slots": onehot_slots(),
             }

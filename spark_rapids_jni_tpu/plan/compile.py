@@ -24,7 +24,12 @@ The lowering rules are the hand-fused flagship pipelines, factored:
   (adaptive decision) probes a spill-registered prebuilt
   :class:`~spark_rapids_jni_tpu.relational.join.SpillableBuildTable`,
   pinned to the engine the plan decided so eviction-driven rebuilds
-  cannot disagree with the compiled program's traced shapes.
+  cannot disagree with the compiled program's traced shapes.  An inner
+  dense-domain join whose consumer takes a scattered row mask (an
+  Exchange, an Aggregate, a Filter, a Join it is the left child of; a
+  Project passes the question up) leaves the left rows where they are
+  and hands on ``match`` as the mask (``decisions``: ``"output":
+  "mask"``); at the root or under a Sort it compacts.
 * Aggregate -> ``group_by_onehot`` / ``group_by_domain_or_sort`` /
   general ``group_by`` by exactly the hand paths' dispatch (domain
   hints apply only to plain int keys; string/encoded keys run the
@@ -411,6 +416,10 @@ class _State:
         # groups in key order, nulls first: a Sort on the same keys
         # above one has nothing left to do
         self.key_ordered = set()
+        # joins lowered by output form: a row mask handed on, or the
+        # matches compacted in front
+        self.joins_masked = 0
+        self.joins_compacted = 0
 
 
 def node_scope(node: ir.PlanNode) -> str:
@@ -522,6 +531,14 @@ def _lower_join(node: ir.Join, env, prebuilts, st):
                 left_valid=live, right_valid=rlive,
                 prebuilt=prebuilts[info["prebuilt"]],
                 engine=info["engine"])
+        elif info["output"] == "mask":
+            # the consumer takes a scattered mask: the matches stay where
+            # the left rows are
+            st.joins_masked += 1
+            out, new_live = join_dense_or_hash(
+                b, rb, node.left_on, node.right_on, info["dense_domain"],
+                node.how, left_valid=live, right_valid=rlive, compact=False)
+            return out, new_live, False
         elif info["dense_domain"] is not None:
             out, cnt = join_dense_or_hash(
                 b, rb, node.left_on, node.right_on, info["dense_domain"],
@@ -530,6 +547,7 @@ def _lower_join(node: ir.Join, env, prebuilts, st):
             out, cnt = hash_join(b, rb, [node.left_on], [node.right_on],
                                  node.how, left_valid=live,
                                  right_valid=rlive)
+        st.joins_compacted += 1
         new_live = jnp.arange(out.num_rows, dtype=jnp.int32) < cnt
     return out, new_live, True
 
@@ -701,14 +719,9 @@ def _resolve_join_plans(plan, inputs, decisions, ctx):
                 strategy = "shuffled"
             rb = inputs.get(node.right.name) \
                 if isinstance(node.right, ir.Scan) else None
-            dense = node.dense_domain
-            if dense == "build":
-                dense = rb.num_rows if rb is not None else None
-            if _inputs_encoded(inputs):
-                # the rowid fast path keys on raw .data, which encoded
-                # columns do not expose — the hand encoded q95 lowering
-                dense = None
-            info = {"strategy": strategy, "dense_domain": dense,
+            info = {"strategy": strategy,
+                    "dense_domain": _dense_domain(node, inputs),
+                    "output": d.get("output", "compact"),
                     "prebuilt": None, "engine": None}
             if strategy == "broadcast":
                 if rb is None:
@@ -733,6 +746,49 @@ def _resolve_join_plans(plan, inputs, decisions, ctx):
 
 def _inputs_encoded(inputs: dict) -> bool:
     return any(is_encoded(c) for b in inputs.values() for c in b.columns)
+
+
+def _dense_domain(node: ir.Join, inputs: dict):
+    """The domain ``node``'s rowid-table path may assume, or None."""
+    dense = node.dense_domain
+    if dense == "build":
+        rb = inputs.get(node.right.name) \
+            if isinstance(node.right, ir.Scan) else None
+        dense = rb.num_rows if rb is not None else None
+    if _inputs_encoded(inputs):
+        # the rowid fast path keys on raw .data, which encoded
+        # columns do not expose — the hand encoded q95 lowering
+        dense = None
+    return dense
+
+
+def _join_outputs(plan: ir.PlanNode, inputs: dict, decisions: dict) -> None:
+    """Adds to each join's decision its output form: ``"mask"`` where the
+    join is an inner, shuffled one over a dense domain and what consumes
+    its rows takes a scattered row mask (an Exchange, an Aggregate, a
+    Filter, a Join whose left child it is; a Project hands its own
+    consumer's answer down), so that it need not put the matches in
+    front; ``"compact"`` at the root, under a Sort and everywhere else."""
+    joins = []   # (Join, its consumer takes a mask), in walk order
+
+    def visit(node, masked):
+        for i, c in enumerate(node.children()):
+            if isinstance(node, ir.Project):
+                visit(c, masked)
+            elif isinstance(node, ir.Join):
+                visit(c, i == 0)
+            else:
+                visit(c, isinstance(node, (ir.Exchange, ir.Aggregate,
+                                           ir.Filter)))
+        if isinstance(node, ir.Join):
+            joins.append((node, masked))
+
+    visit(plan, False)
+    for ji, (node, masked) in enumerate(joins):
+        d = decisions[f"join{ji}:{node.left_on}"]
+        d["output"] = "mask" if (
+            masked and node.how == "inner" and d["strategy"] == "shuffled"
+            and _dense_domain(node, inputs) is not None) else "compact"
 
 
 def _default_stats() -> Optional[dict]:
@@ -766,6 +822,7 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
                 stats = _default_stats()
             decisions = adaptive.plan_decisions(plan, inputs, stats)
             decisions.update(_typed_decisions(plan, inputs))
+            _join_outputs(plan, inputs, decisions)
         with profiler.span("plan.key"):
             key = plan_cache_key(plan, inputs, decisions)
         cache = get_plan_cache()
@@ -786,6 +843,8 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
                 _TRACE_COUNT[0] += 1
                 st = _State(join_plans, agg_hints)
                 batch, live, _pfx = _lower(plan, env, prebuilts, st)
+                get_plan_cache().note_joins(st.joins_masked,
+                                            st.joins_compacted)
                 # from an Aggregate up ``live`` is the group count
                 return batch if live is None else (batch, live)
 

@@ -451,6 +451,7 @@ def join_dense_or_hash(
     suffixes: tuple = ("", "_r"),
     left_valid=None,
     right_valid=None,
+    compact: bool = True,
 ) -> tuple:
     """Adaptive inner join for the dimension-table shape: when the build
     side's keys are UNIQUE ints in ``[0, domain)`` (dense surrogate keys
@@ -466,7 +467,19 @@ def join_dense_or_hash(
     delegates to :func:`hash_join` outright.  Measured r5 on the q95
     shape (64K fact x 8K dim, 1-core XLA-CPU): the general engine's
     per-join cost is dominated by the build sort that this path skips.
+
+    ``compact=False`` (only without a ``capacity``, so the output has the
+    left side's rows) returns ``(result, live)`` with ``live`` a
+    ``bool[left.num_rows]`` row mask, for a consumer that takes one: the
+    dense branch is then a lookup that leaves the left rows where they
+    are (no sort, no gather of a left column, its validity untouched: a
+    dead row is dead by ``live`` alone) and ``live`` is the scattered
+    ``match``; the general branch's rows are compacted by construction,
+    its ``live`` a prefix.  The live rows are the same multiset either way.
     """
+    if not compact and capacity is not None:
+        raise ValueError("compact=False keeps the left side's rows: it "
+                         "takes no capacity")
     lcol, rcol = left[left_on], right[right_on]
     eligible = (how == "inner" and domain > 0
                 and not isinstance(lcol, (StringColumn, Decimal128Column,
@@ -481,9 +494,11 @@ def join_dense_or_hash(
                 and jnp.issubdtype(rcol.data.dtype, jnp.integer)
                 and right.num_rows > 0)
     if not eligible:
-        return hash_join(left, right, [left_on], [right_on], how,
-                         capacity=capacity, suffixes=suffixes,
-                         left_valid=left_valid, right_valid=right_valid)
+        out, total = hash_join(left, right, [left_on], [right_on], how,
+                               capacity=capacity, suffixes=suffixes,
+                               left_valid=left_valid,
+                               right_valid=right_valid)
+        return out, (total if compact else _prefix_live(out, total))
 
     nl, nr = left.num_rows, right.num_rows
     K1 = int(domain)
@@ -510,6 +525,8 @@ def join_dense_or_hash(
         dense_ok = (jnp.all(in_dom | ~r_live) & jnp.all(cnt[:K1] <= 1)
                     & no_wrap)
 
+    right_sel = right.select([n for n in right.names if n != right_on])
+
     def dense(_):
         with scope("join.dense_build"):
             rowid = jnp.zeros((K1 + 1,), jnp.int32).at[slot].set(
@@ -523,6 +540,14 @@ def join_dense_or_hash(
             lk_safe = jnp.where(lk_ok, lk, 0)
             match = lk_ok & present[lk_safe]
             total = jnp.sum(match, dtype=jnp.int32)
+        if not compact:
+            with scope("join.dense_rowid"):
+                ri = rowid[lk_safe]
+            with scope("join.gather_right"):
+                rpart = gather_batch(right_sel, ri, match)
+            # the left columns are taken after the cond: zeros stand in
+            lpart = jax.tree_util.tree_map(jnp.zeros_like, left)
+            return _merge_parts(lpart, rpart, suffixes), match
         from ..parallel.partition import regroup_order
 
         with scope("join.dense_compact"):
@@ -535,21 +560,37 @@ def join_dense_or_hash(
             ri = rowid[jnp.clip(jnp.take(lk_safe, li), 0, K1)]
         with scope("join.gather_left"):
             lpart = gather_batch(left, li, out_valid)
-        right_names = [n for n in right.names if n != right_on]
         with scope("join.gather_right"):
-            rpart = gather_batch(
-                right.select(right_names) if right_names
-                else ColumnBatch({}), ri, out_valid)
+            rpart = gather_batch(right_sel, ri, out_valid)
         return _merge_parts(lpart, rpart, suffixes), total
 
     def general(_):
         with scope("join.general"):
-            return hash_join(left, right, [left_on], [right_on], "inner",
-                             capacity=cap, suffixes=suffixes,
-                             left_valid=left_valid,
-                             right_valid=right_valid)
+            out, total = hash_join(left, right, [left_on], [right_on],
+                                   "inner", capacity=cap, suffixes=suffixes,
+                                   left_valid=left_valid,
+                                   right_valid=right_valid)
+            return out, (total if compact else _prefix_live(out, total))
 
-    return jax.lax.cond(dense_ok, dense, general, None)
+    out, live = jax.lax.cond(dense_ok, dense, general, None)
+    if compact:
+        return out, live
+    # The dense branch's left columns are the caller's, selected here and
+    # not handed out of the cond: what a cond hands out the compiler keeps
+    # in HBM, and an exchange gathers every column out of that at half the
+    # speed it reads a fusion's output at (PERF.md section 5, PR 32).
+    collide = set(left.names) & set(right_sel.names)   # as _merge_parts
+    cols = dict(zip(out.names, out.columns))
+    for name, col in zip(left.names, left.columns):
+        to = name + suffixes[0] if name in collide else name
+        cols[to] = jax.tree_util.tree_map(
+            lambda a, b: jnp.where(dense_ok, a, b), col, cols[to])
+    return ColumnBatch(cols), live
+
+
+def _prefix_live(out: ColumnBatch, total):
+    """The row mask of a compacted join output: its first ``total`` rows."""
+    return jnp.arange(out.num_rows, dtype=jnp.int32) < total
 
 
 def _merge_parts(lpart: ColumnBatch, rpart: ColumnBatch,

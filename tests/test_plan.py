@@ -413,3 +413,235 @@ class TestBuildTablePinning:
             cp.close()
         finally:
             spill_mod.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a dense inner join hands on a row mask where its consumer takes one
+# ---------------------------------------------------------------------------
+
+_ND, _NW, _NSEG = 96, 25, 10
+
+
+def _holey_star(n=512, seed=5):
+    """A q95-shaped star whose joins leave dead rows behind: a third of the
+    fact's ``k`` lies past ``dim1``, three of its 28 ``wh`` values past
+    ``dim2``, and a few keys are null."""
+    import jax.numpy as jnp
+
+    from spark_rapids_jni_tpu.columnar import types as T
+    from spark_rapids_jni_tpu.columnar.column import Column, ColumnBatch
+
+    rng = np.random.default_rng(seed)
+
+    def col(a, t, valid=None):
+        v = np.ones(len(a), bool) if valid is None else valid
+        return Column(jnp.asarray(a), jnp.asarray(v), t)
+
+    fact = ColumnBatch({
+        "k": col(rng.integers(0, _ND * 3 // 2, n).astype(np.int32), T.INT32,
+                 np.arange(n) % 17 != 3),
+        "wh": col(rng.integers(0, _NW + 3, n).astype(np.int32), T.INT32,
+                  np.arange(n) % 23 != 5),
+        "seg": col(rng.integers(0, _NSEG, n).astype(np.int32), T.INT32),
+        "v": col(rng.integers(1, 500, n), T.INT64)})
+    dim1 = ColumnBatch({
+        "k": col(np.arange(_ND, dtype=np.int32), T.INT32),
+        "d1": col(rng.integers(0, 9, _ND), T.INT64)})
+    dim2 = ColumnBatch({
+        "wh": col(np.arange(_NW, dtype=np.int32), T.INT32),
+        "d2": col(rng.integers(0, 9, _NW), T.INT64)})
+    # half of dim1's keys, for a join on the build side of another
+    dimx = ColumnBatch({
+        "k": col(np.arange(_ND // 2, dtype=np.int32), T.INT32),
+        "dx": col(rng.integers(0, 9, _ND // 2), T.INT64)})
+    return {"fact": fact, "dim1": dim1, "dim2": dim2, "dimx": dimx}
+
+
+def _np_fact(inputs):
+    f = inputs["fact"]
+    c = {n: np.asarray(f[n].data) for n in f.names}
+    in1 = np.asarray(f["k"].validity) & (c["k"] < _ND)
+    in2 = np.asarray(f["wh"].validity) & (c["wh"] < _NW)
+    return c, in1, in2
+
+
+def _np_groups(inputs, keep):
+    """seg -> (count, sum(v)) over the fact rows under ``keep``."""
+    c, _, _ = _np_fact(inputs)
+    return {int(s): (int(np.count_nonzero(keep & (c["seg"] == s))),
+                     int(c["v"][keep & (c["seg"] == s)].sum()))
+            for s in np.unique(c["seg"][keep])}
+
+
+def _got_groups(res, ng):
+    ng = int(ng)
+    return {int(s): (int(o), int(v)) for s, o, v in zip(
+        np.asarray(res["seg"].data)[:ng], np.asarray(res["orders"].data)[:ng],
+        np.asarray(res["net"].data)[:ng])}
+
+
+def _masked_plan(name):
+    """``(plan, each join's output form in walk order, which fact rows the
+    numpy reference keeps)`` for one consumer of a dense inner join."""
+    from spark_rapids_jni_tpu.plan.ir import (Agg, Aggregate, Exchange, Filter,
+                                              Join, Project, Scan, Sort)
+
+    def j1(child):
+        return Join(child, Scan("dim1"), "k", "k", dense_domain="build")
+
+    def j2(child):
+        return Join(child, Scan("dim2"), "wh", "wh", dense_domain="build")
+
+    def agg(child, **kw):
+        return Aggregate(child, keys=("seg",),
+                         aggs=(Agg("count", None, "orders"),
+                               Agg("sum", "v", "net")), **kw)
+
+    fact = Scan("fact")
+    both = lambda c, in1, in2: in1 & in2   # noqa: E731
+    if name == "exchanges_and_fused_aggregate":     # q95_plan itself
+        return (agg(Exchange(j2(Exchange(j1(Exchange(fact, "k")), "wh")),
+                             "seg"), domain=_NSEG),
+                ["mask", "mask"], both)
+    if name == "exchange_not_fused":    # regroups on another key
+        return (agg(Exchange(j2(j1(fact)), "wh")), ["mask", "mask"], both)
+    first = lambda c, in1, in2: in1        # noqa: E731
+    if name == "aggregate_general":
+        return agg(j1(fact)), ["mask"], first
+    if name == "aggregate_domain":
+        return agg(j1(fact), domain=_NSEG), ["mask"], first
+    if name == "aggregate_onehot":
+        return agg(j1(fact), domain=_NSEG, onehot=True), ["mask"], first
+    if name == "filter":
+        return (agg(Filter(j2(Filter(j1(fact), "d1", "<", 7)), "v", ">=",
+                           250), domain=_NSEG),
+                ["mask", "mask"], None)
+    if name == "project_hands_the_question_down":
+        return (agg(Project(j2(Exchange(Project(
+            j1(fact), ("wh", "seg", "v")), "wh")), ("seg", "v"))),
+            ["mask", "mask"], both)
+    if name == "second_join":           # the left child of a Join
+        return agg(j2(j1(fact)), domain=_NSEG), ["mask", "mask"], both
+    if name == "root_join":
+        return j1(Exchange(fact, "k")), ["compact"], None
+    if name == "sort_over_join":
+        return Sort(j1(fact), ("v", "seg")), ["compact"], None
+    if name == "project_under_the_root":
+        return Project(j1(fact), ("k", "v", "d1")), ["compact"], None
+    if name == "build_side_join":       # a Join's right child compacts
+        right = Join(Scan("dim1"), Scan("dimx"), "k", "k",
+                     dense_domain="build")
+        return (agg(Join(fact, right, "k", "k", dense_domain=_ND)),
+                ["compact", "mask"], lambda c, in1, in2: in1 & (
+                    c["k"] < _ND // 2))
+    raise KeyError(name)
+
+
+class TestMaskedJoins:
+    """An inner join over a dense domain leaves its left rows where they
+    are and hands on ``match`` as the row mask when what consumes it takes
+    a scattered mask; the answers are the numpy reference's over data on
+    which a third of the rows do NOT match."""
+
+    AGGREGATED = ["exchanges_and_fused_aggregate", "exchange_not_fused",
+                  "aggregate_general", "aggregate_domain",
+                  "aggregate_onehot", "project_hands_the_question_down",
+                  "second_join", "build_side_join"]
+
+    def _run(self, name, inputs):
+        the_plan, forms, keep = _masked_plan(name)
+        inputs = {n: inputs[n] for n in plan.ir.scan_names(the_plan)}
+        cp = plan.compile_plan(the_plan, inputs)
+        out = cp(inputs)
+        got = [d["output"] for k, d in sorted(cp.decisions.items())
+               if k.startswith("join")]
+        assert got == forms, cp.decisions
+        m = plan.plan_cache_metrics()
+        assert (m["joins_masked"], m["joins_compacted"]) == (
+            forms.count("mask"), forms.count("compact"))
+        return out, keep
+
+    @pytest.mark.parametrize("join_engine,groupby_engine", [
+        ("sort", "sort"), ("hash", "scatter")])
+    @pytest.mark.parametrize("name", AGGREGATED)
+    def test_aggregates_equal_numpy(self, knob, name, join_engine,
+                                    groupby_engine):
+        knob("join_engine", join_engine)
+        knob("groupby_engine", groupby_engine)
+        inputs = _holey_star()
+        (res, ng), keep = self._run(name, inputs)
+        want = _np_groups(inputs, keep(*_np_fact(inputs)))
+        assert len(want) == _NSEG and _got_groups(res, ng) == want
+
+    def test_filters_over_masked_joins_equal_numpy(self):
+        inputs = _holey_star()
+        (res, ng), _ = self._run("filter", inputs)
+        c, in1, in2 = _np_fact(inputs)
+        d1 = np.asarray(inputs["dim1"]["d1"].data)
+        keep = in1 & in2 & (c["v"] >= 250)
+        keep &= d1[np.where(in1, c["k"], 0)] < 7
+        assert _got_groups(res, ng) == _np_groups(inputs, keep)
+
+    def test_the_chips_engine_choice_traced(self, monkeypatch):
+        """``auto`` asks ``jax.default_backend()``: answered as the chip
+        does, the q95 shape takes the sort engines and the fused regroup,
+        the form the benchmark's cell runs."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        inputs = _holey_star()
+        (res, ng), keep = self._run("exchanges_and_fused_aggregate", inputs)
+        assert _got_groups(res, ng) == _np_groups(
+            inputs, keep(*_np_fact(inputs)))
+
+    @staticmethod
+    def _rows(batch, live):
+        cols = [batch[n].to_pylist() for n in batch.names]
+        return sorted((tuple(c[i] for c in cols)
+                       for i in np.flatnonzero(np.asarray(live))), key=repr)
+
+    @pytest.mark.parametrize("name", ["root_join", "sort_over_join",
+                                      "project_under_the_root"])
+    def test_a_join_nobody_takes_a_mask_from_compacts(self, name):
+        inputs = _holey_star()
+        (out, live), _ = self._run(name, inputs)
+        c, in1, _ = _np_fact(inputs)
+        live = np.asarray(live)
+        n_live = int(np.count_nonzero(in1))
+        # the matches are in front
+        assert np.array_equal(live, np.arange(live.size) < n_live)
+        d1 = np.asarray(inputs["dim1"]["d1"].data)
+        rows = np.flatnonzero(in1)
+        whv = np.asarray(inputs["fact"]["wh"].validity)
+        if name == "project_under_the_root":
+            want = [(int(c["k"][i]), int(c["v"][i]), int(d1[c["k"][i]]))
+                    for i in rows]
+        else:
+            want = [(int(c["k"][i]), int(c["wh"][i]) if whv[i] else None,
+                     int(c["seg"][i]), int(c["v"][i]), int(d1[c["k"][i]]))
+                    for i in rows]
+        assert self._rows(out, live) == sorted(want, key=repr)
+        if name == "sort_over_join":
+            vs = np.asarray(out["v"].data)[:n_live]
+            assert np.all(vs[:-1] <= vs[1:])
+
+    def test_a_broadcast_join_compacts(self):
+        inputs = _holey_star()
+        inputs = {n: inputs[n] for n in ("fact", "dim1", "dim2")}
+        cp = plan.compile_plan(queries.q9_plan(), inputs)
+        try:
+            res, ng = cp(inputs)
+            forms = [cp.decisions[k]["output"]
+                     for k in ("join0:k", "join1:wh")]
+            assert forms == ["compact", "compact"]
+            m = plan.plan_cache_metrics()
+            assert (m["joins_masked"], m["joins_compacted"]) == (0, 2)
+            c, in1, in2 = _np_fact(inputs)
+            keep = in1 & in2 & (c["v"] >= queries.Q9_V_THRESHOLD)
+            want = _np_groups(inputs, keep)
+            ng = int(ng)
+            got = {int(s): (int(o), int(v)) for s, o, v in zip(
+                np.asarray(res["seg"].data)[:ng],
+                np.asarray(res["orders_hi"].data)[:ng],
+                np.asarray(res["net_hi"].data)[:ng])}
+            assert got == want
+        finally:
+            cp.close()

@@ -135,8 +135,10 @@ PARENT_SIGNATURES = {
 }
 PARENT_DECISIONS = {
     "q6_plan": {'adaptive': True},
-    "q95_plan": {'adaptive': True, 'join0:k': {'strategy': 'shuffled', 'build_rows': 128}, 'join1:wh': {'strategy': 'shuffled', 'build_rows': 25}},
-    "q9_plan": {'adaptive': True, 'join0:k': {'strategy': 'broadcast', 'build_rows': 128, 'engine': 'hash'}, 'join1:wh': {'strategy': 'broadcast', 'build_rows': 25, 'engine': 'hash'}},
+    # a join's decision says its output form too (PR 32): q95's joins each
+    # feed an exchange and hand on a row mask, a broadcast join compacts
+    "q95_plan": {'adaptive': True, 'join0:k': {'strategy': 'shuffled', 'build_rows': 128, 'output': 'mask'}, 'join1:wh': {'strategy': 'shuffled', 'build_rows': 25, 'output': 'mask'}},
+    "q9_plan": {'adaptive': True, 'join0:k': {'strategy': 'broadcast', 'build_rows': 128, 'engine': 'hash', 'output': 'compact'}, 'join1:wh': {'strategy': 'broadcast', 'build_rows': 25, 'engine': 'hash', 'output': 'compact'}},
 }
 
 

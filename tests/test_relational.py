@@ -1542,3 +1542,175 @@ class TestJoinDenseOrHash:
         m = int(wn)
         for name in want.names:
             assert got[name].to_pylist()[:m] == want[name].to_pylist()[:m]
+
+
+def _masked_case(name):
+    """(left keys, right keys, domain, left_valid, right_valid, dense) of
+    one case of a join that hands on a row mask."""
+    t, f = True, False
+    return {
+        # the dense branch: unique build keys inside the domain
+        "dense_all_match": ([3, 0, 7, 3, 1], list(range(8)), 8, None, None,
+                            True),
+        "dense_out_of_domain_and_null":
+            ([3, 0, 9, None, -1, 7, 3, 64, None, 1], list(range(8)), 8,
+             None, None, True),
+        "dense_partial_coverage": ([0, 1, 2, 3, 4, 5], [0, 2, 4], 6, None,
+                                   None, True),
+        "dense_left_valid_holes": ([0, 1, 2, 3, 2, 1], [0, 1, 2, 3], 4,
+                                   [t, f, t, t, f, t], None, True),
+        "dense_right_valid_holes": ([0, 1, 2, 3, 2, 1], [0, 1, 2, 3], 4,
+                                    None, [t, t, f, t], True),
+        "dense_both_valid_holes": ([0, 1, None, 3, 2, 1], [0, 1, 2, 3], 4,
+                                   [f, t, t, t, t, f], [f, t, t, t], True),
+        "dense_null_build_key": ([0, 1, 2, 1], [0, None, 2], 4, None, None,
+                                 True),
+        "dense_no_match": ([5, 6, None], [0, 1, 2], 8, None, None, True),
+        "dense_one_left_row": ([2], [0, 1, 2], 4, None, None, True),
+        "dense_one_left_row_dead": ([2], [0, 1, 2], 4, [f], None, True),
+        "dense_no_left_rows": ([], [0, 1, 2], 4, None, None, True),
+        # the general branch: the check refuses the rowid table
+        "general_duplicate_build_key": ([1, 2, 9, 3], [1, 1, 2], 4, None,
+                                        None, False),
+        # more matches than left rows: both forms keep the first nl
+        "general_duplicates_overflow": ([1, 2, 1, 3], [1, 1, 2], 4, None,
+                                        None, False),
+        "general_build_key_past_domain": ([1, 2, 50, None], [1, 2, 50], 4,
+                                          None, None, False),
+        "general_left_valid_holes": ([1, 2, 1, 2], [2, 2, 1], 4,
+                                     [t, f, f, t], None, False),
+        "general_one_left_row": ([50], [1, 2, 50], 4, None, None, False),
+        "general_no_left_rows": ([], [1, 1], 4, None, None, False),
+    }[name]
+
+
+_MASKED_CASES = [
+    "dense_all_match", "dense_out_of_domain_and_null",
+    "dense_partial_coverage", "dense_left_valid_holes",
+    "dense_right_valid_holes", "dense_both_valid_holes",
+    "dense_null_build_key", "dense_no_match", "dense_one_left_row",
+    "dense_one_left_row_dead", "dense_no_left_rows",
+    "general_duplicate_build_key", "general_duplicates_overflow",
+    "general_build_key_past_domain",
+    "general_left_valid_holes", "general_one_left_row",
+    "general_no_left_rows"]
+
+
+def _live_rows(batch, live):
+    """The rows of ``batch`` under ``live`` as a sorted multiset."""
+    cols = [batch[n].to_pylist() for n in batch.names]
+    keep = np.asarray(live)
+    return sorted((tuple(c[i] for c in cols) for i in range(len(keep))
+                   if keep[i]), key=repr)
+
+
+class TestJoinDenseMasked:
+    """``join_dense_or_hash(compact=False)``: the same live rows as the
+    compacting join, through both branches of the ``cond``; the dense one
+    leaves the left rows where they are."""
+
+    @staticmethod
+    def _inputs(name):
+        lk, rk, domain, lv, rv, dense = _masked_case(name)
+        left = ColumnBatch({
+            "k": Column.from_pylist(lk, T.INT32),
+            "lv": Column.from_pylist(
+                [None if i % 5 == 4 else i * 10 for i in range(len(lk))],
+                T.INT64)})
+        right = ColumnBatch({
+            "k": Column.from_pylist(rk, T.INT32),
+            "rv": Column.from_pylist(
+                [None if i % 3 == 2 else i * 100 for i in range(len(rk))],
+                T.INT64)})
+        kw = {}
+        if lv is not None:
+            kw["left_valid"] = jnp.asarray(lv, jnp.bool_)
+        if rv is not None:
+            kw["right_valid"] = jnp.asarray(rv, jnp.bool_)
+        return left, right, domain, kw, dense
+
+    @pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+    @pytest.mark.parametrize("name", _MASKED_CASES)
+    def test_same_live_rows_as_the_compacting_join(self, name, jit):
+        import jax
+
+        from spark_rapids_jni_tpu.relational import join_dense_or_hash
+
+        left, right, domain, kw, dense = self._inputs(name)
+
+        def run(compact):
+            def f(left, right, kw):
+                return join_dense_or_hash(left, right, "k", "k", domain,
+                                          compact=compact, **kw)
+            return (jax.jit(f) if jit else f)(left, right, kw)
+
+        want, total = run(True)
+        got, live = run(False)
+        nl = left.num_rows
+        assert live.dtype == jnp.bool_ and live.shape == (nl,)
+        assert got.names == want.names and got.num_rows == nl
+        assert want.num_rows == nl
+        assert int(np.asarray(live).sum()) == min(int(total), nl)
+        assert _live_rows(got, live) == _live_rows(
+            want, np.arange(nl) < int(total))
+        if dense:
+            # a lookup: every left column is the caller's, bit for bit,
+            # dead rows and their validity included
+            for n in left.names:
+                assert np.array_equal(np.asarray(got[n].data),
+                                      np.asarray(left[n].data)), n
+                assert np.array_equal(np.asarray(got[n].validity),
+                                      np.asarray(left[n].validity)), n
+        else:
+            # hash_join's rows are compacted by construction
+            assert np.array_equal(np.asarray(live),
+                                  np.arange(nl) < int(total))
+
+    def test_colliding_names_take_their_suffixes(self):
+        from spark_rapids_jni_tpu.relational import join_dense_or_hash
+
+        left = ColumnBatch({"k": ints([2, 9, 0]), "v": ints([1, 2, 3])})
+        right = ColumnBatch({"k": ints([0, 1, 2]), "v": ints([7, 8, 9])})
+        got, live = join_dense_or_hash(left, right, "k", "k", 4,
+                                       suffixes=("_l", "_r"), compact=False)
+        assert list(got.names) == ["k", "v_l", "v_r"]
+        assert np.asarray(live).tolist() == [True, False, True]
+        assert got["v_l"].to_pylist() == [1, 2, 3]
+        assert got["v_r"].to_pylist() == [9, None, 7]
+
+    def test_a_capacity_is_refused(self):
+        from spark_rapids_jni_tpu.relational import join_dense_or_hash
+
+        left, right, domain, kw, _ = self._inputs("dense_all_match")
+        with pytest.raises(ValueError, match="capacity"):
+            join_dense_or_hash(left, right, "k", "k", domain, capacity=4,
+                               compact=False, **kw)
+
+    @pytest.mark.parametrize("how", ["left", "semi"])
+    def test_a_join_that_is_not_inner_hands_back_a_prefix(self, how):
+        from spark_rapids_jni_tpu.relational import join_dense_or_hash
+
+        left, right, domain, kw, _ = self._inputs(
+            "dense_out_of_domain_and_null")
+        want, total = join_dense_or_hash(left, right, "k", "k", domain,
+                                         how=how, **kw)
+        got, live = join_dense_or_hash(left, right, "k", "k", domain,
+                                       how=how, compact=False, **kw)
+        assert np.array_equal(np.asarray(live),
+                              np.arange(got.num_rows) < int(total))
+        assert _live_rows(got, live) == _live_rows(
+            want, np.arange(want.num_rows) < int(total))
+
+    def test_compacting_form_is_the_default_and_unchanged(self):
+        """``compact=True`` is what every direct caller gets: matches in
+        front in left-row order, a count, the rows past it null."""
+        from spark_rapids_jni_tpu.relational import join_dense_or_hash
+
+        left, right, domain, kw, _ = self._inputs(
+            "dense_out_of_domain_and_null")
+        a, na = join_dense_or_hash(left, right, "k", "k", domain, **kw)
+        b, nb = join_dense_or_hash(left, right, "k", "k", domain,
+                                   compact=True, **kw)
+        assert int(na) == int(nb) == 5
+        assert a.to_pydict() == b.to_pydict()
+        assert a["k"].to_pylist() == [3, 0, 7, 3, 1] + [None] * 5

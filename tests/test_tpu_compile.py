@@ -82,18 +82,25 @@ def test_q6_step_compiles_and_fits(one_chip, monkeypatch, float_mode):
         assert mem.temp_size_in_bytes < 2 << 30
 
 
-def _plan_memory(config_name, one_chip, monkeypatch):
-    """The memory the v5e compiler gives a benchmark configuration's IR plan
-    over one partition of the configuration's own size, under its knobs;
-    and the configuration."""
+def _lower_plan(config_name, one_chip, monkeypatch):
+    """A benchmark configuration's IR plan over one partition of the
+    configuration's own size (and the tables its partitions share), under
+    its knobs, lowered for the v5e; the plan's decisions; the configuration
+    and its module."""
     from benchmark import lib
     from spark_rapids_jni_tpu import plan
 
     cfg, mod = lib.load_config(config_name)
+    rows = mod.rows_per_query(cfg)
+
+    def tables():
+        key = jax.random.PRNGKey(0)
+        shared = getattr(mod, "make_shared", None)
+        return {**mod.make_partition(cfg, key, rows),
+                **(shared(cfg, key, rows) if shared else {})}
+
     inputs = jax.tree_util.tree_map(
-        lambda s: _sds(s.shape, s.dtype, one_chip),
-        jax.eval_shape(lambda: mod.make_partition(
-            cfg, jax.random.PRNGKey(0), mod.rows_per_query(cfg))))
+        lambda s: _sds(s.shape, s.dtype, one_chip), jax.eval_shape(tables))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for k, v in cfg["knobs"].items():
         config.set(k, v)
@@ -103,6 +110,14 @@ def _plan_memory(config_name, one_chip, monkeypatch):
     finally:
         config.reset()
         plan.reset_plan_cache()
+    return lowered, cp.decisions, cfg, mod
+
+
+def _plan_memory(config_name, one_chip, monkeypatch):
+    """The memory the v5e compiler gives that program; and the
+    configuration."""
+    lowered, _decisions, cfg, mod = _lower_plan(config_name, one_chip,
+                                                monkeypatch)
     mem = lowered.compile().memory_analysis()
     # what a query reads, and the tiles a row count that is no power of
     # two is padded to
@@ -125,6 +140,46 @@ def test_benchmark_plans_fit_two_in_flight(one_chip, monkeypatch,
     resident = int(cfg["partitions"]) * mem.argument_size_in_bytes
     in_flight = 2 * (mem.temp_size_in_bytes + mem.output_size_in_bytes)
     assert resident + in_flight < HBM_BYTES, (resident, in_flight)
+
+
+def _gathers(text):
+    """``(scope path, rows of the operand read)`` of every gather in a
+    lowered program's text (``as_text(debug_info=True)``)."""
+    import re
+
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    return [(locs.get(m.group(2), ""), int(m.group(1)))
+            for m in re.finditer(
+                r'"stablehlo\.gather"\(.*\(tensor<(\d+)[x>].*loc\((#loc\d+)\)',
+                text)]
+
+
+def test_q95_plan_compiles_with_its_dense_joins_as_lookups(one_chip,
+                                                           monkeypatch):
+    """``q95_plan`` at the cell's size (2^22 fact rows, ``dim1`` 2^19,
+    ``dim2`` 25) with the chip's engines: both joins hand on a row mask, so
+    under ``plan.join.*`` only the general branch (the engine that expands
+    rows) gathers a column of the fact; the dense branch reads the small
+    tables alone.  And the chip's compiler takes the program."""
+    lowered, decisions, cfg, mod = _lower_plan("q95-join-agg", one_chip,
+                                               monkeypatch)
+    rows = mod.rows_per_query(cfg)
+    assert rows == 1 << 22
+    assert [decisions[k]["output"] for k in ("join0:k", "join1:wh")] \
+        == ["mask", "mask"]
+    in_joins = [(path, n) for path, n in _gathers(
+        lowered.as_text(debug_info=True)) if "/plan.join." in path]
+    # the parser sees the fact's columns gathered where they still are ...
+    assert [1 for path, n in in_joins
+            if "/join.general/join.gather_left/" in path and n == rows]
+    # ... the dense branch's probe of the small table ...
+    assert [1 for path, n in in_joins
+            if path.endswith("/join.dense_probe/gather") and n < rows]
+    # ... and nothing of the fact's size outside the general branch
+    assert not [(path, n) for path, n in in_joins
+                if "/join.general/" not in path and n >= rows]
+    mem = lowered.compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30
 
 
 def _lower_slot_build(s):

@@ -222,9 +222,13 @@ class TestScopes:
         config.set("join_engine", "sort")       # what auto is on the chip
         config.set("groupby_engine", "sort")
         try:
-            got = _lowered_scopes(queries.q95_plan(), _q95_inputs())
+            inputs = _q95_inputs()
+            cp = plan.compile_plan(queries.q95_plan(), inputs)
+            text = cp.fn.lower(inputs, ()).as_text(debug_info=True)
         finally:
             config.reset()
+        got = set(re.findall(r"[A-Za-z0-9_]+(?:\.[A-Za-z0-9_]+)+(?=[/\"])",
+                             text))
         want = {
             # every node of the plan (the last Exchange is fused into the
             # aggregate and lowers under its scope)
@@ -232,17 +236,26 @@ class TestScopes:
             "plan.join.dim2", "plan.aggregate.seg",
             "exchange.partition_id", "exchange.regroup",
             "exchange.scatter",
-            # the dense branch: rowid table, probe, compaction, gather
+            # the dense branch, a lookup: rowid table and probe (nobody
+            # reads d1 or d2, so join.dense_rowid and join.gather_right
+            # are dropped before the program is lowered)
             "join.dense_check", "join.dense_build", "join.dense_probe",
-            "join.dense_compact", "join.gather_left",
-            # the general branch: bisection and expansion
+            # the general branch: bisection, expansion, both sides gathered
             "join.general", "join.probe_keys", "join.build_sort",
             "join.bisect", "keys.bisect_gather", "keys.bisect_compare",
-            "join.expand",
+            "join.expand", "join.gather_left",
             # the sort-scan aggregation over the regrouped rows
             "agg.sortscan_keys", "agg.sortscan_boundary",
             "agg.sortscan_reduce"}
         assert want <= got, sorted(want - got)
+        # both joins feed an exchange, which takes a scattered mask: no
+        # join puts its matches in front, and a left column is gathered
+        # only where the general engine expands rows
+        assert "join.dense_compact" not in got
+        paths = set(re.findall(r'"([^"]*join\.gather_left[^"]*)"', text))
+        assert paths and all("/join.general/" in p for p in paths), paths
+        assert [cp.decisions[k]["output"] for k in ("join0:k", "join1:wh")] \
+            == ["mask", "mask"]
 
     def test_node_scopes_hold_letters_digits_underscore_dot_only(self):
         from spark_rapids_jni_tpu.plan import compile as pc
