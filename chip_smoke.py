@@ -67,8 +67,8 @@ ANSWER_TIMEOUT_S = 600.0  # longest wait for one answer
 # for the CPU tests; 2000 lost the worker in 2 chip runs of 6, when its
 # backend (about 10 s to start) still started after its hello.
 HEARTBEAT_MS = 10000.0
-# what every phase runs at: log2 of the q6 batch rows, of the q95 fact rows
-# (bench_rows_tpu), and of the rows per device with --chips 4
+# what every phase runs at: log2 of the q6 batch rows, of the q95 fact rows,
+# and of the rows per device with --chips 4
 REAL_ROWS = {"q6": 24, "q95": 24, "shard": 22, "tpch_q1": 22}
 
 

@@ -48,7 +48,7 @@ cd "$(dirname "$0")/.."
 CHAOS_SEED="${CHAOS_SEED:-0}"
 
 echo "== chaos campaign (seed=${CHAOS_SEED}) =="
-BENCH_FORCE_CPU=1 python -m tools.chaos --seed "${CHAOS_SEED}" \
+SRJ_FORCE_CPU=1 python -m tools.chaos --seed "${CHAOS_SEED}" \
     --report /tmp/chaos_report.json
 
 # the full matrix must cover the distributed-sort, streaming-scan,

@@ -148,23 +148,13 @@ _register("chaos_trials", 4, int,
           "(tools/chaos.py) on top of the exhaustive one-fault-per-trial "
           "sweep; each trial samples 2-3 recoverable fault rules with "
           "deterministic skip/count offsets from the campaign seed.")
-# (the legacy `bench_rows` knob was dropped: nothing read it after the
-# bench went per-platform — graftlint GL005 now fails on dead knobs)
-_register("bench_rows_tpu", 1 << 24, int,
-          "Full-size row count for the q6 bench on an accelerator: the "
-          "size of one batch an executor holds on the chip (2^24 rows of "
-          "q6 are 0.36 GB of columns).")
-_register("bench_rows_cpu", 1 << 20, int,
-          "Full-size row count for the q6 bench on the CPU "
-          "(BENCH_FORCE_CPU=1): the scatter engine runs 1M rows in "
-          "~35ms, so the refine step fits the budget comfortably.")
 _register("q6_group_path", "onehot", str,
-          "Aggregation path for the q6 flagship bench: 'onehot' "
-          "(group_by_onehot over the bench's static key domain, engine "
-          "picked by q6_onehot_engine) or 'sort' (the general "
-          "engine-selectable group_by — despite the legacy value name it "
-          "honors the groupby_engine knob, so on CPU it runs the "
-          "slot-table scatter engine, not a hard-wired sort).")
+          "Aggregation path of a domain-key Aggregate (q6_plan, "
+          "_q6_step): 'onehot' (group_by_onehot over the key's static "
+          "domain, engine picked by q6_onehot_engine) or 'sort' (the "
+          "general engine-selectable group_by — despite the legacy value "
+          "name it honors the groupby_engine knob, so on CPU it runs "
+          "the slot-table scatter engine, not a hard-wired sort).")
 _register("q6_onehot_engine", "auto", str,
           "Engine for the q6 domain-key aggregation: 'auto' (scatter on "
           "CPU, xla on accelerators — measured both ways round 4), 'xla' "
@@ -425,7 +415,7 @@ _register("serve_placement", "load", str,
           "(placed sessions + pong queue depth), arena pressure, and "
           "stall suspicion, and spreads new incarnations across hosts "
           "fewest-live-slots-first; 'round_robin' keeps the legacy "
-          "rotation — the comparison arm for bench.py --elastic.")
+          "rotation.")
 _register("serve_autoscale", False, _parse_bool,
           "Queue-driven autoscaling of the worker fleet (serve/"
           "elastic.py): admission-queue depth above the high-water mark "

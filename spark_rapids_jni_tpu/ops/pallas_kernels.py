@@ -36,10 +36,8 @@ xfails in ``tests/test_tpu_compile.py``):
   for KernelType.TC: cumsum``.
 
 So ``"pallas"`` for any of those three knobs raises on a chip.  They stay
-off the ``auto`` path; the bench rows ``slot_build_pallas`` /
-``slot_probe_pallas`` / ``partition_scatter_pallas`` and
-``bench.py --multidevice`` run them on the CPU in interpret mode for
-parity only (PALLAS_MEMO.md r14 ledger).
+off the ``auto`` path; ``tests/test_pallas_kernels.py`` runs them on the
+CPU in interpret mode for parity only (PALLAS_MEMO.md r14 ledger).
 
 Four hash kernels (murmur3/xxhash64 x int64/string) lived here through
 round 4 "for parity/API only".  They were measured on real v5e (r3

@@ -63,8 +63,8 @@ def regroup_order(pid, num_slots: int, engine: str = "auto",
       ``[n, num_slots]`` one-hot cumsum, plus ONE int32 scatter to
       invert the destination map.  The CPU path: ``lax.sort`` is
       XLA-CPU's worst primitive (r4 q6 engine table), while linear
-      passes and scatters are its best.  Measured r5 (prof_q95, 64K
-      rows, 1-core CPU): exchange leg 17.7 ms -> counting sort ~2 ms.
+      passes and scatters are its best.  Measured r5 (64K rows,
+      1-core CPU): exchange leg 17.7 ms -> counting sort ~2 ms.
     * ``'auto'`` — scatter on CPU when the one-hot stays small (few
       slots AND bounded n*num_slots cells), sort otherwise.
 

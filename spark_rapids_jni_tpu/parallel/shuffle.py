@@ -70,8 +70,8 @@ def exchange(
 
     pid, n_oob = route_out_of_range(pid, P)
     # platform-aware stable regroup (counting sort on CPU, lax.sort on
-    # accelerators) — the r5 prof_q95 breakdown showed this local leg
-    # dominating the exchange cost on XLA-CPU
+    # accelerators) — this local leg dominated the exchange cost on
+    # XLA-CPU (r5)
     from .partition import regroup_order
 
     perm = regroup_order(pid, P + 1)

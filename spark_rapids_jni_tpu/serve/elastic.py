@@ -15,7 +15,7 @@ a few dozen bytes, and it now carries everything placement needs):
   worker's own admission queue from its pong) first, then arena
   pressure, then the stall-suspect epoch, then slot id for
   determinism.  ``serve_placement=round_robin`` keeps a pure-rotation
-  dispatcher as the comparison arm for ``bench.py --elastic``.
+  dispatcher.
 * :class:`AutoScaler` — a control loop over the supervisor's admission
   queue depth.  Depth above ``serve_autoscale_high_water`` for a full
   ``serve_autoscale_hold_ms`` dwell (debounce: a one-tick burst is not

@@ -39,9 +39,9 @@ up in the worker-side query-kind registry (:func:`register_query_kind`)
   digest of the promoted bytes (the chaos scenario's workload: the
   digest is a pure function of the seed, so survivors are comparable
   bit-for-bit across worker kills)
-* ``q6_digest`` — the bench workload: ``steps`` q6 steps over
-  deterministic example batches, returns ``[digest, seconds]`` exactly
-  like ``bench.py --serve``'s in-process queries
+* ``q6_digest`` — ``steps`` q6 steps over deterministic example
+  batches, returns ``[digest, seconds]`` (``chip_smoke.py`` compares
+  the digest with the same steps run in process)
 * ``shuffle_digest`` — a deterministic shuffle exchange keyed by
   ``params["store_key"]`` through the persistent shuffle store
   (``--store-dir``): returns the delivered rows' sha256 plus whether
@@ -529,10 +529,6 @@ def main(argv=None) -> int:
                          "connect, so a fresh generation skips "
                          "first-query compile for warm classes")
     args = ap.parse_args(argv)
-
-    if os.environ.get("BENCH_FORCE_CPU"):
-        import jax
-        jax.config.update("jax_platforms", "cpu")
 
     from .. import faultinj
     faultinj.configure()  # env: the supervisor's exported schedule

@@ -26,6 +26,9 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+import shutil  # noqa: E402
+import tempfile  # noqa: E402
+
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
@@ -78,6 +81,27 @@ def eight_devices():
     if len(devs) < 8:
         pytest.skip("needs 8 virtual devices")
     return devs[:8]
+
+
+def _short_tempdir():
+    """A short directory of the test's own as ``tempfile.tempdir``.
+
+    A ``FrontDoor`` binds Unix sockets under ``tempfile.mkdtemp()``, and
+    an ``AF_UNIX`` path holds 107 bytes: under pytest's ``tmp_path``
+    (user name, run number, xdist worker, test name) whether it fits
+    depends on all four.  ``/tmp/spXXXXXXXX`` is 14.
+    """
+    short = tempfile.mkdtemp(prefix="sp", dir="/tmp")
+    before, tempfile.tempdir = tempfile.tempdir, short
+    try:
+        yield short
+    finally:
+        tempfile.tempdir = before
+        shutil.rmtree(short, ignore_errors=True)
+
+
+short_tempdir = pytest.fixture(_short_tempdir)
+short_tempdir_module = pytest.fixture(scope="module")(_short_tempdir)
 
 
 @pytest.fixture

@@ -758,6 +758,8 @@ class TestZoneMapMorselSkip:
                                       predicate=("x", "<", thresh),
                                       zone_map=zone)
         assert src.blocks_skipped > 0  # 1% selectivity MUST skip
+        # ... and most of what the sidecar was consulted for
+        assert src.blocks_skipped >= src.blocks_scanned
         res = svc.exchange_stream(src, key_names=["k"])
         full = svc.exchange_stream(
             MorselSource.from_batch(batch, mesh, morsel_rows=128),

@@ -188,7 +188,11 @@ class TestJsonPlane:
 
 
 class TestEndToEnd:
-    """Real fleets: batches through spawned workers on each plane."""
+    """Real fleets: batches through spawned workers on each plane.
+
+    Supervision is not what is tested: at the 80 ms heartbeat the rest
+    of the serving tests use, a worker busy under six xdist workers is
+    declared lost until the re-placement budget is spent."""
 
     @pytest.fixture(autouse=True)
     def _fast_ladder(self):
@@ -200,7 +204,7 @@ class TestEndToEnd:
         from spark_rapids_jni_tpu.serve import FrontDoor
         want = {k: dp.batch_digest(make_result_batch(512, k))
                 for k in range(2)}
-        fd = FrontDoor(workers=1, heartbeat_ms=80.0,
+        fd = FrontDoor(workers=1, heartbeat_ms=5000.0,
                        data_plane_mode="shm")
         try:
             sess = {k: fd.submit("arrow_batch", {"rows": 512, "seed": k})
@@ -225,7 +229,7 @@ class TestEndToEnd:
             {"match": "data_write_wk", "fault": "shm_torn", "count": 1},
         ]})
         want = dp.batch_digest(make_result_batch(512, 7))
-        fd = FrontDoor(workers=1, heartbeat_ms=80.0,
+        fd = FrontDoor(workers=1, heartbeat_ms=5000.0,
                        data_plane_mode="shm")
         try:
             s = fd.submit("arrow_batch", {"rows": 512, "seed": 7})
@@ -245,7 +249,7 @@ class TestEndToEnd:
              "count": 1},
         ]})
         want = dp.batch_digest(make_result_batch(512, 9))
-        fd = FrontDoor(workers=1, heartbeat_ms=80.0,
+        fd = FrontDoor(workers=1, heartbeat_ms=5000.0,
                        data_plane_mode="shm")
         try:
             s = fd.submit("arrow_batch", {"rows": 512, "seed": 9})

@@ -12,7 +12,6 @@ happen within a bounded poll.
 
 import os
 import signal
-import tempfile
 import threading
 import time
 
@@ -38,8 +37,7 @@ REMOTE_TEMPLATE = "sh -c 'exec \"$@\"' launcher-agent {argv}"
 
 
 @pytest.fixture(autouse=True)
-def _fast_ladder(tmp_path, monkeypatch):
-    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+def _fast_ladder(short_tempdir):
     config.set("serve_backoff_ms", 40.0)
     yield
     for knob in ("serve_backoff_ms", "serve_launcher", "serve_placement",

@@ -13,7 +13,6 @@ fixtures keep fleets small and heartbeats fast.
 
 import os
 import signal
-import tempfile
 import threading
 import time
 
@@ -30,12 +29,11 @@ from spark_rapids_jni_tpu.serve import (
 
 
 @pytest.fixture(autouse=True)
-def _fast_ladder(tmp_path, monkeypatch):
+def _fast_ladder(short_tempdir):
     # deterministic per-test fleet dirs: every mkdtemp (the fleet dir,
-    # its sockets, stores, worker dirs) lands under THIS test's tmp_path
-    # instead of a shared /tmp — two tests (or a retried flake) can
-    # never contend on leftover directories, and pytest reaps them
-    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    # its sockets, stores, worker dirs) lands under THIS test's own
+    # short directory instead of a shared /tmp — two tests (or a retried
+    # flake) can never contend on leftover directories
     config.set("serve_backoff_ms", 40.0)
     yield
     config.reset("serve_backoff_ms")
@@ -89,6 +87,25 @@ class TestHappyPath:
         with pytest.raises(ServeError):
             fd.submit("echo", {"value": "late"}).result(timeout=1)
         assert _no_stragglers()
+
+    @pytest.mark.parametrize("fleet", [
+        {}, {"transport": "tcp", "hosts": "hostA,hostB"}], ids=["unix", "tcp"])
+    def test_computed_result_crosses_bit_identical(self, fleet):
+        """The workers' ``q6_digest`` over seeded batches equals the same
+        steps run here, over either transport.  (A heartbeat with room:
+        a worker that computes under load is not what is tested.)"""
+        from spark_rapids_jni_tpu.serve.worker import _qk_q6_digest
+
+        asks = [{"rows": 2048, "stream": i, "query": 0, "steps": 1}
+                for i in range(2)]
+        fd = FrontDoor(workers=2, heartbeat_ms=5000.0, **fleet)
+        try:
+            sess = [fd.submit("q6_digest", p, tenant=f"t{i}")
+                    for i, p in enumerate(asks)]
+            got = [s.result(timeout=120)[0] for s in sess]
+        finally:
+            fd.shutdown()
+        assert got == [_qk_q6_digest(None, p, None)[0] for p in asks]
 
     def test_unknown_kind_fails_loudly(self):
         fd = FrontDoor(workers=1, heartbeat_ms=80.0)

@@ -167,7 +167,7 @@ def test_the_older_plans_keep_their_cache_keys(name):
 def test_no_knob_was_added_and_q6_keeps_its_27_slots():
     import __graft_entry__ as ge
 
-    assert len(config._REGISTRY) == 73
+    assert len(config._REGISTRY) == 71
     config.set("q6_float_mode", "f64")
     config.set("q6_onehot_engine", "xla")
     b = ge._device_batch(0, 1 << 10)

@@ -95,6 +95,32 @@ class TestLifecycle:
         assert s.granted_bytes == 1 * MB  # fit without splitting
         assert arena.total_allocated() == 0
 
+    def test_concurrent_streams_bit_identical_to_solo(self, arena):
+        """Four tenant streams interleaved on one arena give, query for
+        query, the digests the same queries give one at a time, and the
+        arena drains."""
+        from spark_rapids_jni_tpu.serve.worker import _qk_q6_digest
+
+        asks = {(i, k): {"rows": 2048, "stream": i, "query": k, "steps": 1}
+                for i in range(4) for k in range(2)}
+
+        def wave(max_concurrent):
+            rt = ServeRuntime(max_concurrent=max_concurrent)
+            try:
+                sess = {key: rt.submit(
+                    lambda ctx, sess, p=p: _qk_q6_digest(ctx, p, sess)[0],
+                    est_bytes=1 * MB, tenant=f"stream-{key[0]}")
+                    for key, p in asks.items()}
+                return {key: s.result(timeout=120)
+                        for key, s in sess.items()}
+            finally:
+                assert rt.shutdown()
+
+        solo = wave(1)
+        assert len(set(solo.values())) == len(asks)  # seeds differ
+        assert wave(4) == solo
+        assert arena.total_allocated() == 0
+
     def test_reservation_splits_under_pressure(self, arena, runtime):
         gate = threading.Event()
 

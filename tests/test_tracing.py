@@ -4,8 +4,6 @@ the lowered plan, the per-query timeline across the process boundary, and the
 converter that reads all of it back."""
 
 import re
-import shutil
-import tempfile
 import time
 
 import jax
@@ -405,23 +403,15 @@ WORKER_STAGES = ("worker.admit_wait", "worker.reserve", "worker.run",
 
 
 @pytest.fixture(scope="module")
-def fleet():
-    """One front door with one CPU worker, its socket under a short
-    directory (an ``AF_UNIX`` path holds 107 characters)."""
+def fleet(short_tempdir_module):
+    """One front door with one CPU worker."""
     from spark_rapids_jni_tpu.serve import FrontDoor
 
-    short = tempfile.mkdtemp(prefix="trc", dir="/tmp")
-    mp = pytest.MonkeyPatch()
-    mp.setattr(tempfile, "tempdir", short)
-    fd = None
+    fd = FrontDoor(workers=1, heartbeat_ms=5000.0)
     try:
-        fd = FrontDoor(workers=1, heartbeat_ms=5000.0)
         yield fd
     finally:
-        if fd is not None:
-            fd.shutdown()
-        mp.undo()
-        shutil.rmtree(short, ignore_errors=True)
+        fd.shutdown()
 
 
 class TestTimeline:

@@ -9,10 +9,3 @@ import sys
 _root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _root not in sys.path:
     sys.path.insert(0, _root)
-
-# Honor bench.py's CPU-pin convention in every tools/ script so the whole
-# measurement chain can be dry-run end-to-end off-hardware.
-if os.environ.get("BENCH_FORCE_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")

@@ -190,8 +190,8 @@ class Project:
 
     def universe(self) -> List[ParsedFile]:
         """Every .py under the project root (reads/uses may legitimately
-        live outside the linted paths — bench.py, __graft_entry__.py,
-        tools/ scripts)."""
+        live outside the linted paths — __graft_entry__.py,
+        chip_smoke.py, tools/ scripts)."""
         if self._universe is None:
             seen = {pf.path for pf in self.files}
             extra = []
