@@ -48,6 +48,9 @@ class PlanCache:
         # the newest plan traced: its joins by output form (a row mask
         # handed on, or the matches compacted in front)
         self.joins = {"joins_masked": 0, "joins_compacted": 0}
+        # ... and the gathers of one index a row that its sort-engine
+        # aggregates traced outside the branch that many groups take
+        self.agg_rowwide_gathers = 0
 
     def note_routes(self, routes) -> None:
         """Count a newly compiled plan's ``route:arithmetic:type``s."""
@@ -62,6 +65,12 @@ class PlanCache:
         with self._lock:
             self.joins = {"joins_masked": int(masked),
                           "joins_compacted": int(compacted)}
+
+    def note_rowwide_gathers(self, count: int) -> None:
+        """A plan was traced: the row-wide gathers of its sort-engine
+        aggregates (``relational.aggregate.rowwide_gathers``)."""
+        with self._lock:
+            self.agg_rowwide_gathers = int(count)
 
     def _capacity(self) -> int:
         if self._maxsize is not None:
@@ -165,6 +174,7 @@ class PlanCache:
                 "pinned": len(self._pins),
                 **self.routes,
                 **self.joins,
+                "agg_rowwide_gathers": self.agg_rowwide_gathers,
                 # int8 slots of the newest one-hot contraction traced
                 "onehot_slots": onehot_slots(),
             }

@@ -791,6 +791,39 @@ def _join_outputs(plan: ir.PlanNode, inputs: dict, decisions: dict) -> None:
             and _dense_domain(node, inputs) is not None) else "compact"
 
 
+def _rows_at(node: ir.PlanNode, inputs: dict) -> Optional[int]:
+    """Rows of ``node``'s output as it is lowered, where the plan and the
+    inputs tell: every node but an Aggregate hands on as many rows as its
+    (left) child has, an inner join within that budget."""
+    while not isinstance(node, ir.Scan):
+        if isinstance(node, ir.Aggregate) or (
+                isinstance(node, ir.Join) and node.how != "inner"):
+            return None
+        node = node.child
+    return getattr(inputs.get(node.name), "num_rows", None)
+
+
+def _aggregate_heads(plan: ir.PlanNode, inputs: dict,
+                     decisions: dict) -> None:
+    """Adds ``"head"`` to the decision of each Aggregate that the sort
+    engine may run (every lowering but the one-hot one: the scatter and
+    domain engines keep it as their fallback): the group slots at which
+    it fetches its result.  With the ``num_groups`` a query returns that
+    tells which branch ran: the head's up to it, the row-wide one past."""
+    from ..relational.aggregate import sortscan_head
+
+    ai = 0
+    for node in plan.walk():
+        if not isinstance(node, ir.Aggregate):
+            continue
+        schema = _schema_at(node.child, inputs)
+        if schema is None or not _takes_onehot(node, schema):
+            decisions.setdefault(
+                f"aggregate{ai}:{','.join(node.keys)}", {})["head"] = \
+                sortscan_head(_rows_at(node.child, inputs))
+        ai += 1
+
+
 def _default_stats() -> Optional[dict]:
     """Live stats the system already recorded: the process-wide
     :class:`~spark_rapids_jni_tpu.shuffle.registry.ShuffleMetrics`
@@ -823,6 +856,7 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
             decisions = adaptive.plan_decisions(plan, inputs, stats)
             decisions.update(_typed_decisions(plan, inputs))
             _join_outputs(plan, inputs, decisions)
+            _aggregate_heads(plan, inputs, decisions)
         with profiler.span("plan.key"):
             key = plan_cache_key(plan, inputs, decisions)
         cache = get_plan_cache()
@@ -839,12 +873,17 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
             # the Python lowering: runs only when jit traces, so this
             # span appears on a cache miss (or a retrace) and never on
             # a hit
+            from ..relational.aggregate import rowwide_gathers
+
             with profiler.span("plan.trace"):
                 _TRACE_COUNT[0] += 1
                 st = _State(join_plans, agg_hints)
+                rowwide = rowwide_gathers()
                 batch, live, _pfx = _lower(plan, env, prebuilts, st)
                 get_plan_cache().note_joins(st.joins_masked,
                                             st.joins_compacted)
+                get_plan_cache().note_rowwide_gathers(
+                    rowwide_gathers() - rowwide)
                 # from an Aggregate up ``live`` is the group count
                 return batch if live is None else (batch, live)
 

@@ -26,12 +26,16 @@ equality domain)
 -> one ``lax.sort`` carrying [keys..., row-id] (agg values are gathered
 along the permutation afterwards by default; config
 ``group_sort_payload='ride'`` makes them ride the sort as extra payload
-operands instead — round 3 measured the wide emulated-64-bit sort at
-~1s/iter @256K rows on v5e, so narrow-sort+gather is the default) ->
+operands instead; input that ``assume_grouped`` says is in order already
+is neither sorted nor moved) ->
 adjacent-compare boundaries on the sorted key words -> per-agg prefix
 ``cumsum`` (or segmented min/max ``associative_scan``) -> group result =
 scan value at each group's last row minus the previous group's, fetched
-with one small gather at the compacted group-end positions.
+at the compacted group-end positions: at the first
+``min(rows, 4096)`` of them, padded back to the input's rows, unless the
+data has more groups than that (``lax.cond`` on ``num_groups``; a gather
+costs by the index, 33-47 ms a buffer of 2^22 on a v5e, PERF.md section
+5, and a result of ten groups needs ten).
 
 Output is padded to the input row count with a device ``num_groups``
 scalar (same discipline as :mod:`filter`); groups appear in key-sorted
@@ -347,6 +351,39 @@ def group_by(
                               assume_grouped)
 
 
+# Group slots a general engine provides for before it has seen the data:
+# the scatter engine's default table, and the head of the result at which
+# the sort engine reads its scans.
+_DEFAULT_GROUP_SLOTS = 4096
+
+# key column representations whose gathered rows _pad_rows can pad
+_HEAD_KEY_TYPES = (Column, Decimal128Column, StringColumn, DictionaryColumn)
+
+_ROWWIDE_GATHERS = [0]
+
+
+def sortscan_head(num_rows: Optional[int] = None) -> int:
+    """Group slots at which the sort engine fetches its result over
+    ``num_rows`` input rows (None: more than it ever takes); data with
+    more groups takes the branch that fetches at every row."""
+    if num_rows is None:
+        return _DEFAULT_GROUP_SLOTS
+    return min(int(num_rows), _DEFAULT_GROUP_SLOTS)
+
+
+def rowwide_gathers() -> int:
+    """Gathers of one index a row, over inputs of more rows than the
+    head, that the sort engine has traced in this process outside the
+    branch that many groups take.  The plan compiler notes a plan's share
+    (``plan.plan_cache_metrics()["agg_rowwide_gathers"]``)."""
+    return _ROWWIDE_GATHERS[0]
+
+
+def _note_rowwide_gather(n: int) -> None:
+    if n > sortscan_head(n):
+        _ROWWIDE_GATHERS[0] += 1
+
+
 def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
     """The sort engine: one stable multi-operand sort, then scans."""
     n = batch.num_rows
@@ -373,17 +410,18 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                 )
             if spec.column not in agg_cols:
                 agg_cols.append(spec.column)
-    # Two ways to move agg values into sorted order (config
-    # ``group_sort_payload``).  'ride': values ride the sort as payload
-    # operands — no post-sort gathers, but every 64-bit operand is an
-    # emulated u32 pair inside the TPU sort network, and the multi-operand
-    # sort measured ~1s/iter at 256K rows on v5e (round 3).  'gather':
-    # sort carries only [keys..., row-id]; each agg column is fetched
-    # afterwards with one take() along the permutation (linear passes,
-    # ~24ms per 2M-row gather measured round 2).
+    # Two ways to move agg values into sorted order, on the sorting path
+    # only (config ``group_sort_payload``; grouped input is read where it
+    # lies).  'ride': values ride the sort as payload operands — no
+    # post-sort gathers, but every 64-bit operand is an emulated u32 pair
+    # inside the TPU sort network.  'gather' (the default): the sort
+    # carries only [keys..., row-id] and each agg column is fetched
+    # afterwards with one take() along the permutation: 33-47 ms a
+    # u32-sized buffer of 2^22 rows on a v5e (PERF.md section 5).
     from .. import config as _config
 
-    ride = _config.get("group_sort_payload") == "ride"
+    ride = (not assume_grouped
+            and _config.get("group_sort_payload") == "ride")
     payload = [iota]
     spans = {}
     if ride:
@@ -405,8 +443,7 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
         # scan below works on input order directly and the whole sort —
         # the engine's dominant cost — disappears.
         skeys = tuple(karr)
-        sperm = iota
-        spay = tuple(payload[1:])
+        sperm = spay = None
     else:
         with scope("agg.sortscan_sort"):
             res = jax.lax.sort(tuple(karr) + tuple(payload), num_keys=nk,
@@ -437,31 +474,79 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
         prev_ends = jnp.roll(ends, 1)
         out_valid = iota < num_groups
 
+    # A result of num_groups rows is read at the head of the group slots:
+    # the scans stay whole, but what fetches them at the group ends takes
+    # ``head`` indices and pads back to n rows, unless the data has more
+    # groups than that (one scalar compare picks the branch at run time).
+    head = sortscan_head(n)
+
+    def per_group(read, pad=lambda a: _pad_leading(a, n - head)):
+        """``read(w)``: per-group values at the first ``w`` group slots;
+        padded to the n rows of the result where ``w`` is the head."""
+        if head == n:
+            return read(n)
+
+        def at_head(_):
+            with scope("agg.sortscan_head"):
+                return pad(read(head))
+
+        def in_full(_):
+            with scope("agg.sortscan_full"):
+                return read(n)
+
+        return jax.lax.cond(num_groups <= head, at_head, in_full, None)
+
+    def at_ends(run):
+        """A segmented scan's value at each group's last row."""
+        return per_group(lambda w: run[ends[:w]])
+
     def at_ends_diff(cs):
         """Per-group total from a prefix scan: cs[end_g] - cs[end_{g-1}]."""
-        ce = jnp.take(cs, ends)
-        cp = jnp.where(iota == 0, jnp.zeros((), cs.dtype),
-                       jnp.take(cs, prev_ends))
-        return ce - cp
+        def read(w):
+            ce = cs[ends[:w]]
+            cp = jnp.where(iota[:w] == 0, jnp.zeros((), cs.dtype),
+                           cs[prev_ends[:w]])
+            return ce - cp
+
+        return per_group(read)
+
+    def in_order(arr):
+        """A column's buffer in sorted row order: its own under
+        ``assume_grouped``, else one gather of all n rows."""
+        if assume_grouped:
+            return arr
+        _note_rowwide_gather(n)
+        return arr[sperm]
 
     with scope("agg.sortscan_reduce"):
         out = {}
-        starts = jnp.where(iota == 0, 0, prev_ends + 1)
-        rows0 = jnp.take(sperm, jnp.clip(starts, 0, n - 1))
-        for name in key_names:
-            out[name] = gather_column(batch[name], rows0, out_valid)
+        starts = jnp.clip(jnp.where(iota == 0, 0, prev_ends + 1), 0, n - 1)
+
+        def first_rows(w):
+            """Each key column at its group's first row."""
+            rows0 = starts[:w] if assume_grouped else sperm[starts[:w]]
+            return [gather_column(batch[name], rows0, out_valid[:w])
+                    for name in key_names]
+
+        if all(isinstance(batch[name], _HEAD_KEY_TYPES)
+               for name in key_names):
+            firsts = per_group(
+                first_rows, pad=lambda cols: [_pad_rows(c, n) for c in cols])
+        else:   # a representation with no null-row padding of its own
+            _note_rowwide_gather(n)
+            firsts = first_rows(n)
+        out.update(zip(key_names, firsts))
 
         def sorted_valid(name):
-            return jnp.take(batch[name].validity, sperm) & sorted_occ
+            return in_order(batch[name].validity) & sorted_occ
 
         def sorted_col(name):
-            if ride and name in spans:
+            if name in spans:
                 off = spans[name]
                 data = spay[off - 1]  # payload[0] is iota (== sperm)
                 valid = spay[off] & sorted_occ
                 return data, valid
-            col = batch[name]
-            return jnp.take(col.data, sperm), sorted_valid(name)
+            return in_order(batch[name].data), sorted_valid(name)
 
         for spec in aggs:
             if spec.op == "count":
@@ -492,7 +577,7 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                 from ..ops import decimal as D
 
                 svalid = sorted_valid(spec.column)
-                slimbs = jnp.take(dcol.limbs, sperm, axis=0)
+                slimbs = in_order(dcol.limbs)
                 nn_d = at_ends_diff(jnp.cumsum(svalid.astype(jnp.int32)))
                 has_any_d = out_valid & (nn_d > 0)
                 if spec.op in ("min", "max"):
@@ -506,14 +591,14 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                     hi = jnp.where(svalid, slimbs[:, 1], fhi)
                     rlo, rhi = _seg_scan_minmax128(lo, hi, boundary, spec.op)
                     out[spec.out_name] = Decimal128Column(
-                        jnp.stack([jnp.take(rlo, ends),
-                                   jnp.take(rhi, ends)], axis=1),
+                        per_group(lambda w: jnp.stack(
+                            [rlo[ends[:w]], rhi[ends[:w]]], axis=1)),
                         has_any_d, dcol.dtype)
                     continue
                 u = D._from_i128(slimbs)
                 u = jnp.where(svalid[:, None], u, jnp.zeros((), jnp.uint32))
                 run = _seg_scan_sum256(u, boundary)
-                s256 = jnp.take(run, ends, axis=0)
+                s256 = at_ends(run)
                 if spec.op == "mean":
                     limbs128, ok, out_t = _decimal_avg(s256, nn_d, dcol.dtype)
                     out[spec.out_name] = Decimal128Column(
@@ -540,7 +625,7 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                                   else jnp.float64)
                 acc = jnp.where(valid, acc, jnp.zeros((), acc.dtype))
                 if jnp.issubdtype(acc.dtype, jnp.floating):
-                    s = jnp.take(_seg_scan_sum(acc, boundary), ends)
+                    s = at_ends(_seg_scan_sum(acc, boundary))
                 else:
                     s = at_ends_diff(jnp.cumsum(acc))  # exact mod-2^64
                 if spec.op == "mean":
@@ -566,7 +651,7 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                     valid_num = valid
                 masked = jnp.where(valid_num, data, fill)
                 run = _seg_scan_minmax(masked, boundary, spec.op)
-                r = jnp.take(run, ends)
+                r = at_ends(run)
                 if is_float:
                     seg_nan = at_ends_diff(
                         jnp.cumsum(nan_in.astype(jnp.int32))) > 0
@@ -582,9 +667,6 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                 out[spec.out_name] = Column(r, out_valid & has_any, col_dtype)
 
     return ColumnBatch(out), num_groups
-
-
-_DEFAULT_GROUP_SLOTS = 4096
 
 
 def _group_by_hash(batch, key_names, aggs, row_valid, num_slots,
@@ -1783,20 +1865,30 @@ def _domain_partials_scatter(batch, key_name, aggs, domain, row_valid=None):
             "fsum": fsum_of, "d64": d64_of}, overflow
 
 
+def _pad_leading(a, extra: int):
+    """``a`` with ``extra`` zero rows after its own."""
+    return jnp.pad(a, [(0, extra)] + [(0, 0)] * (a.ndim - 1))
+
+
 def _pad_rows(col, pad_to: int):
     """Pad a result column with null rows up to ``pad_to`` rows."""
     n = col.num_rows
     if n == pad_to:
         return col
-    extra = pad_to - n
-    pv = jnp.concatenate([col.validity, jnp.zeros((extra,), jnp.bool_)])
+
+    def rows(a):
+        return _pad_leading(a, pad_to - n)
+
+    if isinstance(col, DictionaryColumn):
+        return dataclasses.replace(col, codes=rows(col.codes),
+                                   validity=rows(col.validity))
+    if isinstance(col, StringColumn):
+        return StringColumn(rows(col.chars), rows(col.lengths),
+                            rows(col.validity), col.dtype)
     if isinstance(col, Decimal128Column):
-        pl = jnp.concatenate(
-            [col.limbs, jnp.zeros((extra, 2), jnp.uint64)], axis=0)
-        return Decimal128Column(pl, pv, col.dtype)
-    pd = jnp.concatenate(
-        [col.data, jnp.zeros((extra,), col.data.dtype)])
-    return Column(pd, pv, col.dtype)
+        return Decimal128Column(rows(col.limbs), rows(col.validity),
+                                col.dtype)
+    return Column(rows(col.data), rows(col.validity), col.dtype)
 
 
 def group_by_domain_or_sort(

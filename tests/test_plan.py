@@ -645,3 +645,88 @@ class TestMaskedJoins:
             assert got == want
         finally:
             cp.close()
+
+
+# ---------------------------------------------------------------------------
+# the sort engine's head in a plan: the decision and the counter
+# ---------------------------------------------------------------------------
+
+class TestAggregateHeads:
+    """An aggregate that the sort engine may run says in its decision the
+    group slots at which it fetches its result, and
+    ``plan_cache_metrics()["agg_rowwide_gathers"]`` the gathers of one
+    index a row that the newest plan traced makes whatever the data
+    holds."""
+
+    ROWS = 1 << 13   # more than the head holds, so a row-wide gather counts
+
+    @pytest.mark.parametrize("name,engine,want", [
+        # the benchmark's q95 as the chip runs it: grouped rows read in
+        # place, ten groups fetched at the head
+        ("q95", "auto", 0),
+        # q9's aggregate has a domain and takes the segment sums; the
+        # sort engine is its fallback and fetches at the head too, but has
+        # to move sum(v)'s and avg(v)'s column through its sort (data,
+        # validity, twice)
+        ("q9", "auto", 4),
+        # no domain, the sort engine pinned: the same move for one sum
+        ("general", "sort", 2),
+        ("general", "scatter", 2),   # ... as the scatter engine's fallback
+    ])
+    def test_counter_and_decision(self, knob, monkeypatch, name, engine,
+                                  want):
+        import __graft_entry__ as ge
+
+        from spark_rapids_jni_tpu.plan.ir import Agg, Aggregate, Scan
+
+        fact, dim1, dim2 = ge._q95_batches(self.ROWS, seed=3)
+        inputs = {"fact": fact, "dim1": dim1, "dim2": dim2}
+        if name == "q95":
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            the_plan = queries.q95_plan()
+        elif name == "q9":
+            the_plan = queries.q9_plan()
+        else:
+            knob("groupby_engine", engine)
+            the_plan = Aggregate(Scan("fact"), keys=("seg",),
+                                 aggs=(Agg("sum", "v", "net"),))
+            inputs = {"fact": fact}
+        cp = plan.compile_plan(the_plan, inputs)
+        try:
+            res, ng = cp(inputs)
+            assert cp.decisions["aggregate0:seg"]["head"] == 4096
+            m = plan.plan_cache_metrics()
+            assert m["agg_rowwide_gathers"] == want
+            assert 0 < int(ng) <= 10
+            # a second lookup hits, traces nothing and leaves the counter
+            t0 = plan.trace_count()
+            again = plan.compile_plan(the_plan, inputs)
+            assert again is cp and again.last_lookup == "hit"
+            again(inputs)
+            assert plan.trace_count() == t0
+            assert plan.plan_cache_metrics()["agg_rowwide_gathers"] == want
+        finally:
+            cp.close()
+
+    def test_a_onehot_aggregate_says_nothing(self):
+        import __graft_entry__ as ge
+
+        b = ge._device_batch(0, self.ROWS)
+        cp = plan.compile_plan(queries.q6_plan(), {"batch": b})
+        cp({"batch": b})
+        assert not [k for k in cp.decisions if k.startswith("aggregate")]
+        assert plan.plan_cache_metrics()["agg_rowwide_gathers"] == 0
+
+    def test_the_head_follows_the_rows(self):
+        """Under 4096 rows the head is every row, and the decision says
+        so; above, 4096."""
+        import __graft_entry__ as ge
+
+        for rows, head in ((1 << 9, 1 << 9), (1 << 12, 4096),
+                           (1 << 13, 4096)):
+            fact, dim1, dim2 = ge._q95_batches(rows, seed=3)
+            cp = plan.compile_plan(
+                queries.q95_plan(),
+                {"fact": fact, "dim1": dim1, "dim2": dim2})
+            assert cp.decisions["aggregate0:seg"] == {"head": head}
+            cp.close()

@@ -136,9 +136,12 @@ PARENT_SIGNATURES = {
 PARENT_DECISIONS = {
     "q6_plan": {'adaptive': True},
     # a join's decision says its output form too (PR 32): q95's joins each
-    # feed an exchange and hand on a row mask, a broadcast join compacts
-    "q95_plan": {'adaptive': True, 'join0:k': {'strategy': 'shuffled', 'build_rows': 128, 'output': 'mask'}, 'join1:wh': {'strategy': 'shuffled', 'build_rows': 25, 'output': 'mask'}},
-    "q9_plan": {'adaptive': True, 'join0:k': {'strategy': 'broadcast', 'build_rows': 128, 'engine': 'hash', 'output': 'compact'}, 'join1:wh': {'strategy': 'broadcast', 'build_rows': 25, 'engine': 'hash', 'output': 'compact'}},
+    # feed an exchange and hand on a row mask, a broadcast join compacts.
+    # An aggregate the sort engine may run says the group slots at which it
+    # fetches its result (PR 34): all 2^10 rows here, 4096 from there up;
+    # q6's takes the one-hot engine and says nothing
+    "q95_plan": {'adaptive': True, 'join0:k': {'strategy': 'shuffled', 'build_rows': 128, 'output': 'mask'}, 'join1:wh': {'strategy': 'shuffled', 'build_rows': 25, 'output': 'mask'}, 'aggregate0:seg': {'head': 1024}},
+    "q9_plan": {'adaptive': True, 'join0:k': {'strategy': 'broadcast', 'build_rows': 128, 'engine': 'hash', 'output': 'compact'}, 'join1:wh': {'strategy': 'broadcast', 'build_rows': 25, 'engine': 'hash', 'output': 'compact'}, 'aggregate0:seg': {'head': 1024}},
 }
 
 

@@ -143,15 +143,16 @@ def test_benchmark_plans_fit_two_in_flight(one_chip, monkeypatch,
 
 
 def _gathers(text):
-    """``(scope path, rows of the operand read)`` of every gather in a
-    lowered program's text (``as_text(debug_info=True)``)."""
+    """``(scope path, rows of the operand read, rows of the result)`` of
+    every gather in a lowered program's text
+    (``as_text(debug_info=True)``)."""
     import re
 
     locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
-    return [(locs.get(m.group(2), ""), int(m.group(1)))
+    return [(locs.get(m.group(3), ""), int(m.group(1)), int(m.group(2)))
             for m in re.finditer(
-                r'"stablehlo\.gather"\(.*\(tensor<(\d+)[x>].*loc\((#loc\d+)\)',
-                text)]
+                r'"stablehlo\.gather"\(.*\(tensor<(\d+)[x>].*'
+                r'-> tensor<(\d+)[x>].*loc\((#loc\d+)\)', text)]
 
 
 def test_q95_plan_compiles_with_its_dense_joins_as_lookups(one_chip,
@@ -160,15 +161,21 @@ def test_q95_plan_compiles_with_its_dense_joins_as_lookups(one_chip,
     ``dim2`` 25) with the chip's engines: both joins hand on a row mask, so
     under ``plan.join.*`` only the general branch (the engine that expands
     rows) gathers a column of the fact; the dense branch reads the small
-    tables alone.  And the chip's compiler takes the program."""
+    tables alone.  The fused aggregate reads its grouped rows in place and
+    fetches its scans at the head of the group slots: under
+    ``agg.sortscan_reduce`` only the branch that more than 4096 groups take
+    gathers a row-wide result.  And the chip's compiler takes the
+    program."""
     lowered, decisions, cfg, mod = _lower_plan("q95-join-agg", one_chip,
                                                monkeypatch)
     rows = mod.rows_per_query(cfg)
     assert rows == 1 << 22
     assert [decisions[k]["output"] for k in ("join0:k", "join1:wh")] \
         == ["mask", "mask"]
-    in_joins = [(path, n) for path, n in _gathers(
-        lowered.as_text(debug_info=True)) if "/plan.join." in path]
+    assert decisions["aggregate0:seg"] == {"head": 4096}
+    gathers = _gathers(lowered.as_text(debug_info=True))
+    in_joins = [(path, n) for path, n, _out in gathers
+                if "/plan.join." in path]
     # the parser sees the fact's columns gathered where they still are ...
     assert [1 for path, n in in_joins
             if "/join.general/join.gather_left/" in path and n == rows]
@@ -178,6 +185,18 @@ def test_q95_plan_compiles_with_its_dense_joins_as_lookups(one_chip,
     # ... and nothing of the fact's size outside the general branch
     assert not [(path, n) for path, n in in_joins
                 if "/join.general/" not in path and n >= rows]
+    in_reduce = [(path, out) for path, _n, out in gathers
+                 if "/plan.aggregate.seg/agg.sortscan_reduce/" in path]
+    # the key, count(*), the value's non-null count and sum(v): two
+    # buffers each, at the head ...
+    at_head = [out for path, out in in_reduce
+               if "/agg.sortscan_head/" in path]
+    assert len(at_head) == 8 and set(at_head) == {4096}
+    # ... the same eight a row wide where the data has more groups ...
+    assert [out for path, out in in_reduce
+            if "/agg.sortscan_full/" in path] == [rows] * 8
+    # ... and nothing outside the two branches
+    assert len(in_reduce) == 16
     mem = lowered.compile().memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
 
