@@ -6,7 +6,7 @@ query used to mean hand-writing another.  This package makes a query
 DATA instead:
 
 * :mod:`ir` — a small logical IR (Scan/Filter/Project/Join/Aggregate/
-  Exchange/Sort over ``ColumnBatch``, expressions ``Col``/``Lit``/``+ - *``
+  Exchange/Sort/TopK over ``ColumnBatch``, expressions ``Col``/``Lit``/``+ - *``
   inside a Project), hashable and canonicalized so a plan SHAPE is a
   dict key;
 * :mod:`compile` — lowers a whole plan into ONE jitted program, fusing
@@ -28,8 +28,8 @@ bit-identical to the hand-fused paths on plain AND encoded inputs,
 under both engine knob settings.
 """
 
-from .ir import (Aggregate, Agg, Arith, Col, DateLit, Exchange, Filter, Join,
-                 Lit, Project, Scan, Sort)
+from .ir import (Aggregate, Agg, Arith, Col, DateLit, Desc, Exchange, Filter,
+                 Join, Lit, Project, Scan, Sort, SortOrder, TopK)
 from .compile import (CompiledPlan, compile_plan, execute, expr_type,
                       trace_count)
 from .cache import get_plan_cache, plan_cache_metrics, reset_plan_cache
@@ -40,7 +40,7 @@ from . import queries
 
 __all__ = [
     "Scan", "Filter", "Project", "Join", "Aggregate", "Agg", "Exchange",
-    "Sort", "Col", "Lit", "Arith", "DateLit",
+    "Sort", "SortOrder", "Desc", "TopK", "Col", "Lit", "Arith", "DateLit",
     "CompiledPlan", "compile_plan", "execute", "expr_type", "trace_count",
     "get_plan_cache", "plan_cache_metrics", "reset_plan_cache",
     "choose_join_strategy", "choose_join_engine", "choose_groupby_engine",

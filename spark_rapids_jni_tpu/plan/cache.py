@@ -51,6 +51,9 @@ class PlanCache:
         # ... and the gathers of one index a row that its sort-engine
         # aggregates traced outside the branch that many groups take
         self.agg_rowwide_gathers = 0
+        # ... and the row slots its ordered limits put through a sort or
+        # a selection
+        self.topk_sorted_rows = 0
 
     def note_routes(self, routes) -> None:
         """Count a newly compiled plan's ``route:arithmetic:type``s."""
@@ -71,6 +74,12 @@ class PlanCache:
         aggregates (``relational.aggregate.rowwide_gathers``)."""
         with self._lock:
             self.agg_rowwide_gathers = int(count)
+
+    def note_topk_rows(self, rows: int) -> None:
+        """A plan was traced: the row slots its ordered limits (``TopK``)
+        put through their selection, 0 for a plan with none."""
+        with self._lock:
+            self.topk_sorted_rows = int(rows)
 
     def _capacity(self) -> int:
         if self._maxsize is not None:
@@ -175,6 +184,7 @@ class PlanCache:
                 **self.routes,
                 **self.joins,
                 "agg_rowwide_gathers": self.agg_rowwide_gathers,
+                "topk_sorted_rows": self.topk_sorted_rows,
                 # int8 slots of the newest one-hot contraction traced
                 "onehot_slots": onehot_slots(),
             }

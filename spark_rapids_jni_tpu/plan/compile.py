@@ -41,7 +41,13 @@ The lowering rules are the hand-fused flagship pipelines, factored:
   an Aggregate that takes the one-hot engine the computed columns are
   handed over unevaluated and computed inside its row slices.
 * Sort on exactly the keys of a composite-domain Aggregate below it is
-  elided: that engine emits key order, nulls first.
+  elided: that engine emits key order, nulls first.  Any other Sort is
+  one stable sort over the keys' radix words (a descending key's
+  complemented) and a gather of every column.
+* TopK -> ``k`` rounds of selection over the order's radix words among
+  the live rows (``relational.sort.top_k_rows``) and a gather of ``k``
+  rows of every column: no row slot is sorted or moved, whatever the
+  input's size.
 
 One ``jax.jit`` wraps the whole lowered pipeline, so XLA sees every
 stage together.  Programs are cached in :mod:`cache` keyed on
@@ -420,12 +426,14 @@ class _State:
         # matches compacted in front
         self.joins_masked = 0
         self.joins_compacted = 0
+        # row slots the plan's ordered limits put through their selection
+        self.topk_sorted_rows = 0
 
 
 def node_scope(node: ir.PlanNode) -> str:
     """The named scope a node's own operations are lowered under:
     ``plan.filter.<column>``, ``plan.exchange.<key>``, ``plan.join.<right
-    scan name>``, ``plan.aggregate.<first key>``, ``plan.sort``; a
+    scan name>``, ``plan.aggregate.<first key>``, ``plan.sort``, ``plan.topk``; a
     Project's computed outputs each have ``plan.project.<output>``.  A
     child is lowered before and outside its parent's scope, so a device
     operation's path starts at the one node it belongs to."""
@@ -480,6 +488,9 @@ def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
     if isinstance(node, ir.Sort):
         return _lower_sort(node, env, prebuilts, st)
 
+    if isinstance(node, ir.TopK):
+        return _lower_topk(node, env, prebuilts, st)
+
     if isinstance(node, ir.Join):
         return _lower_join(node, env, prebuilts, st)
 
@@ -487,6 +498,13 @@ def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
         return _lower_aggregate(node, env, prebuilts, st)
 
     raise TypeError(f"cannot lower {type(node).__name__}")
+
+
+def _sort_keys(node) -> list:
+    from ..relational.sort import SortKey
+
+    return [SortKey(o.name, o.ascending, o.resolved_nulls_first())
+            for o in node.order()]
 
 
 def _lower_sort(node: ir.Sort, env, prebuilts, st):
@@ -497,10 +515,11 @@ def _lower_sort(node: ir.Sort, env, prebuilts, st):
     # from an Aggregate: the count of live rows, which are in front
     count = live if live is not None and live.ndim == 0 else None
     if count is not None:
-        if id(node.child) in st.key_ordered:
+        if id(node.child) in st.key_ordered \
+                and node.keys == node.child.keys:
             return b, count, True   # already in this order: elided
         live = jnp.arange(b.num_rows, dtype=jnp.int32) < count
-    keys = [SortKey(k) for k in node.keys]
+    keys = _sort_keys(node)
     with profiler.scope(node_scope(node)):
         if live is None:
             return sort_by(b, keys), None, True
@@ -514,6 +533,27 @@ def _lower_sort(node: ir.Sort, env, prebuilts, st):
             live.astype(jnp.int32))
         return (out.select([nm for nm in out.names if nm != "__occ"]),
                 new_live if count is None else count, True)
+
+
+def _lower_topk(node: ir.TopK, env, prebuilts, st):
+    """The ordered limit: selection among the live rows (from an Aggregate
+    the ``count`` in front), then ``n`` rows of every column."""
+    from ..relational.gather import gather_batch
+    from ..relational.sort import top_k_rows
+
+    b, live, _pfx = _lower(node.child, env, prebuilts, st)
+    counted = live is not None and live.ndim == 0
+    rows = b.num_rows
+    st.topk_sorted_rows += rows
+    with profiler.scope(node_scope(node)):
+        with profiler.scope("topk.select"):
+            if counted:
+                live = jnp.arange(rows, dtype=jnp.int32) < live
+            idx, cnt = top_k_rows(b, _sort_keys(node), node.n, live)
+        with profiler.scope("topk.gather"):
+            front = jnp.arange(node.n, dtype=jnp.int32) < cnt
+            out = gather_batch(b, idx, front)
+    return out, (cnt if counted else front), True
 
 
 def _lower_join(node: ir.Join, env, prebuilts, st):
@@ -766,17 +806,18 @@ def _join_outputs(plan: ir.PlanNode, inputs: dict, decisions: dict) -> None:
     """Adds to each join's decision its output form: ``"mask"`` where the
     join is an inner, shuffled one over a dense domain and what consumes
     its rows takes a scattered row mask (an Exchange, an Aggregate, a
-    Filter, a Join whose left child it is; a Project hands its own
-    consumer's answer down), so that it need not put the matches in
-    front; ``"compact"`` at the root, under a Sort and everywhere else."""
+    Filter, a Join on either side: the build takes ``right_valid`` as the
+    probe takes ``left_valid``; a Project hands its own consumer's answer
+    down), so that it need not put the matches in front; ``"compact"`` at
+    the root, under a Sort or TopK and everywhere else."""
     joins = []   # (Join, its consumer takes a mask), in walk order
 
     def visit(node, masked):
-        for i, c in enumerate(node.children()):
+        for c in node.children():
             if isinstance(node, ir.Project):
                 visit(c, masked)
             elif isinstance(node, ir.Join):
-                visit(c, i == 0)
+                visit(c, True)
             else:
                 visit(c, isinstance(node, (ir.Exchange, ir.Aggregate,
                                            ir.Filter)))
@@ -796,6 +837,8 @@ def _rows_at(node: ir.PlanNode, inputs: dict) -> Optional[int]:
     inputs tell: every node but an Aggregate hands on as many rows as its
     (left) child has, an inner join within that budget."""
     while not isinstance(node, ir.Scan):
+        if isinstance(node, ir.TopK):
+            return node.n
         if isinstance(node, ir.Aggregate) or (
                 isinstance(node, ir.Join) and node.how != "inner"):
             return None
@@ -884,6 +927,7 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
                                             st.joins_compacted)
                 get_plan_cache().note_rowwide_gathers(
                     rowwide_gathers() - rowwide)
+                get_plan_cache().note_topk_rows(st.topk_sorted_rows)
                 # from an Aggregate up ``live`` is the group count
                 return batch if live is None else (batch, live)
 
@@ -898,12 +942,22 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
 
 def _schema_at(node: ir.PlanNode, inputs: dict) -> Optional[dict]:
     """name -> SparkType of ``node``'s output where it can be told from
-    the plan and the inputs alone (Scan, Filter, Project); else None."""
+    the plan and the inputs alone (Scan, Filter, Exchange, Sort, TopK,
+    Project, a Join whose sides share no name but the key); else None."""
     if isinstance(node, ir.Scan):
         return _batch_schema(inputs[node.name]) if node.name in inputs \
             else None
-    if isinstance(node, ir.Filter):
+    if isinstance(node, (ir.Filter, ir.Exchange, ir.Sort, ir.TopK)):
         return _schema_at(node.child, inputs)
+    if isinstance(node, ir.Join):
+        left = _schema_at(node.child, inputs)
+        right = _schema_at(node.right, inputs)
+        if left is None or right is None:
+            return None
+        right = {n: t for n, t in right.items() if n != node.right_on}
+        if set(left) & set(right):
+            return None   # suffixed by the join: not followed here
+        return {**left, **right}
     if isinstance(node, ir.Project):
         below = _schema_at(node.child, inputs)
         if below is None:
@@ -919,11 +973,13 @@ def _schema_at(node: ir.PlanNode, inputs: dict) -> Optional[dict]:
 def _typed_decisions(plan: ir.PlanNode, inputs: dict) -> dict:
     """What the compiler decides from types: for each computed output of a
     Project its Spark type and the arithmetic of every operation under
-    it (``project<i>:<output>``), and a Sort that the Aggregate below it
-    makes redundant (``sort<i>:<keys>``).  Plans with neither get nothing,
-    so their cache keys are what they were."""
+    it (``project<i>:<output>``), a Sort that the Aggregate below it
+    makes redundant (``sort<i>:<keys>``), and an ordered limit's ``n``, its
+    keys with direction and null placement and its route
+    (``topk<i>:<keys>``).  Plans with none of them get
+    nothing, so their cache keys are what they were."""
     out = {}
-    pi = si = 0
+    pi = si = ti = 0
     for node in plan.walk():
         if isinstance(node, ir.Project):
             below = _schema_at(node.child, inputs)
@@ -948,6 +1004,13 @@ def _typed_decisions(plan: ir.PlanNode, inputs: dict) -> dict:
                         "elided": "the composite-domain aggregate below "
                                   "emits key order, nulls first"}
             si += 1
+        elif isinstance(node, ir.TopK):
+            order = node.order()
+            out[f"topk{ti}:{','.join(o.name for o in order)}"] = {
+                "n": node.n,
+                "keys": tuple(o.describe() for o in order),
+                "route": "selection"}
+            ti += 1
     return out
 
 

@@ -220,7 +220,11 @@ class Join(PlanNode):
     path (``join_dense_or_hash``): an int domain, or the sentinel
     ``"build"`` meaning "the build side's row count" (the TPC-DS dim
     shape, where keys are an arange over the dim's rows — a property of
-    the DATA, resolved when the plan meets its inputs).  ``strategy``
+    the DATA, resolved when the plan meets its inputs).  An int domain
+    may be a sparse key range far wider than the build side (TPC-H order
+    keys take 8 of every 32 values: 1,500,000 keys below 6,000,001); the
+    program checks what it assumes at run time and takes the general
+    engine where the data says otherwise.  ``strategy``
     picks the physical form: ``'shuffled'`` (the hand-q95 lowering),
     ``'broadcast'`` (spill-registered prebuilt build table +
     ``hash_join(prebuilt=)``), or ``'auto'`` (the adaptive layer
@@ -311,14 +315,89 @@ class Exchange(PlanNode):
 
 
 @dataclass(frozen=True)
+class SortOrder(_Signed):
+    """One key of an ordering with its direction.  ``nulls_first=None`` is
+    Spark's default for the direction: nulls first ascending, last
+    descending."""
+
+    name: str
+    ascending: bool = True
+    nulls_first: Optional[bool] = None
+
+    def resolved_nulls_first(self) -> bool:
+        return self.ascending if self.nulls_first is None \
+            else bool(self.nulls_first)
+
+    def describe(self) -> str:
+        return (f"{self.name} {'asc' if self.ascending else 'desc'} nulls "
+                f"{'first' if self.resolved_nulls_first() else 'last'}")
+
+
+def Desc(name: str, nulls_first: Optional[bool] = None) -> SortOrder:
+    """``name`` descending (nulls last unless said otherwise)."""
+    return SortOrder(name, False, nulls_first)
+
+
+def _orders(keys) -> tuple:
+    """Keys as given, a bare name left a bare name (so that an ascending
+    plan's signature stays what it was)."""
+    return tuple(k if isinstance(k, SortOrder) else str(k) for k in keys)
+
+
+def _order_signature(keys) -> tuple:
+    return tuple(k.signature() if isinstance(k, SortOrder) else k
+                 for k in keys)
+
+
+def _as_orders(keys) -> tuple:
+    """Every key as a :class:`SortOrder`."""
+    return tuple(k if isinstance(k, SortOrder) else SortOrder(k)
+                 for k in keys)
+
+
+@dataclass(frozen=True)
 class Sort(PlanNode):
-    """Order rows by ``keys`` (ascending, nulls first)."""
+    """Order rows by ``keys``: a name is ascending, nulls first; a
+    :class:`SortOrder` (``Desc(name)``) says its own direction and null
+    placement."""
 
     child: PlanNode
-    keys: Tuple[str, ...]
+    keys: Tuple[object, ...]  # str | SortOrder
 
     def __post_init__(self):
-        object.__setattr__(self, "keys", _tup(self.keys))
+        object.__setattr__(self, "keys", _orders(self.keys))
+
+    def order(self) -> Tuple[SortOrder, ...]:
+        return _as_orders(self.keys)
+
+    def signature(self) -> tuple:
+        return ("Sort", self.child.signature(), _order_signature(self.keys))
+
+
+@dataclass(frozen=True)
+class TopK(PlanNode):
+    """The first ``n`` rows of ``child`` in the order of ``keys`` (Spark's
+    ``TakeOrderedAndProject``: ``ORDER BY ... LIMIT n``).  SQL leaves rows
+    equal in every key unordered, so which of them make the cut is the
+    engine's choice.  The output has ``n`` row slots, the live rows in
+    front."""
+
+    child: PlanNode
+    keys: Tuple[object, ...]  # str | SortOrder
+    n: int
+
+    def __post_init__(self):
+        object.__setattr__(self, "keys", _orders(self.keys))
+        object.__setattr__(self, "n", int(self.n))
+        if self.n < 1:
+            raise ValueError(f"TopK of {self.n} rows")
+
+    def order(self) -> Tuple[SortOrder, ...]:
+        return _as_orders(self.keys)
+
+    def signature(self) -> tuple:
+        return ("TopK", self.child.signature(),
+                _order_signature(self.keys), self.n)
 
 
 def scan_names(plan: PlanNode) -> tuple:

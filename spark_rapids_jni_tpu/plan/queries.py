@@ -9,13 +9,15 @@ proof that new queries are now data, not code: a q9-shaped pipeline
 (multi-join + conditional aggregate) that exists ONLY as IR — there is
 no hand-fused ``_q9_step`` anywhere.  ``tpch_q1_plan`` is TPC-H Q1 as
 Spark SQL types it: expressions in a Project, two low-cardinality keys,
-decimal sums and averages.
+decimal sums and averages.  ``tpch_q3_plan`` is TPC-H Q3 in the physical
+shape Spark gives it: two filtered joins, one feeding the other's build
+side, a three-key group-by and an ordered limit.
 """
 
 from __future__ import annotations
 
-from .ir import (Agg, Aggregate, Col, DateLit, Exchange, Filter, Join,
-                 Project, Scan, Sort)
+from .ir import (Agg, Aggregate, Col, DateLit, Desc, Exchange, Filter, Join,
+                 Project, Scan, Sort, TopK)
 
 # the q9 conditional: high-value orders only (the WHEN net > threshold
 # arm of q9's conditional aggregate, expressed as filter -> row_valid)
@@ -120,3 +122,66 @@ def tpch_q1_plan(delta_days: int = 90) -> Sort:
               Agg("mean", "l_discount", "avg_disc"),
               Agg("count", None, "count_order")),
         domain=TPCH_Q1_DOMAINS, onehot=True), keys)
+
+
+# TPC-H key ranges at scale factor 1: C_CUSTKEY 1..150,000 dense, and
+# O_ORDERKEY sparse (dbgen keeps 8 of every 32 values: 1,500,000 keys
+# up to 6,000,000)
+TPCH_SF1_CUSTKEY_DOMAIN = 150_001
+TPCH_SF1_ORDERKEY_DOMAIN = 6_000_001
+
+
+def tpch_q3_plan(segment_code: int = 1, date_iso: str = "1995-03-15",
+                 custkey_domain: int = TPCH_SF1_CUSTKEY_DOMAIN,
+                 orderkey_domain: int = TPCH_SF1_ORDERKEY_DOMAIN,
+                 limit: int = 10) -> TopK:
+    """TPC-H Q3, the shipping priority query (specification clause 2.4.3,
+    validation parameters SEGMENT = BUILDING, DATE = 1995-03-15)::
+
+        select l_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,
+               o_orderdate, o_shippriority
+        from customer, orders, lineitem
+        where c_mktsegment = 'BUILDING' and c_custkey = o_custkey
+          and l_orderkey = o_orderkey and o_orderdate < date '1995-03-15'
+          and l_shipdate > date '1995-03-15'
+        group by l_orderkey, o_orderdate, o_shippriority
+        order by revenue desc, o_orderdate limit 10
+
+    in the physical shape Spark plans it at scale factor 1 under its
+    defaults.  CUSTOMER filtered on its segment (``segment_code``: the
+    dictionary code of ``c_mktsegment``, alphabetical, BUILDING = 1) and
+    pruned to its key is small enough to broadcast: it joins the filtered
+    ORDERS with no exchange on either side.  That join's rows, pruned to
+    the three columns still read, are the build side, behind an exchange
+    on ``o_orderkey``, of the join with LINEITEM, itself filtered, pruned
+    and exchanged on ``l_orderkey``.  The rows are then partitioned by the
+    first group key, so the aggregate follows with no further exchange,
+    and ``TakeOrderedAndProject`` takes the ten.  The domains are hints
+    the program checks: customer keys are dense, order keys sparse (a
+    quarter of the range is used).  Spark types ``revenue_term``
+    ``decimal(26,4)`` and its sum ``decimal(36,4)``."""
+    date = DateLit(date_iso)
+    customer = Project(
+        Filter(Scan("customer"), "c_mktsegment", "==", int(segment_code)),
+        ("c_custkey",))
+    orders = Project(
+        Join(Filter(Scan("orders"), "o_orderdate", "<", date), customer,
+             "o_custkey", "c_custkey", dense_domain=int(custkey_domain)),
+        ("o_orderkey", "o_orderdate", "o_shippriority"))
+    lineitem = Project(
+        Filter(Scan("lineitem"), "l_shipdate", ">", date),
+        ("l_orderkey", "l_extendedprice", "l_discount"))
+    joined = Join(Exchange(lineitem, "l_orderkey"),
+                  Exchange(orders, "o_orderkey"),
+                  "l_orderkey", "o_orderkey",
+                  dense_domain=int(orderkey_domain))
+    terms = Project(joined, (
+        "l_orderkey", "o_orderdate", "o_shippriority",
+        ("revenue_term", Col("l_extendedprice") * (1 - Col("l_discount")))))
+    revenue = Aggregate(
+        terms, keys=("l_orderkey", "o_orderdate", "o_shippriority"),
+        aggs=(Agg("sum", "revenue_term", "revenue"),))
+    return TopK(
+        Project(revenue, ("l_orderkey", "revenue", "o_orderdate",
+                          "o_shippriority")),
+        (Desc("revenue"), "o_orderdate"), int(limit))

@@ -199,20 +199,6 @@ def _seg_scan_sum(vals, boundary):
     return out
 
 
-def _seg_scan_sum256(vals, boundary):
-    """Segmented running 256-bit sum over uint32[n, 8] limb rows
-    (decimal128 group sums; limb add from :mod:`ops.decimal`)."""
-    from ..ops import decimal as D
-
-    def comb(a, b):
-        av, ab = a
-        bv, bb = b
-        return jnp.where(bb[:, None], bv, D._add(av, bv)), ab | bb
-
-    out, _ = jax.lax.associative_scan(comb, (vals, boundary))
-    return out
-
-
 def _dec128_lt(alo, ahi, blo, bhi):
     """Signed 128-bit a < b over (lo u64, hi u64) pairs."""
     ah = jax.lax.bitcast_convert_type(ahi, jnp.int64)
@@ -391,13 +377,28 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
     key_cols = _canon_keys([batch[k] for k in key_names])
     have_rv = row_valid is not None
     with scope("agg.sortscan_keys"):
-        karr = K.batch_radix_keys(key_cols, equality=True,
-                                  nulls_first=True)
-        if have_rv:
-            occ = row_valid.astype(jnp.bool_)
-            karr = [jnp.where(occ, jnp.uint32(0), jnp.uint32(1))] + [
-                jnp.where(occ, k, jnp.zeros((), k.dtype)) for k in karr
-            ]
+        if assume_grouped:
+            karr = K.batch_radix_keys(key_cols, equality=True,
+                                      nulls_first=True)
+            if have_rv:
+                occ = row_valid.astype(jnp.bool_)
+                karr = [jnp.where(occ, jnp.uint32(0), jnp.uint32(1))] + [
+                    jnp.where(occ, k, jnp.zeros((), k.dtype)) for k in karr
+                ]
+        else:
+            # the sorting path: the same bits laid end to end (the row
+            # flag, each key's null flag and words), so that the sort
+            # compares as few operands as they fill
+            lead = []
+            if have_rv:
+                occ = row_valid.astype(jnp.bool_)
+                lead = [jnp.where(occ, jnp.uint32(0), jnp.uint32(1))]
+            karr = K.packed_radix_keys(key_cols, lead_flags=lead,
+                                       equality=True, nulls_first=True)
+            if have_rv:   # a dead row: the flag's bit and nothing else
+                karr = [jnp.where(occ, k, jnp.uint32(1 << 31 if i == 0
+                                                     else 0))
+                        for i, k in enumerate(karr)]
     iota = jnp.arange(n, dtype=jnp.int32)
 
     agg_cols = []
@@ -446,16 +447,23 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
         sperm = spay = None
     else:
         with scope("agg.sortscan_sort"):
-            res = jax.lax.sort(tuple(karr) + tuple(payload), num_keys=nk,
-                               is_stable=True)
+            # the row id as the last key makes the order total: one
+            # answer, the stable sort's, from the unstable sort (which
+            # the v5e compiler builds in half the time)
+            res = jax.lax.sort(tuple(karr) + tuple(payload),
+                               num_keys=nk + 1, is_stable=False)
         skeys = res[:nk]
         sperm = res[nk]
         spay = res[nk + 1:]
 
     with scope("agg.sortscan_boundary"):
         boundary = ~K.rows_equal_adjacent(skeys)
-        sorted_occ = (skeys[0] == 0) if have_rv \
-            else jnp.ones((n,), jnp.bool_)
+        if not have_rv:
+            sorted_occ = jnp.ones((n,), jnp.bool_)
+        elif assume_grouped:
+            sorted_occ = skeys[0] == 0
+        else:   # the row flag is the packed words' first bit
+            sorted_occ = (skeys[0] >> jnp.uint32(31)) == 0
         num_groups = (boundary & sorted_occ).sum(dtype=jnp.int32)
 
         # last row of each live group: next row starts a new group / is
@@ -509,6 +517,13 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
             return ce - cp
 
         return per_group(read)
+
+    def ends_diff(cs, w):
+        """:func:`at_ends_diff`'s read with one gather: the scan at the
+        group before is the scan at this group's end, moved one slot."""
+        ce = cs[ends[:w]]
+        return ce - jnp.where(iota[:w] == 0, jnp.zeros((), cs.dtype),
+                              jnp.roll(ce, 1))
 
     def in_order(arr):
         """A column's buffer in sorted row order: its own under
@@ -578,7 +593,8 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
 
                 svalid = sorted_valid(spec.column)
                 slimbs = in_order(dcol.limbs)
-                nn_d = at_ends_diff(jnp.cumsum(svalid.astype(jnp.int32)))
+                counts = jnp.cumsum(svalid.astype(jnp.int32))
+                nn_d = per_group(lambda w: ends_diff(counts, w))
                 has_any_d = out_valid & (nn_d > 0)
                 if spec.op in ("min", "max"):
                     if spec.op == "min":  # fill nulls with +max signed 128
@@ -595,10 +611,31 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                             [rlo[ends[:w]], rhi[ends[:w]]], axis=1)),
                         has_any_d, dcol.dtype)
                     continue
-                u = D._from_i128(slimbs)
-                u = jnp.where(svalid[:, None], u, jnp.zeros((), jnp.uint32))
-                run = _seg_scan_sum256(u, boundary)
-                s256 = at_ends(run)
+                # Each 32-bit lane of the two's-complement value is summed
+                # on its own as a 64-bit prefix scan (2^31 rows of < 2^32
+                # stay under 2^63) and read at the group ends; a negative
+                # row's four sign-extension lanes are 2^32 - 1 each, so
+                # the count of negatives stands for them.  The carries are
+                # folded once, on the per-group sums.  (A segmented
+                # 256-bit associative scan gave the same sums; the v5e
+                # compiler had not built it for 2^20 rows after 50
+                # minutes of CPU time and 11 GB: PERF.md section 6, PR 35.)
+                live = jnp.where(svalid[:, None], slimbs,
+                                 jnp.zeros((), jnp.uint64))
+                m32 = jnp.uint64(0xFFFFFFFF)
+                scans = [jnp.cumsum(x) for x in (
+                    live[:, 0] & m32, live[:, 0] >> jnp.uint64(32),
+                    live[:, 1] & m32, live[:, 1] >> jnp.uint64(32))]
+                negatives = jnp.cumsum(
+                    (live[:, 1] >> jnp.uint64(63)).astype(jnp.int32))
+
+                def lane_sums(w):
+                    low4 = [ends_diff(cs, w) for cs in scans]
+                    ext = ends_diff(negatives, w).astype(jnp.uint64) * m32
+                    return _carry_fold_u64_lanes(
+                        jnp.stack(low4 + [ext] * 4, axis=1))
+
+                s256 = per_group(lane_sums)
                 if spec.op == "mean":
                     limbs128, ok, out_t = _decimal_avg(s256, nn_d, dcol.dtype)
                     out[spec.out_name] = Decimal128Column(
@@ -1840,7 +1877,7 @@ def _domain_partials_scatter(batch, key_name, aggs, domain, row_valid=None):
 
             # _from_i128 sign-extends to 256-bit two's complement, so the
             # per-lane sums are already correct mod 2^256 (same argument
-            # as the sort path's _seg_scan_sum256: <= 2^31 rows of
+            # as the sort path's lane prefix scans: <= 2^31 rows of
             # |v| < 2^127 never reach the wrap)
             u = D._from_i128(jnp.where(vvalid[:, None], vcol.limbs,
                                        jnp.zeros((), jnp.uint64)))

@@ -191,6 +191,42 @@ def batch_radix_keys(
     return out
 
 
+def packed_radix_keys(cols: Sequence, *, lead_flags: Sequence = (),
+                      equality: bool, nulls_first: bool = True) -> list:
+    """:func:`batch_radix_keys` with its bits laid end to end: each of
+    ``lead_flags`` (uint32 0/1 arrays, the most significant) and each
+    column's null flag takes one bit, each radix word its 32, and the bit
+    string is cut into uint32 words, the last padded with zeros.  Unsigned
+    lexicographic order and equality of the packed words are those of the
+    unpacked ones, exactly: a three-key (int64, DATE, int32) composite
+    with a row flag is 5 words where it was 8.  What a sort pays for
+    (on the v5e its compile time grows with every key operand: PERF.md
+    section 6, PR 35) follows the word count."""
+    fields = [(f.astype(jnp.uint32), 1) for f in lead_flags]
+    for c in cols:
+        fields.append((null_flag(c, nulls_first), 1))
+        v = c.validity
+        fields.extend(
+            (jnp.where(v, k, jnp.zeros((), k.dtype)), 32)
+            for k in column_radix_keys(c, equality=equality))
+    total = sum(bits for _f, bits in fields)
+    words = [None] * (-(-total // 32))
+
+    def put(i, part):
+        words[i] = part if words[i] is None else words[i] | part
+
+    off = 0
+    for f, bits in fields:
+        i, r = divmod(off, 32)
+        if r + bits <= 32:
+            put(i, f << jnp.uint32(32 - r - bits) if r + bits < 32 else f)
+        else:   # a 32-bit word across two: r > 0
+            put(i, f >> jnp.uint32(r))
+            put(i + 1, f << jnp.uint32(32 - r))
+        off += bits
+    return words
+
+
 def rows_equal_adjacent(key_arrays: Sequence[jax.Array]) -> jax.Array:
     """bool[n]: row i has identical keys to row i-1 (row 0 -> False)."""
     n = key_arrays[0].shape[0]

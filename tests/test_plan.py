@@ -528,11 +528,13 @@ def _masked_plan(name):
         return Sort(j1(fact), ("v", "seg")), ["compact"], None
     if name == "project_under_the_root":
         return Project(j1(fact), ("k", "v", "d1")), ["compact"], None
-    if name == "build_side_join":       # a Join's right child compacts
+    if name == "build_side_join":
+        # a Join's right child hands on a mask too (PR 35): the build
+        # takes ``right_valid`` as the probe takes ``left_valid``
         right = Join(Scan("dim1"), Scan("dimx"), "k", "k",
                      dense_domain="build")
         return (agg(Join(fact, right, "k", "k", dense_domain=_ND)),
-                ["compact", "mask"], lambda c, in1, in2: in1 & (
+                ["mask", "mask"], lambda c, in1, in2: in1 & (
                     c["k"] < _ND // 2))
     raise KeyError(name)
 
