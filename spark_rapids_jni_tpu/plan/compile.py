@@ -848,12 +848,14 @@ def _rows_at(node: ir.PlanNode, inputs: dict) -> Optional[int]:
 
 def _aggregate_heads(plan: ir.PlanNode, inputs: dict,
                      decisions: dict) -> None:
-    """Adds ``"head"`` to the decision of each Aggregate that the sort
-    engine may run (every lowering but the one-hot one: the scatter and
-    domain engines keep it as their fallback): the group slots at which
-    it fetches its result.  With the ``num_groups`` a query returns that
-    tells which branch ran: the head's up to it, the row-wide one past."""
-    from ..relational.aggregate import sortscan_head
+    """Adds ``"head"`` and ``"tiers"`` to the decision of each Aggregate
+    that the sort engine may run (every lowering but the one-hot one: the
+    scatter and domain engines keep it as their fallback): the group
+    slots at which it fetches its result, the narrowest first, short of
+    every row.  With the ``num_groups`` a query returns that tells which
+    branch ran: the narrowest width that holds them (the head's up to
+    ``"head"``), the row-wide one past the last."""
+    from ..relational.aggregate import sortscan_tiers
 
     ai = 0
     for node in plan.walk():
@@ -861,9 +863,12 @@ def _aggregate_heads(plan: ir.PlanNode, inputs: dict,
             continue
         schema = _schema_at(node.child, inputs)
         if schema is None or not _takes_onehot(node, schema):
+            rows = _rows_at(node.child, inputs)
+            tiers = sortscan_tiers(rows)   # ends at ``rows`` where known
             decisions.setdefault(
-                f"aggregate{ai}:{','.join(node.keys)}", {})["head"] = \
-                sortscan_head(_rows_at(node.child, inputs))
+                f"aggregate{ai}:{','.join(node.keys)}", {}).update(
+                    head=tiers[0],
+                    tiers=tuple(w for w in tiers if w != rows))
         ai += 1
 
 

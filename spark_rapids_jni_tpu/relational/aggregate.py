@@ -31,11 +31,13 @@ is neither sorted nor moved) ->
 adjacent-compare boundaries on the sorted key words -> per-agg prefix
 ``cumsum`` (or segmented min/max ``associative_scan``) -> group result =
 scan value at each group's last row minus the previous group's, fetched
-at the compacted group-end positions: at the first
-``min(rows, 4096)`` of them, padded back to the input's rows, unless the
-data has more groups than that (``lax.cond`` on ``num_groups``; a gather
+at the compacted group-end positions: at the first ``w`` of them, padded
+back to the input's rows, for the narrowest ``w`` of a short ladder
+(:func:`sortscan_tiers`: 4096, 65,536, 1,048,576 while they are under the
+row count, then every row) that holds the data's groups
+(``lax.switch`` on how many widths ``num_groups`` exceeds; a gather
 costs by the index, 33-47 ms a buffer of 2^22 on a v5e, PERF.md section
-5, and a result of ten groups needs ten).
+5, and a result of ten groups needs ten, one of 11,000 no 6.0 M).
 
 Output is padded to the input row count with a device ``num_groups``
 scalar (same discipline as :mod:`filter`); groups appear in key-sorted
@@ -342,25 +344,43 @@ def group_by(
 # the sort engine reads its scans.
 _DEFAULT_GROUP_SLOTS = 4096
 
+# Past the head the sort engine's fetch widens by this factor a step, so
+# that it makes at most 16 times the indices the groups need; a finer
+# ladder is a branch more to compile in every fetch of every aggregate.
+# Three widths short of every row: at most four branches.
+_TIER_FACTOR = 16
+_TIER_STEPS = 3
+
 # key column representations whose gathered rows _pad_rows can pad
 _HEAD_KEY_TYPES = (Column, Decimal128Column, StringColumn, DictionaryColumn)
 
 _ROWWIDE_GATHERS = [0]
 
 
-def sortscan_head(num_rows: Optional[int] = None) -> int:
-    """Group slots at which the sort engine fetches its result over
-    ``num_rows`` input rows (None: more than it ever takes); data with
-    more groups takes the branch that fetches at every row."""
+def sortscan_tiers(num_rows: Optional[int] = None) -> tuple:
+    """The ascending widths, in group slots, at which the sort engine may
+    fetch its result over ``num_rows`` input rows: the head, the head
+    times 16 and times 256 while they are under the row count, then every
+    row (None: more rows than any width, so the ladder without its end).
+    Data takes the narrowest width that holds its groups."""
+    ladder = tuple(_DEFAULT_GROUP_SLOTS * _TIER_FACTOR ** i
+                   for i in range(_TIER_STEPS))
     if num_rows is None:
-        return _DEFAULT_GROUP_SLOTS
-    return min(int(num_rows), _DEFAULT_GROUP_SLOTS)
+        return ladder
+    n = int(num_rows)
+    return tuple(w for w in ladder if w < n) + (n,)
+
+
+def sortscan_head(num_rows: Optional[int] = None) -> int:
+    """The first of :func:`sortscan_tiers`: ``min(num_rows, 4096)``."""
+    return sortscan_tiers(num_rows)[0]
 
 
 def rowwide_gathers() -> int:
     """Gathers of one index a row, over inputs of more rows than the
     head, that the sort engine has traced in this process outside the
-    branch that many groups take.  The plan compiler notes a plan's share
+    branches that fetch its scans (the row-wide one among them).  The
+    plan compiler notes a plan's share
     (``plan.plan_cache_metrics()["agg_rowwide_gathers"]``)."""
     return _ROWWIDE_GATHERS[0]
 
@@ -484,25 +504,30 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
 
     # A result of num_groups rows is read at the head of the group slots:
     # the scans stay whole, but what fetches them at the group ends takes
-    # ``head`` indices and pads back to n rows, unless the data has more
-    # groups than that (one scalar compare picks the branch at run time).
-    head = sortscan_head(n)
+    # ``w`` indices and pads back to n rows, for the narrowest width of
+    # the ladder that holds the data's groups (one scalar, the widths
+    # that num_groups exceeds, picks the branch at run time).
+    tiers = sortscan_tiers(n)
+    tier = sum((num_groups > w).astype(jnp.int32) for w in tiers[:-1])
+    tier_scopes = (["agg.sortscan_head"]
+                   + [f"agg.sortscan_tier.{w}" for w in tiers[1:-1]]
+                   + ["agg.sortscan_full"])
 
-    def per_group(read, pad=lambda a: _pad_leading(a, n - head)):
+    def per_group(read, pad=lambda a: _pad_leading(a, n - a.shape[0])):
         """``read(w)``: per-group values at the first ``w`` group slots;
-        padded to the n rows of the result where ``w`` is the head."""
-        if head == n:
+        padded to the n rows of the result where ``w`` is under them."""
+        if len(tiers) == 1:
             return read(n)
 
-        def at_head(_):
-            with scope("agg.sortscan_head"):
-                return pad(read(head))
+        def at(w, name):
+            def fetch(_):
+                with scope(name):
+                    return read(n) if w == n else pad(read(w))
 
-        def in_full(_):
-            with scope("agg.sortscan_full"):
-                return read(n)
+            return fetch
 
-        return jax.lax.cond(num_groups <= head, at_head, in_full, None)
+        return jax.lax.switch(
+            tier, [at(w, name) for w, name in zip(tiers, tier_scopes)], None)
 
     def at_ends(run):
         """A segmented scan's value at each group's last row."""

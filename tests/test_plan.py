@@ -655,7 +655,8 @@ class TestMaskedJoins:
 
 class TestAggregateHeads:
     """An aggregate that the sort engine may run says in its decision the
-    group slots at which it fetches its result, and
+    group slots at which it fetches its result (the head and the widths
+    past it, short of every row), and
     ``plan_cache_metrics()["agg_rowwide_gathers"]`` the gathers of one
     index a row that the newest plan traced makes whatever the data
     holds."""
@@ -697,6 +698,7 @@ class TestAggregateHeads:
         try:
             res, ng = cp(inputs)
             assert cp.decisions["aggregate0:seg"]["head"] == 4096
+            assert cp.decisions["aggregate0:seg"]["tiers"] == (4096,)
             m = plan.plan_cache_metrics()
             assert m["agg_rowwide_gathers"] == want
             assert 0 < int(ng) <= 10
@@ -721,14 +723,16 @@ class TestAggregateHeads:
 
     def test_the_head_follows_the_rows(self):
         """Under 4096 rows the head is every row, and the decision says
-        so; above, 4096."""
+        so; above, 4096 and each wider fetch that is short of every row."""
         import __graft_entry__ as ge
 
-        for rows, head in ((1 << 9, 1 << 9), (1 << 12, 4096),
-                           (1 << 13, 4096)):
+        for rows, head, tiers in ((1 << 9, 1 << 9, ()), (1 << 12, 4096, ()),
+                                  (1 << 13, 4096, (4096,)),
+                                  (1 << 17, 4096, (4096, 65536))):
             fact, dim1, dim2 = ge._q95_batches(rows, seed=3)
             cp = plan.compile_plan(
                 queries.q95_plan(),
                 {"fact": fact, "dim1": dim1, "dim2": dim2})
-            assert cp.decisions["aggregate0:seg"] == {"head": head}
+            assert cp.decisions["aggregate0:seg"] == {"head": head,
+                                                      "tiers": tiers}
             cp.close()

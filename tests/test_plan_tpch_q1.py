@@ -138,10 +138,11 @@ PARENT_DECISIONS = {
     # a join's decision says its output form too (PR 32): q95's joins each
     # feed an exchange and hand on a row mask, a broadcast join compacts.
     # An aggregate the sort engine may run says the group slots at which it
-    # fetches its result (PR 34): all 2^10 rows here, 4096 from there up;
+    # fetches its result (PR 34): all 2^10 rows here, 4096 from there up,
+    # and (PR 36) the wider fetches short of every row, none here;
     # q6's takes the one-hot engine and says nothing
-    "q95_plan": {'adaptive': True, 'join0:k': {'strategy': 'shuffled', 'build_rows': 128, 'output': 'mask'}, 'join1:wh': {'strategy': 'shuffled', 'build_rows': 25, 'output': 'mask'}, 'aggregate0:seg': {'head': 1024}},
-    "q9_plan": {'adaptive': True, 'join0:k': {'strategy': 'broadcast', 'build_rows': 128, 'engine': 'hash', 'output': 'compact'}, 'join1:wh': {'strategy': 'broadcast', 'build_rows': 25, 'engine': 'hash', 'output': 'compact'}, 'aggregate0:seg': {'head': 1024}},
+    "q95_plan": {'adaptive': True, 'join0:k': {'strategy': 'shuffled', 'build_rows': 128, 'output': 'mask'}, 'join1:wh': {'strategy': 'shuffled', 'build_rows': 25, 'output': 'mask'}, 'aggregate0:seg': {'head': 1024, 'tiers': ()}},
+    "q9_plan": {'adaptive': True, 'join0:k': {'strategy': 'broadcast', 'build_rows': 128, 'engine': 'hash', 'output': 'compact'}, 'join1:wh': {'strategy': 'broadcast', 'build_rows': 25, 'engine': 'hash', 'output': 'compact'}, 'aggregate0:seg': {'head': 1024, 'tiers': ()}},
 }
 
 

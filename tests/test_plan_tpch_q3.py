@@ -287,13 +287,17 @@ def test_a_sort_on_other_keys_above_a_key_ordered_aggregate_is_not_elided():
 @pytest.mark.parametrize("mode", ["eager", "jit"])
 @pytest.mark.parametrize("engine", ["auto", "sort"])
 def test_q3_plan_is_the_reference_on_dbgen_tables(monkeypatch, engine, mode):
-    """More groups than the head (the row-wide branch) and more than ten; a
-    third of the customers have no order, most orders no qualifying line."""
+    """More groups than the head and more than ten: eagerly the 1024-slot
+    branch of the ladder, jitted (the ladder cut to its head) the row-wide
+    one; a third of the customers have no order, most orders no qualifying
+    line."""
     monkeypatch.setattr(agg, "_DEFAULT_GROUP_SLOTS", HEAD)
+    if mode == "jit":
+        monkeypatch.setattr(agg, "_TIER_STEPS", 1)
     _engines(engine)
     tables = make_tables(520 if mode == "eager" else 1500, 3)
     g, _ref = groups_of(tables)
-    assert g > HEAD
+    assert HEAD < g <= 16 * HEAD < len(tables["lineitem"]["l_orderkey"])
     ordered = set(tables["orders"]["o_custkey"])
     assert any(c not in ordered for c in tables["customer"]["c_custkey"])
     got, _want, cp = check(tables, mode)
@@ -310,7 +314,8 @@ def test_q3_plan_is_the_reference_on_dbgen_tables(monkeypatch, engine, mode):
                           "o_orderdate asc nulls first"),
         "route": "selection"}
     assert d["aggregate0:l_orderkey,o_orderdate,o_shippriority"] == {
-        "head": HEAD}
+        "head": HEAD,
+        "tiers": (HEAD,) if mode == "jit" else (HEAD, 16 * HEAD)}
     m = plan.plan_cache_metrics()
     assert m["joins_masked"] == 2 and m["joins_compacted"] == 0
     assert m["topk_sorted_rows"] == len(tables["lineitem"]["l_orderkey"])
@@ -495,7 +500,8 @@ def test_a_join_that_is_anothers_build_child_hands_on_a_mask():
 
 
 def test_q3_scopes_start_at_their_own_plan_node(monkeypatch):
-    monkeypatch.setattr(agg, "_DEFAULT_GROUP_SLOTS", HEAD)
+    head = 8   # three widths of these few hundred rows: 8, 128, every row
+    monkeypatch.setattr(agg, "_DEFAULT_GROUP_SLOTS", head)
     _engines("sort")
     tables = make_tables(100, 19)
     inputs = to_batches(tables)
@@ -516,10 +522,12 @@ def test_q3_scopes_start_at_their_own_plan_node(monkeypatch):
             "plan.project.revenue_term/expr.mul_exact",
             "plan.join.o_orderkey/join.gather_right",
             "plan.aggregate.l_orderkey/agg.sortscan_sort"} <= paths
-    assert any(p.startswith("plan.aggregate.l_orderkey/agg.sortscan_reduce/"
-                            "agg.sortscan_full") for p in paths)
-    assert any(p.startswith("plan.aggregate.l_orderkey/agg.sortscan_reduce/"
-                            "agg.sortscan_head") for p in paths)
+    assert 16 * head < len(tables["lineitem"]["l_orderkey"]) < 256 * head
+    for branch in ("agg.sortscan_head", f"agg.sortscan_tier.{16 * head}",
+                   "agg.sortscan_full"):
+        assert any(p.startswith("plan.aggregate.l_orderkey/"
+                                f"agg.sortscan_reduce/{branch}")
+                   for p in paths), branch
 
 
 def test_q3_over_the_serving_runtime_and_the_data_plane():

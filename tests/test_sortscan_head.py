@@ -1,10 +1,12 @@
 """The sort engine's head (``relational/aggregate.py:_group_by_sortscan``):
 a result of ``num_groups`` rows is fetched at the first group slots and
-padded back to the input's rows; data with more groups than the head holds
-takes the row-wide fetch.  Both against plain Python and against each other,
-on either side of ``num_groups = head``."""
+padded back to the input's rows, at the narrowest width of a short ladder
+that holds the groups; data with more groups than the last of them takes
+the row-wide fetch.  Every width against plain Python and against the
+row-wide fetch, on either side of each ``num_groups = width``."""
 
 import math
+import re
 
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,8 @@ from spark_rapids_jni_tpu.relational import AggSpec, group_by
 
 _HEAD = 64          # the head these cases patch in, so that n stays small
 _HEAD_ROWS = 256
+_TIER_HEAD = 8      # ... and one that makes three widths of those rows
+_TIER_WIDTHS = (8, 128, _HEAD_ROWS)
 _HEAD_AGGS = [
     AggSpec("count", None, "n"), AggSpec("count", "v", "nv"),
     AggSpec("sum", "v", "sv"), AggSpec("sum", "f", "sf"),
@@ -139,6 +143,23 @@ def _head_run(monkeypatch, head, batch, rv, grouped, mode):
     return out, int(ng)
 
 
+def _grouped_ints(n, g):
+    """``g`` runs of equal int32 keys over ``n`` live rows with an int64
+    value each; the batch, the runs' first rows after the first, each row's
+    run, the keys and the values."""
+    rng = np.random.default_rng(g)
+    cuts = np.sort(rng.choice(np.arange(1, n), g - 1, replace=False))
+    gid = np.zeros((n,), np.int64)
+    gid[cuts] = 1
+    gid = np.cumsum(gid)
+    keys = rng.permutation(g).astype(np.int32)[gid]
+    v = rng.integers(-(1 << 40), 1 << 40, n)
+    ones = jnp.ones((n,), jnp.bool_)
+    batch = ColumnBatch({"k": Column(jnp.asarray(keys), ones, T.INT32),
+                         "v": Column(jnp.asarray(v), ones, T.INT64)})
+    return batch, cuts, gid, keys, v
+
+
 def _canon(vals):
     return [repr(x) if isinstance(x, float) else x for x in vals]
 
@@ -153,15 +174,17 @@ class TestSortScanHead:
     """The sort engine fetches its scans at the first ``head`` group slots
     and pads back to the input's rows; data with more groups takes the
     row-wide fetch.  Both equal plain Python, column for column, and each
-    other bit for bit, on either side of ``num_groups = head``."""
+    other bit for bit, on either side of ``num_groups = head`` and of
+    every wider fetch's width."""
 
-    def _check(self, monkeypatch, n, g, has_rv, grouped, mode, seed):
+    def _check(self, monkeypatch, n, g, has_rv, grouped, mode, seed,
+               head=_HEAD):
         batch, rv, g, want = _head_case(n, g, has_rv, grouped, seed)
-        out, ng = _head_run(monkeypatch, _HEAD, batch, rv, grouped, mode)
+        out, ng = _head_run(monkeypatch, head, batch, rv, grouped, mode)
         assert ng == g
         for name, vals in want.items():
             assert _canon(out[name].to_pylist()[:g]) == _canon(vals), name
-        if n <= _HEAD:
+        if n <= head:
             return   # the head is every row: one program, by construction
         # what the engine did before it had a head: every fetch row-wide
         ref, ng_ref = _head_run(monkeypatch, n, batch, rv, grouped, mode)
@@ -196,6 +219,83 @@ class TestSortScanHead:
         self._check(monkeypatch, _HEAD - 16, g, has_rv, grouped, mode,
                     seed=17)
 
+    # every combination jitted (four programs, traced once each); eagerly,
+    # where every fetch compiles its branches anew (7 s a case), grouped
+    # rows with dead ones and sorted rows without
+    @pytest.mark.parametrize("g,has_rv,grouped,mode", [
+        pytest.param(w + d, has_rv, grouped, mode, id="-".join([
+            str(w + d), "row_valid" if has_rv else "all_live",
+            "grouped" if grouped else "sorting", mode]))
+        for mode in ("jit", "eager") for has_rv in (True, False)
+        for grouped in (True, False) if mode == "jit" or has_rv == grouped
+        for w in _TIER_WIDTHS[:-1] for d in (-1, 0, 1)])
+    def test_either_side_of_each_width(self, monkeypatch, g, has_rv, grouped,
+                                       mode):
+        """A head of 8 makes three widths of 256 rows (8, 128, every row):
+        a group under, at and over each edge."""
+        from spark_rapids_jni_tpu.relational import aggregate as A
+
+        monkeypatch.setattr(A, "_DEFAULT_GROUP_SLOTS", _TIER_HEAD)
+        assert A.sortscan_tiers(_HEAD_ROWS) == _TIER_WIDTHS
+        self._check(monkeypatch, _HEAD_ROWS, g, has_rv, grouped, mode,
+                    seed=29 + g, head=_TIER_HEAD)
+
+    @pytest.mark.parametrize("rows,want", [
+        (10, (10,)), (4096, (4096,)), (8192, (4096, 8192)),
+        (1 << 22, (4096, 65536, 1048576, 1 << 22)),
+        (6001215, (4096, 65536, 1048576, 6001215)),
+        (None, (4096, 65536, 1048576))])
+    def test_the_widths_follow_the_rows(self, rows, want):
+        from spark_rapids_jni_tpu.relational import aggregate as A
+
+        assert A.sortscan_tiers(rows) == want
+        assert A.sortscan_head(rows) == want[0]
+
+    def test_the_ladder_the_module_ships(self, monkeypatch):
+        """Unpatched, 4097 groups over 131,072 grouped rows: the branch
+        that ran is the 65,536-slot one, and its gathers take 65,536
+        indices."""
+        import jax
+
+        n, g = 1 << 17, 4097
+        batch, cuts, gid, keys, v = _grouped_ints(n, g)
+        taken = []
+        switch = jax.lax.switch
+
+        def noting(index, branches, *operands):
+            taken.append((int(index), len(branches)))
+            return switch(index, branches, *operands)
+
+        def run(b):
+            return group_by(b, ["k"], [AggSpec("sum", "v", "s")],
+                            engine="sort", assume_grouped=True)
+
+        monkeypatch.setattr(jax.lax, "switch", noting)
+        out, ng = run(batch)
+        monkeypatch.undo()
+        # of the widths 4096, 65,536 and every row, the second
+        assert taken and set(taken) == {(1, 3)}
+        assert int(ng) == g
+        assert np.array_equal(np.asarray(out["k"].data)[:g],
+                              keys[np.concatenate([[0], cuts])])
+        assert np.array_equal(
+            np.asarray(out["s"].data)[:g],
+            np.add.reduceat(v, np.concatenate([[0], cuts])))
+        assert np.asarray(out["s"].validity).sum() == g
+        text = jax.jit(run).lower(batch).as_text(debug_info=True)
+        locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+        indices = {}
+        for m in re.finditer(r'"stablehlo\.gather"\(.*-> tensor<(\d+)[x>]'
+                             r'.*loc\((#loc\d+)\)', text):
+            scope = re.search(r"agg\.sortscan_(head|full|tier\.\d+)",
+                              locs.get(m.group(2), ""))
+            if scope:
+                indices.setdefault(scope.group(0), set()).add(
+                    int(m.group(1)))
+        assert indices == {"agg.sortscan_head": {4096},
+                           "agg.sortscan_tier.65536": {65536},
+                           "agg.sortscan_full": {n}}
+
     @pytest.mark.parametrize("g", [10, 4096, 4097])
     def test_the_head_the_module_ships(self, g):
         """4096 slots, unpatched, on 8192 grouped rows: the counts and
@@ -206,16 +306,7 @@ class TestSortScanHead:
         from spark_rapids_jni_tpu.relational import aggregate as A
 
         n = 8192
-        rng = np.random.default_rng(g)
-        cuts = np.sort(rng.choice(np.arange(1, n), g - 1, replace=False))
-        gid = np.zeros((n,), np.int64)
-        gid[cuts] = 1
-        gid = np.cumsum(gid)
-        keys = rng.permutation(g).astype(np.int32)[gid]
-        v = rng.integers(-(1 << 40), 1 << 40, n)
-        ones = jnp.ones((n,), jnp.bool_)
-        batch = ColumnBatch({"k": Column(jnp.asarray(keys), ones, T.INT32),
-                             "v": Column(jnp.asarray(v), ones, T.INT64)})
+        batch, cuts, gid, keys, v = _grouped_ints(n, g)
         before = A.rowwide_gathers()
         out, ng = jax.jit(lambda b: group_by(
             b, ["k"], [AggSpec("count", None, "c"), AggSpec("sum", "v", "s")],

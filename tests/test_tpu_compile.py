@@ -162,17 +162,18 @@ def test_q95_plan_compiles_with_its_dense_joins_as_lookups(one_chip,
     under ``plan.join.*`` only the general branch (the engine that expands
     rows) gathers a column of the fact; the dense branch reads the small
     tables alone.  The fused aggregate reads its grouped rows in place and
-    fetches its scans at the head of the group slots: under
-    ``agg.sortscan_reduce`` only the branch that more than 4096 groups take
-    gathers a row-wide result.  And the chip's compiler takes the
-    program."""
+    fetches its scans at the narrowest of 4096, 65,536 and 1,048,576 group
+    slots that holds the groups: under ``agg.sortscan_reduce`` only the
+    branch that more than 1,048,576 groups take gathers a row-wide result.
+    And the chip's compiler takes the program."""
     lowered, decisions, cfg, mod = _lower_plan("q95-join-agg", one_chip,
                                                monkeypatch)
     rows = mod.rows_per_query(cfg)
     assert rows == 1 << 22
     assert [decisions[k]["output"] for k in ("join0:k", "join1:wh")] \
         == ["mask", "mask"]
-    assert decisions["aggregate0:seg"] == {"head": 4096}
+    tiers = (4096, 65536, 1048576)
+    assert decisions["aggregate0:seg"] == {"head": 4096, "tiers": tiers}
     gathers = _gathers(lowered.as_text(debug_info=True))
     in_joins = [(path, n) for path, n, _out in gathers
                 if "/plan.join." in path]
@@ -188,15 +189,16 @@ def test_q95_plan_compiles_with_its_dense_joins_as_lookups(one_chip,
     in_reduce = [(path, out) for path, _n, out in gathers
                  if "/plan.aggregate.seg/agg.sortscan_reduce/" in path]
     # the key, count(*), the value's non-null count and sum(v): two
-    # buffers each, at the head ...
-    at_head = [out for path, out in in_reduce
-               if "/agg.sortscan_head/" in path]
-    assert len(at_head) == 8 and set(at_head) == {4096}
+    # buffers each, at the head and at each wider slot count ...
+    for w, name in zip(tiers, ("agg.sortscan_head", "agg.sortscan_tier.65536",
+                               "agg.sortscan_tier.1048576")):
+        assert [out for path, out in in_reduce
+                if f"/{name}/" in path] == [w] * 8, name
     # ... the same eight a row wide where the data has more groups ...
     assert [out for path, out in in_reduce
             if "/agg.sortscan_full/" in path] == [rows] * 8
-    # ... and nothing outside the two branches
-    assert len(in_reduce) == 16
+    # ... and nothing outside the branches
+    assert len(in_reduce) == 32
     mem = lowered.compile().memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
 

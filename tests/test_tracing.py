@@ -220,7 +220,8 @@ class TestScopes:
         config.set("join_engine", "sort")       # what auto is on the chip
         config.set("groupby_engine", "sort")
         try:
-            inputs = _q95_inputs()
+            # rows enough for three fetch widths: 4096, 65,536, every row
+            inputs = _q95_inputs(1 << 17)
             cp = plan.compile_plan(queries.q95_plan(), inputs)
             text = cp.fn.lower(inputs, ()).as_text(debug_info=True)
         finally:
@@ -242,9 +243,11 @@ class TestScopes:
             "join.general", "join.probe_keys", "join.build_sort",
             "join.bisect", "keys.bisect_gather", "keys.bisect_compare",
             "join.expand", "join.gather_left",
-            # the sort-scan aggregation over the regrouped rows
+            # the sort-scan aggregation over the regrouped rows, and the
+            # branches that fetch its scans at the group ends
             "agg.sortscan_keys", "agg.sortscan_boundary",
-            "agg.sortscan_reduce"}
+            "agg.sortscan_reduce", "agg.sortscan_head",
+            "agg.sortscan_tier.65536", "agg.sortscan_full"}
         assert want <= got, sorted(want - got)
         # both joins feed an exchange, which takes a scattered mask: no
         # join puts its matches in front, and a left column is gathered

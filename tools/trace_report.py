@@ -8,7 +8,9 @@ Runs the cell once with the profiler on, through the benchmark's own entry
 away, and reads it with the program's converter:
 
 * ``device_by_scope``: device seconds by named scope
-  (``profiler.device_time_by_scope``), and the share outside every scope;
+  (``profiler.device_time_by_scope``; ``--depth 3`` keeps a third scope of
+  each path, which tells ``agg.sortscan_head``, ``agg.sortscan_tier.<width>``
+  and ``agg.sortscan_full`` apart), and the share outside every scope;
 * ``device_ops``: the heaviest device operations (the benchmark's short
   names: opcode and result type) with the scope each runs in;
 * in a served cell, the median ``FrontDoorSession.timeline`` of the window's
@@ -80,13 +82,13 @@ def median_timeline(rows, qs):
             for st in stages}, len(tls)
 
 
-def device_report(xplane, top):
+def device_report(xplane, top, depth):
     from spark_rapids_jni_tpu import profiler
 
     opener = gzip.open if xplane.endswith(".gz") else open
     with opener(xplane, "rb") as f:
         events = profiler.convert_xplane(f.read())
-    by_scope = profiler.device_time_by_scope(events, depth=2)
+    by_scope = profiler.device_time_by_scope(events, depth=depth)
     busy = sum(by_scope.values())
     # the heaviest operations, under the benchmark's names, with their
     # scope: self-times, so a while does not count its body twice
@@ -119,6 +121,9 @@ def main():
     ap.add_argument("--seconds", type=float, default=12.0)
     ap.add_argument("--rows", type=int, default=None, metavar="LOG2")
     ap.add_argument("--top", type=int, default=12)
+    ap.add_argument("--depth", type=int, default=2,
+                    help="scopes of a path that device_by_scope keeps (3 "
+                    "tells the sort engine's fetch branches apart)")
     ap.add_argument("--keep-xplane", default=None, metavar="PATH",
                     help="copy the trace there, gzipped")
     args = ap.parse_args()
@@ -179,7 +184,7 @@ def main():
                     gzip.open(args.keep_xplane, "wb") as dst:
                 shutil.copyfileobj(src, dst)
         if files:
-            report.update(device_report(files[-1], args.top))
+            report.update(device_report(files[-1], args.top, args.depth))
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
     text = json.dumps(report, indent=1)
