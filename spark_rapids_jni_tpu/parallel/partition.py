@@ -48,7 +48,7 @@ _COUNTING_MAX_CELLS = 1 << 25
 
 
 def regroup_order(pid, num_slots: int, engine: str = "auto",
-                  secondary=None):
+                  secondary=None, lead_bits=None):
     """Stable permutation that orders rows by partition id — the local
     leg every shuffle pays before its all-to-all.
 
@@ -75,6 +75,9 @@ def regroup_order(pid, num_slots: int, engine: str = "auto",
     received in key order (Spark's exchange-before-HashAggregate shape,
     fused into ONE row-sized sort).  Secondary operands force the sort
     engine: a counting sort has no within-slot key order.
+    ``lead_bits`` says how few bits the first of them holds (a null flag:
+    1), so that it and ``pid`` make one operand: on the v5e a sort's
+    compile time grows with every key operand (PERF.md section 6).
     """
     import jax
 
@@ -88,10 +91,20 @@ def regroup_order(pid, num_slots: int, engine: str = "auto",
                   and n * num_slots <= _COUNTING_MAX_CELLS else "sort")
     if engine == "sort":
         if secondary is not None:
-            ops = (pid,) + tuple(secondary) + (
-                jnp.arange(n, dtype=jnp.int32),)
-            return jax.lax.sort(ops, num_keys=len(ops) - 1,
-                                is_stable=True)[-1]
+            secondary = tuple(secondary)
+            lead = (pid.astype(jnp.uint32),)
+            if lead_bits is not None and secondary:
+                # the partition id above the first word's few bits: one
+                # sort operand where there were two
+                lead = ((lead[0] << jnp.uint32(lead_bits))
+                        | secondary[0].astype(jnp.uint32),)
+                secondary = secondary[1:]
+            # the row id as the last key makes the order total: the
+            # stable sort's answer from the unstable sort, which the v5e
+            # compiler builds in half the time (as the aggregate's sort)
+            ops = lead + secondary + (jnp.arange(n, dtype=jnp.int32),)
+            return jax.lax.sort(ops, num_keys=len(ops),
+                                is_stable=False)[-1]
         return jnp.argsort(pid, stable=True).astype(jnp.int32)
     if engine != "scatter":
         raise ValueError(f"unknown regroup engine {engine!r}")

@@ -54,6 +54,8 @@ class PlanCache:
         # ... and the row slots its ordered limits put through a sort or
         # a selection
         self.topk_sorted_rows = 0
+        # ... and the row slots its aggregates take in, summed
+        self.agg_input_slots = 0
 
     def note_routes(self, routes) -> None:
         """Count a newly compiled plan's ``route:arithmetic:type``s."""
@@ -80,6 +82,12 @@ class PlanCache:
         put through their selection, 0 for a plan with none."""
         with self._lock:
             self.topk_sorted_rows = int(rows)
+
+    def note_agg_input_slots(self, slots: int) -> None:
+        """A plan was traced: the row slots its aggregates take in,
+        summed over them, whatever share of the slots holds a live row."""
+        with self._lock:
+            self.agg_input_slots = int(slots)
 
     def _capacity(self) -> int:
         if self._maxsize is not None:
@@ -185,6 +193,7 @@ class PlanCache:
                 **self.joins,
                 "agg_rowwide_gathers": self.agg_rowwide_gathers,
                 "topk_sorted_rows": self.topk_sorted_rows,
+                "agg_input_slots": self.agg_input_slots,
                 # int8 slots of the newest one-hot contraction traced
                 "onehot_slots": onehot_slots(),
             }

@@ -5,7 +5,10 @@ The lowering rules are the hand-fused flagship pipelines, factored:
 * Filter -> a row mask carried forward (never a compaction pass); on a
   dictionary-encoded column the predicate evaluates over the d-entry
   dictionary once and pushes down onto codes (``predicate_mask``) —
-  late materialization preserved, no decode under jit.
+  late materialization preserved, no decode under jit.  Above an
+  Aggregate (``HAVING``) the group count in front becomes ``arange <
+  count`` and, with the predicate, a scattered mask; a decimal column
+  compares with an exact literal (``ir.Lit``) at the column's scale.
 * Exchange -> the local shuffle leg (Spark-exact murmur3 pid + stable
   ``regroup_order``), dead rows routed to the trailing
   pseudo-partition so live prefixes survive the permutation.
@@ -24,12 +27,14 @@ The lowering rules are the hand-fused flagship pipelines, factored:
   (adaptive decision) probes a spill-registered prebuilt
   :class:`~spark_rapids_jni_tpu.relational.join.SpillableBuildTable`,
   pinned to the engine the plan decided so eviction-driven rebuilds
-  cannot disagree with the compiled program's traced shapes.  An inner
-  dense-domain join whose consumer takes a scattered row mask (an
-  Exchange, an Aggregate, a Filter, a Join it is the left child of; a
+  cannot disagree with the compiled program's traced shapes.  An inner,
+  semi or anti dense-domain join whose consumer takes a scattered row
+  mask (an Exchange, an Aggregate, a Filter, a Join on either side; a
   Project passes the question up) leaves the left rows where they are
   and hands on ``match`` as the mask (``decisions``: ``"output":
-  "mask"``); at the root or under a Sort it compacts.
+  "mask"``, and ``"how"`` where it is not inner); at the root or under a
+  Sort it compacts.  The build side may be an Aggregate's output, whose
+  group count becomes its ``right_valid``.
 * Aggregate -> ``group_by_onehot`` / ``group_by_domain_or_sort`` /
   general ``group_by`` by exactly the hand paths' dispatch (domain
   hints apply only to plain int keys; string/encoded keys run the
@@ -174,6 +179,8 @@ def _filter_mask(col, op: str, value):
     literal transformed once per frame, bit-identical to
     decode-then-compare, zero decodes on the fast path)."""
     fn = _FILTER_OPS[op]
+    if isinstance(value, ir.Lit):
+        return _decimal_filter_mask(col, op, value)
     if isinstance(value, ir.DateLit):
         value = jnp.int32(value.days)   # what a DATE column holds
     if isinstance(col, PACKED_COLUMNS):
@@ -182,6 +189,33 @@ def _filter_mask(col, op: str, value):
         return predicate_mask(col, lambda d: fn(d.data, value))
     # a comparison with a null is null, and the row goes (as on codes)
     return fn(col.data, value) & col.validity
+
+
+def _decimal_filter_mask(col, op: str, lit: ir.Lit):
+    """``col <op> lit`` for a decimal column in 64- or 128-bit storage:
+    Spark casts both sides to one decimal type, so the literal is brought
+    to the column's scale (an exact multiple of a power of ten) and the
+    unscaled integers are compared, signed."""
+    if col.dtype.kind is not T.Kind.DECIMAL or lit.scale > col.dtype.scale:
+        raise NotImplementedError(
+            f"a filter of {col.dtype!r} against a decimal literal of "
+            f"scale {lit.scale}")
+    unscaled = int(lit.unscaled) * 10 ** (col.dtype.scale - lit.scale)
+    if not isinstance(col, Decimal128Column):
+        if abs(unscaled) >= 1 << 63:
+            raise NotImplementedError("a literal past 64 bits")
+        return _FILTER_OPS[op](col.data.astype(jnp.int64),
+                               jnp.int64(unscaled)) & col.validity
+    from ..relational.aggregate import _dec128_lt
+
+    two = unscaled % (1 << 128)   # two's complement, as the limbs are
+    clo, chi = jnp.uint64(two & (2**64 - 1)), jnp.uint64(two >> 64)
+    lo, hi = col.limbs[:, 0], col.limbs[:, 1]
+    less = _dec128_lt(lo, hi, clo, chi)
+    equal = (lo == clo) & (hi == chi)
+    mask = {"<": less, "<=": less | equal, ">": ~(less | equal),
+            ">=": ~less, "==": equal, "!=": ~equal}[op]
+    return mask & col.validity
 
 
 # ---------------------------------------------------------------------------
@@ -385,7 +419,7 @@ def _derived(node: ir.Project, b: ColumnBatch):
 
 
 def _exchange_local(b: ColumnBatch, key: str, live, partitions: int,
-                    secondary=None) -> ColumnBatch:
+                    secondary=None, lead_bits=None) -> ColumnBatch:
     """The hand paths' ``exchange_local``: dead rows get pseudo-partition
     P (``spark_partition_id``) and the stable regroup sends them LAST,
     so live rows stay compacted in front and an arange<count mask
@@ -396,7 +430,8 @@ def _exchange_local(b: ColumnBatch, key: str, live, partitions: int,
     with profiler.scope("exchange.partition_id"):
         pid = spark_partition_id([b[key]], partitions, live)
     with profiler.scope("exchange.regroup"):
-        order = regroup_order(pid, partitions + 1, secondary=secondary)
+        order = regroup_order(pid, partitions + 1, secondary=secondary,
+                              lead_bits=lead_bits)
     with profiler.scope("exchange.scatter"):
         return ColumnBatch({name: gather_column(col, order)
                             for name, col in zip(b.names, b.columns)})
@@ -428,6 +463,8 @@ class _State:
         self.joins_compacted = 0
         # row slots the plan's ordered limits put through their selection
         self.topk_sorted_rows = 0
+        # row slots the plan's aggregates take in, summed
+        self.agg_input_slots = 0
 
 
 def node_scope(node: ir.PlanNode) -> str:
@@ -464,6 +501,8 @@ def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
         b, live, _pfx = _lower(node.child, env, prebuilts, st)
         with profiler.scope(node_scope(node)):
             mask = _filter_mask(b[node.column], node.op, node.value)
+            # above an Aggregate (HAVING): its groups are in front
+            live = _counted_rows(b, live)
             live = mask if live is None else live & mask
         return b, live, False
 
@@ -473,6 +512,7 @@ def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
 
     if isinstance(node, ir.Exchange):
         b, live, pfx = _lower(node.child, env, prebuilts, st)
+        _no_count(node, live)
         with profiler.scope(node_scope(node)):
             live_arr = (jnp.ones((b.num_rows,), jnp.bool_) if live is None
                         else live)
@@ -500,6 +540,27 @@ def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
     raise TypeError(f"cannot lower {type(node).__name__}")
 
 
+def _is_count(live) -> bool:
+    """From an Aggregate up ``live`` is the scalar count of rows in front."""
+    return live is not None and live.ndim == 0
+
+
+def _counted_rows(b: ColumnBatch, live):
+    """``live`` as a row mask where it is an Aggregate's group count."""
+    if _is_count(live):
+        return jnp.arange(b.num_rows, dtype=jnp.int32) < live
+    return live
+
+
+def _no_count(node: ir.PlanNode, live, side: str = "") -> None:
+    """A node that takes row masks only met an Aggregate's group count."""
+    if _is_count(live):
+        raise TypeError(
+            f"{type(node).__name__} ({node_scope(node)}) cannot take the "
+            f"output of an Aggregate{side}: it hands on a group count, not "
+            "a row mask (a Filter above the Aggregate makes one)")
+
+
 def _sort_keys(node) -> list:
     from ..relational.sort import SortKey
 
@@ -513,7 +574,7 @@ def _lower_sort(node: ir.Sort, env, prebuilts, st):
 
     b, live, _pfx = _lower(node.child, env, prebuilts, st)
     # from an Aggregate: the count of live rows, which are in front
-    count = live if live is not None and live.ndim == 0 else None
+    count = live if _is_count(live) else None
     if count is not None:
         if id(node.child) in st.key_ordered \
                 and node.keys == node.child.keys:
@@ -542,7 +603,7 @@ def _lower_topk(node: ir.TopK, env, prebuilts, st):
     from ..relational.sort import top_k_rows
 
     b, live, _pfx = _lower(node.child, env, prebuilts, st)
-    counted = live is not None and live.ndim == 0
+    counted = _is_count(live)
     rows = b.num_rows
     st.topk_sorted_rows += rows
     with profiler.scope(node_scope(node)):
@@ -560,7 +621,10 @@ def _lower_join(node: ir.Join, env, prebuilts, st):
     from ..relational.join import hash_join, join_dense_or_hash
 
     b, live, _pfx = _lower(node.child, env, prebuilts, st)
+    _no_count(node, live, " as its probe side")
     rb, rlive, _rpfx = _lower(node.right, env, prebuilts, st)
+    # an Aggregate's output as the build side: its groups are in front
+    rlive = _counted_rows(rb, rlive)
     info = st.join_plans[st.join_i]
     st.join_i += 1
 
@@ -619,6 +683,8 @@ def _lower_aggregate(node: ir.Aggregate, env, prebuilts, st):
         # (as the regroup that orders its rows, or not at all)
         b, live, pfx = _lower(child.child if fuse else child, env,
                               prebuilts, st)
+    _no_count(node, live)
+    st.agg_input_slots += b.num_rows
     with profiler.scope(node_scope(node)):
         return _aggregate(node, aggs, hint, child if fuse else None,
                           b, live, pfx, derive, st)
@@ -655,8 +721,10 @@ def _aggregate(node: ir.Aggregate, aggs, hint, fused, b, live, pfx,
                                            nulls_first=True)
             live_arr = (jnp.ones((b.num_rows,), jnp.bool_) if live is None
                         else live)
+            # (the first of the key's words is its null flag: one bit)
             staged = _exchange_local(b, fused.key, live_arr,
-                                     fused.partitions, secondary=segkeys)
+                                     fused.partitions, secondary=segkeys,
+                                     lead_bits=1)
             if live is not None and not pfx:
                 live = jnp.arange(staged.num_rows, dtype=jnp.int32) < \
                     jnp.sum(live.astype(jnp.int32))
@@ -802,14 +870,19 @@ def _dense_domain(node: ir.Join, inputs: dict):
     return dense
 
 
+# joins whose output, compacted or not, has the left side's row slots
+_ROW_KEEPING_JOINS = ("inner", "semi", "anti")
+
+
 def _join_outputs(plan: ir.PlanNode, inputs: dict, decisions: dict) -> None:
     """Adds to each join's decision its output form: ``"mask"`` where the
-    join is an inner, shuffled one over a dense domain and what consumes
-    its rows takes a scattered row mask (an Exchange, an Aggregate, a
-    Filter, a Join on either side: the build takes ``right_valid`` as the
-    probe takes ``left_valid``; a Project hands its own consumer's answer
-    down), so that it need not put the matches in front; ``"compact"`` at
-    the root, under a Sort or TopK and everywhere else."""
+    join is an inner, semi or anti, shuffled one over a dense domain and
+    what consumes its rows takes a scattered row mask (an Exchange, an
+    Aggregate, a Filter, a Join on either side: the build takes
+    ``right_valid`` as the probe takes ``left_valid``; a Project hands its
+    own consumer's answer down), so that it need not put the matches in
+    front; ``"compact"`` at the root, under a Sort or TopK and everywhere
+    else.  A join that is not inner also says its ``"how"``."""
     joins = []   # (Join, its consumer takes a mask), in walk order
 
     def visit(node, masked):
@@ -828,19 +901,24 @@ def _join_outputs(plan: ir.PlanNode, inputs: dict, decisions: dict) -> None:
     for ji, (node, masked) in enumerate(joins):
         d = decisions[f"join{ji}:{node.left_on}"]
         d["output"] = "mask" if (
-            masked and node.how == "inner" and d["strategy"] == "shuffled"
+            masked and node.how in _ROW_KEEPING_JOINS
+            and d["strategy"] == "shuffled"
             and _dense_domain(node, inputs) is not None) else "compact"
+        if node.how != "inner":
+            d["how"] = node.how
 
 
 def _rows_at(node: ir.PlanNode, inputs: dict) -> Optional[int]:
     """Rows of ``node``'s output as it is lowered, where the plan and the
     inputs tell: every node but an Aggregate hands on as many rows as its
-    (left) child has, an inner join within that budget."""
+    (left) child has, an inner join within that budget, a semi or anti
+    join the left rows it keeps."""
     while not isinstance(node, ir.Scan):
         if isinstance(node, ir.TopK):
             return node.n
         if isinstance(node, ir.Aggregate) or (
-                isinstance(node, ir.Join) and node.how != "inner"):
+                isinstance(node, ir.Join)
+                and node.how not in _ROW_KEEPING_JOINS):
             return None
         node = node.child
     return getattr(inputs.get(node.name), "num_rows", None)
@@ -933,6 +1011,7 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
                 get_plan_cache().note_rowwide_gathers(
                     rowwide_gathers() - rowwide)
                 get_plan_cache().note_topk_rows(st.topk_sorted_rows)
+                get_plan_cache().note_agg_input_slots(st.agg_input_slots)
                 # from an Aggregate up ``live`` is the group count
                 return batch if live is None else (batch, live)
 

@@ -169,6 +169,7 @@ class Filter(PlanNode):
 
     Lowered as a row mask carried to the next mask consumer (group-by
     ``row_valid`` / join ``left_valid``) — never as a compaction pass.
+    Above an :class:`Aggregate` it is SQL's ``HAVING``.
     On a dictionary-encoded column the predicate evaluates over the
     d-entry dictionary once and pushes down onto codes
     (``predicate_mask``).
@@ -177,7 +178,9 @@ class Filter(PlanNode):
     child: PlanNode
     column: str
     op: str
-    value: object  # hashable scalar literal, or a DateLit
+    # a hashable scalar literal, a DateLit, or a Lit: an exact decimal
+    # compared with a decimal column at the column's scale
+    value: object
 
     def __post_init__(self):
         if self.op not in FILTER_OPS:
@@ -224,7 +227,10 @@ class Join(PlanNode):
     may be a sparse key range far wider than the build side (TPC-H order
     keys take 8 of every 32 values: 1,500,000 keys below 6,000,001); the
     program checks what it assumes at run time and takes the general
-    engine where the data says otherwise.  ``strategy``
+    engine where the data says otherwise.  ``how='semi'`` / ``'anti'``
+    (an ``IN`` / ``NOT EXISTS`` subquery as Spark plans one) keep the left
+    rows whose key is, or is not, on the build side, and hand on no right
+    column; over a dense domain that is one lookup a row.  ``strategy``
     picks the physical form: ``'shuffled'`` (the hand-q95 lowering),
     ``'broadcast'`` (spill-registered prebuilt build table +
     ``hash_join(prebuilt=)``), or ``'auto'`` (the adaptive layer

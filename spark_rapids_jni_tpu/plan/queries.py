@@ -11,13 +11,15 @@ no hand-fused ``_q9_step`` anywhere.  ``tpch_q1_plan`` is TPC-H Q1 as
 Spark SQL types it: expressions in a Project, two low-cardinality keys,
 decimal sums and averages.  ``tpch_q3_plan`` is TPC-H Q3 in the physical
 shape Spark gives it: two filtered joins, one feeding the other's build
-side, a three-key group-by and an ordered limit.
+side, a three-key group-by and an ordered limit.  ``tpch_q18_plan`` is
+TPC-H Q18: a group-by of one group an order under a ``HAVING``, the ``IN``
+subquery as a semi-join, two more joins, a second group-by and a top-100.
 """
 
 from __future__ import annotations
 
 from .ir import (Agg, Aggregate, Col, DateLit, Desc, Exchange, Filter, Join,
-                 Project, Scan, Sort, TopK)
+                 Lit, Project, Scan, Sort, TopK)
 
 # the q9 conditional: high-value orders only (the WHEN net > threshold
 # arm of q9's conditional aggregate, expressed as filter -> row_valid)
@@ -185,3 +187,66 @@ def tpch_q3_plan(segment_code: int = 1, date_iso: str = "1995-03-15",
         Project(revenue, ("l_orderkey", "revenue", "o_orderdate",
                           "o_shippriority")),
         (Desc("revenue"), "o_orderdate"), int(limit))
+
+
+def tpch_q18_plan(quantity: int = 300,
+                  custkey_domain: int = TPCH_SF1_CUSTKEY_DOMAIN,
+                  orderkey_domain: int = TPCH_SF1_ORDERKEY_DOMAIN,
+                  limit: int = 100) -> TopK:
+    """TPC-H Q18, the large volume customer query (specification clause
+    2.4.18, validation parameter QUANTITY = 300)::
+
+        select c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice,
+               sum(l_quantity)
+        from customer, orders, lineitem
+        where o_orderkey in (select l_orderkey from lineitem
+                             group by l_orderkey
+                             having sum(l_quantity) > 300)
+          and c_custkey = o_custkey and o_orderkey = l_orderkey
+        group by c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+        order by o_totalprice desc, o_orderdate limit 100
+
+    in the physical shape Spark plans it at scale factor 1.  The subquery
+    is an aggregate of one group an order (1,500,000 at SF1) behind an
+    exchange on its key, the ``HAVING`` a Filter above it (the sum is
+    ``decimal(22,2)``; Spark casts the literal to it), and the ``IN`` a
+    left semi join of ORDERS with its keys over the sparse order-key
+    domain.  The orders that pass join CUSTOMER (pruned to its key) on the
+    dense customer-key domain; those rows, pruned and exchanged on
+    ``o_orderkey``, are the build side of the join with LINEITEM, itself
+    pruned and exchanged on ``l_orderkey``.  The rows are then partitioned
+    by a group key, so the second aggregate follows with no further
+    exchange, and ``TakeOrderedAndProject`` takes the hundred.  The
+    subquery is lowered once and used once: the semi join that Spark also
+    infers on LINEITEM, over a reused exchange, is left out.
+
+    ``c_name`` is the text ``Customer#`` and ``c_custkey`` in nine digits
+    (clause 4.2.3), so ``c_custkey`` determines it: the plan groups on
+    ``c_custkey`` and whoever presents the hundred rows writes the name
+    from the key.  The build side's ``c_custkey`` is ``o_custkey`` under
+    the join's other name, and the group key ``o_orderkey`` the probe
+    side's ``l_orderkey``: an equality join keeps one of each pair."""
+    lines = Project(Scan("lineitem"), ("l_orderkey", "l_quantity"))
+    per_order = Aggregate(Exchange(lines, "l_orderkey"),
+                          keys=("l_orderkey",),
+                          aggs=(Agg("sum", "l_quantity", "sum_qty"),))
+    large = Project(Filter(per_order, "sum_qty", ">", Lit(int(quantity))),
+                    ("l_orderkey",))
+    orders = Join(Scan("orders"), large, "o_orderkey", "l_orderkey",
+                  how="semi", dense_domain=int(orderkey_domain))
+    customers = Join(orders, Project(Scan("customer"), ("c_custkey",)),
+                     "o_custkey", "c_custkey",
+                     dense_domain=int(custkey_domain))
+    build = Project(customers, ("o_orderkey", ("c_custkey", Col("o_custkey")),
+                                "o_orderdate", "o_totalprice"))
+    joined = Join(Exchange(lines, "l_orderkey"),
+                  Exchange(build, "o_orderkey"),
+                  "l_orderkey", "o_orderkey",
+                  dense_domain=int(orderkey_domain))
+    keys = ("c_custkey", "o_orderkey", "o_orderdate", "o_totalprice")
+    volume = Aggregate(
+        Project(joined, ("c_custkey", ("o_orderkey", Col("l_orderkey")),
+                         "o_orderdate", "o_totalprice", "l_quantity")),
+        keys=keys, aggs=(Agg("sum", "l_quantity", "sum_qty"),))
+    return TopK(Project(volume, keys + ("sum_qty",)),
+                (Desc("o_totalprice"), "o_orderdate"), int(limit))

@@ -351,6 +351,14 @@ _DEFAULT_GROUP_SLOTS = 4096
 _TIER_FACTOR = 16
 _TIER_STEPS = 3
 
+# The sorting path's keys are the key columns' bits laid end to end
+# (``packed_radix_keys``).  Past this many words the sort takes the spans
+# of the live values instead (``span_packed_keys``), in this many words:
+# the v5e compiler takes 40-60 s for every key operand of a sort (nine:
+# 350-530 s; four: 120 s; PERF.md section 6, PR 37).
+_WIDE_KEY_WORDS = 5
+_SPAN_KEY_WORDS = 3
+
 # key column representations whose gathered rows _pad_rows can pad
 _HEAD_KEY_TYPES = (Column, Decimal128Column, StringColumn, DictionaryColumn)
 
@@ -388,6 +396,53 @@ def rowwide_gathers() -> int:
 def _note_rowwide_gather(n: int) -> None:
     if n > sortscan_head(n):
         _ROWWIDE_GATHERS[0] += 1
+
+
+def _sort_wide_keys(karr, narrow, occ):
+    """The sorting path's sort for keys of many words: ``(sorted words,
+    permutation)`` with the order of ``lax.sort(karr + [row id])``, rows of
+    equal keys adjacent and equal in every sorted word, a dead row's first
+    bit set.  ``narrow`` is :func:`keys.span_packed_keys`' answer: where
+    the live keys' spans fit its few words (TPC-H Q18's four keys, 229
+    bits by their types, span 84) those and the row id are the sort's only
+    operands.  Where they do not, the type-wide words ``karr`` are sorted
+    least significant first by one stable two-operand sort inside a loop,
+    which costs a gather and a sort a word at run time and next to nothing
+    to compile; the answer is the same."""
+    packed, fits = narrow
+    n = packed[0].shape[0]
+    iota = jnp.arange(n, dtype=jnp.int32)
+    if occ is not None:   # a dead row: the flag's bit and nothing else
+        packed = [jnp.where(occ, k, jnp.uint32(1 << 31 if i == 0 else 0))
+                  for i, k in enumerate(packed)]
+
+    def by_spans(_):
+        with scope("agg.sortscan_sort"):
+            return tuple(jax.lax.sort(tuple(packed) + (iota,),
+                                      num_keys=len(packed) + 1,
+                                      is_stable=False))
+
+    def by_words(_):
+        with scope("agg.sortscan_sort_passes"):
+            stack = jnp.stack(karr)
+
+            def one_pass(i, perm):
+                word = jax.lax.dynamic_index_in_dim(
+                    stack, len(karr) - 1 - i, axis=0, keepdims=False)
+                return jax.lax.sort((word[perm], perm), num_keys=1,
+                                    is_stable=True)[1]
+
+            perm = jax.lax.fori_loop(0, len(karr), one_pass, iota)
+            in_order = [k[perm] for k in karr]
+            run = jnp.cumsum((~K.rows_equal_adjacent(in_order))
+                             .astype(jnp.uint32))
+            # the row flag, then each run of equal keys under its ordinal
+            return ((in_order[0] & jnp.uint32(1 << 31), run)
+                    + (jnp.zeros((n,), jnp.uint32),) * (len(packed) - 2)
+                    + (perm,))
+
+    res = jax.lax.cond(fits, by_spans, by_words, None)
+    return res[:-1], res[-1]
 
 
 def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
@@ -458,7 +513,18 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
             payload.extend([col.data, col.validity])
 
     nk = len(karr)
-    if assume_grouped:
+    narrow = None
+    if not assume_grouped and not ride and n > 0 and nk > _WIDE_KEY_WORDS:
+        with scope("agg.sortscan_keys"):
+            narrow = K.span_packed_keys(
+                key_cols, lead_flags=lead,
+                live=occ if have_rv else jnp.ones((n,), jnp.bool_),
+                words=_SPAN_KEY_WORDS, equality=True, nulls_first=True)
+    if narrow is not None:
+        skeys, sperm = _sort_wide_keys(karr, narrow,
+                                       occ if have_rv else None)
+        nk, spay = len(skeys), ()
+    elif assume_grouped:
         # sort-order reuse: an upstream stage already laid equal keys out
         # adjacently (dead rows in one trailing run), so the boundary
         # scan below works on input order directly and the whole sort —
@@ -545,10 +611,25 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
 
     def ends_diff(cs, w):
         """:func:`at_ends_diff`'s read with one gather: the scan at the
-        group before is the scan at this group's end, moved one slot."""
+        group before is the scan at this group's end, moved one slot.
+        A 64-bit scan is moved and subtracted as its 32-bit halves, with
+        the borrow: moved whole, the v5e compiler's program lost the high
+        half of the scan at the group before at one slot in every 79,872
+        (sums off by multiples of 2^32 once the scan had passed 2^32:
+        PERF.md section 6, PR 37; the ``tpch-q18.served`` cell holds it)."""
         ce = cs[ends[:w]]
-        return ce - jnp.where(iota[:w] == 0, jnp.zeros((), cs.dtype),
-                              jnp.roll(ce, 1))
+        first = iota[:w] == 0
+
+        def before(x):
+            return jnp.where(first, jnp.zeros((), x.dtype), jnp.roll(x, 1))
+
+        if ce.dtype.itemsize < 8:
+            return ce - before(ce)
+        hi, lo = K._split64(ce.astype(jnp.uint64))
+        hi_b, lo_b = before(hi), before(lo)
+        d_hi = hi - hi_b - (lo < lo_b).astype(jnp.uint32)
+        return ((d_hi.astype(jnp.uint64) << jnp.uint64(32))
+                | (lo - lo_b).astype(jnp.uint64)).astype(cs.dtype)
 
     def in_order(arr):
         """A column's buffer in sorted row order: its own under

@@ -52,6 +52,8 @@ from . import keys as K
 from .filter import compact
 from .gather import gather_batch
 
+_compact_rows = compact   # where a parameter is called ``compact``
+
 _HOWS = ("inner", "left", "right", "full", "semi", "anti")
 
 
@@ -69,6 +71,18 @@ def _resolve_join_engine(engine):
         raise ValueError(f"unknown join engine {engine!r} "
                          "(use 'auto', 'sort', 'hash', or 'pallas')")
     return engine
+
+
+def _sort_build_keys(rkeys, nr) -> tuple:
+    """The sort engine's build product: the build side's radix words in
+    order, and last the permutation that orders them.  The row id as the
+    last key makes the order total, so the unstable sort gives the stable
+    sort's answer (rows of one key in original order), and the v5e
+    compiler builds it in three quarters of the time (PERF.md section 6,
+    PR 37)."""
+    return tuple(jax.lax.sort(
+        tuple(rkeys) + (jnp.arange(nr, dtype=jnp.int32),),
+        num_keys=len(rkeys) + 1, is_stable=False))
 
 
 def _hash_build(rkeys, nr, table_engine: str = "lax"):
@@ -317,11 +331,7 @@ def hash_join(
             with scope("join.build_sort"):
                 rkeys = K.batch_radix_keys(rcols, equality=True,
                                            nulls_first=False)
-                iota_r = jnp.arange(nr, dtype=jnp.int32)
-                sorted_ops = jax.lax.sort(
-                    tuple(rkeys) + (iota_r,), num_keys=len(rkeys),
-                    is_stable=True
-                )
+                sorted_ops = _sort_build_keys(rkeys, nr)
             sorted_rkeys, rperm = sorted_ops[:-1], sorted_ops[-1]
         with scope("join.bisect"):
             lo, hi = K.equal_range(sorted_rkeys, lkeys)
@@ -463,10 +473,19 @@ def join_dense_or_hash(
     compacted in left-row order, ``(result, count)``, ``count >
     capacity`` = truncation) is bit-identical between branches.
 
-    Only single-int-key inner joins take the dense path; anything else
-    delegates to :func:`hash_join` outright.  Measured r5 on the q95
-    shape (64K fact x 8K dim, 1-core XLA-CPU): the general engine's
-    per-join cost is dominated by the build sort that this path skips.
+    Only single-int-key inner, semi and anti joins take the dense path;
+    anything else delegates to :func:`hash_join` outright.  Measured r5
+    on the q95 shape (64K fact x 8K dim, 1-core XLA-CPU): the general
+    engine's per-join cost is dominated by the build sort that this path
+    skips.
+
+    ``semi`` / ``anti`` (an ``IN`` / ``NOT EXISTS`` subquery as Spark
+    plans it) ask only whether a key is there: the dense branch is
+    ``present[key]`` and nothing else (no rowid table, no gather of a
+    right column, build keys may repeat), the output the left side's
+    columns and rows (a ``capacity`` is not looked at, as in
+    :func:`hash_join`); an anti join keeps a live left row whose key is
+    null or outside the domain, since it matches nothing.
 
     ``compact=False`` (only without a ``capacity``, so the output has the
     left side's rows) returns ``(result, live)`` with ``live`` a
@@ -481,7 +500,8 @@ def join_dense_or_hash(
         raise ValueError("compact=False keeps the left side's rows: it "
                          "takes no capacity")
     lcol, rcol = left[left_on], right[right_on]
-    eligible = (how == "inner" and domain > 0
+    presence = how in ("semi", "anti")   # is the key there: nothing fetched
+    eligible = ((how == "inner" or presence) and domain > 0
                 and not isinstance(lcol, (StringColumn, Decimal128Column,
                                           DictionaryColumn, RunLengthColumn,
                                           BitPackedColumn,
@@ -522,12 +542,29 @@ def join_dense_or_hash(
             jnp.all((rk.astype(rcol.data.dtype) == rcol.data) | ~r_live)
             & jnp.all((lk32.astype(lcol.data.dtype) == lcol.data)
                       | ~(lcol.validity & lv_pre)))
-        dense_ok = (jnp.all(in_dom | ~r_live) & jnp.all(cnt[:K1] <= 1)
-                    & no_wrap)
+        # a rowid table holds one row a key; presence lets keys repeat
+        unique = True if presence else jnp.all(cnt[:K1] <= 1)
+        dense_ok = jnp.all(in_dom | ~r_live) & unique & no_wrap
 
-    right_sel = right.select([n for n in right.names if n != right_on])
+    right_sel = ColumnBatch({}) if presence else right.select(
+        [n for n in right.names if n != right_on])
+
+    def dense_presence():
+        """The semi/anti lookup: the left rows kept, where they are."""
+        with scope("join.dense_semi"):
+            lk = lcol.data.astype(jnp.int32)
+            lk_ok = lcol.validity & lv_pre & (lk >= 0) & (lk < K1)
+            found = lk_ok & (cnt[:K1] > 0)[jnp.where(lk_ok, lk, 0)]
+            keep = found if how == "semi" else lv_pre & ~found
+        if not compact:
+            # the left columns are taken after the cond: zeros stand in
+            return jax.tree_util.tree_map(jnp.zeros_like, left), keep
+        with scope("join.dense_compact"):
+            return _compact_rows(left, keep)
 
     def dense(_):
+        if presence:
+            return dense_presence()
         with scope("join.dense_build"):
             rowid = jnp.zeros((K1 + 1,), jnp.int32).at[slot].set(
                 jnp.arange(nr, dtype=jnp.int32))
@@ -567,7 +604,7 @@ def join_dense_or_hash(
     def general(_):
         with scope("join.general"):
             out, total = hash_join(left, right, [left_on], [right_on],
-                                   "inner", capacity=cap, suffixes=suffixes,
+                                   how, capacity=cap, suffixes=suffixes,
                                    left_valid=left_valid,
                                    right_valid=right_valid)
             return out, (total if compact else _prefix_live(out, total))
@@ -714,9 +751,7 @@ def spillable_build_table(right: ColumnBatch, right_on: Sequence[str],
         if eng in ("hash", "pallas"):
             return eng, _hash_build(rkeys, nr,
                                     "pallas" if eng == "pallas" else "lax")
-        iota_r = jnp.arange(nr, dtype=jnp.int32)
-        return eng, tuple(jax.lax.sort(
-            tuple(rkeys) + (iota_r,), num_keys=len(rkeys), is_stable=True))
+        return eng, _sort_build_keys(rkeys, nr)
 
     return SpillableBuildTable(builder, ctx=ctx, name=name)
 

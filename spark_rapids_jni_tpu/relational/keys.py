@@ -137,6 +137,10 @@ def column_radix_keys(col, *, equality: bool = False) -> list:
 
     kind = col.dtype.kind
     d = col.data
+    if kind is T.Kind.DECIMAL:
+        # a decimal in 32- or 64-bit storage: its unscaled integer (values
+        # of one column share a scale, so their order is the values')
+        kind = T.Kind.INT32 if d.dtype.itemsize <= 4 else T.Kind.INT64
     if kind is T.Kind.BOOLEAN:
         return [d.astype(jnp.uint32)]
     if kind in (T.Kind.INT8, T.Kind.INT16, T.Kind.INT32, T.Kind.DATE):
@@ -225,6 +229,78 @@ def packed_radix_keys(cols: Sequence, *, lead_flags: Sequence = (),
             put(i + 1, f << jnp.uint32(32 - r))
         off += bits
     return words
+
+
+def _place_bits(acc_hi, acc_lo, field, shift):
+    """``field`` (uint64 rows, a scalar ``shift`` in 0..127 that need not
+    be static) or-ed into a 128-bit string held as two uint64 limbs."""
+    s = jnp.clip(jnp.asarray(shift, jnp.int32), 0, 127).astype(jnp.uint64)
+    low = s < jnp.uint64(64)
+    m63 = jnp.uint64(63)
+    zero = jnp.zeros((), jnp.uint64)
+    # no shift by 64 or more is ever asked of the hardware
+    over = jnp.where(s == zero, zero, field >> ((jnp.uint64(64) - s) & m63))
+    return (acc_hi | jnp.where(low, over, field << ((s - jnp.uint64(64)) & m63)),
+            acc_lo | jnp.where(low, field << (s & m63), zero))
+
+
+def span_packed_keys(cols: Sequence, *, lead_flags: Sequence = (), live,
+                     words: int, equality: bool, nulls_first: bool = True):
+    """:func:`packed_radix_keys` with each column's value cut to the bits
+    that the values of its ``live`` rows span: ``value - least value`` in as
+    many bits as ``greatest - least`` has, found on the device, so that
+    four keys of a few million distinct values each fill three words where
+    their types fill eight.  Returns ``(packed, fits)``: ``words`` uint32
+    arrays whose unsigned lexicographic order and equality among live rows
+    are those of the unpacked keys, exactly, wherever the scalar ``fits``
+    is true (the flags and spans together have at most ``32 * words``
+    bits); where it is false they mean nothing and the caller sorts the
+    type-wide words instead.  ``None`` where a column lowers to more than
+    two radix words (a wide decimal, a string): no span is taken of
+    those.  A sort's compile time on the v5e grows with every key operand
+    (PERF.md section 6, PR 37); a dead row's words are whatever its
+    buffers hold and the caller overwrites them."""
+    if not 1 <= words <= 4:
+        raise ValueError(f"{words} words: the string has 128 bits")
+    lowered = [column_radix_keys(c, equality=equality) for c in cols]
+    if any(len(ws) > 2 for ws in lowered):
+        return None
+    n = live.shape[0]
+    u64 = jnp.uint64
+    acc_hi = jnp.zeros((n,), u64)
+    acc_lo = jnp.zeros((n,), u64)
+    used = jnp.int32(0)    # bits taken so far, from the top of the string
+    for f in lead_flags:
+        acc_hi, acc_lo = _place_bits(acc_hi, acc_lo, f.astype(u64),
+                                     127 - used)
+        used = used + 1
+    top = jnp.uint32(0xFFFFFFFF)
+    for c, ws in zip(cols, lowered):
+        acc_hi, acc_lo = _place_bits(
+            acc_hi, acc_lo, null_flag(c, nulls_first).astype(u64),
+            127 - used)
+        used = used + 1
+        ok = c.validity & live
+        hi = ws[0] if len(ws) == 2 else jnp.zeros((n,), jnp.uint32)
+        lo = ws[-1]
+        # least and greatest value over the rows that count, a word at a
+        # time (32-bit reductions only)
+        mn_hi = jnp.min(jnp.where(ok, hi, top))
+        mn_lo = jnp.min(jnp.where(ok & (hi == mn_hi), lo, top))
+        mx_hi = jnp.max(jnp.where(ok, hi, jnp.uint32(0)))
+        mx_lo = jnp.max(jnp.where(ok & (hi == mx_hi), lo, jnp.uint32(0)))
+        least = (mn_hi.astype(u64) << u64(32)) | mn_lo.astype(u64)
+        most = (mx_hi.astype(u64) << u64(32)) | mx_lo.astype(u64)
+        span = jnp.where(most >= least, most - least, u64(0))  # none counts
+        bits = jnp.sum((span >> jnp.arange(64, dtype=u64)) != u64(0),
+                       dtype=jnp.int32)
+        value = (hi.astype(u64) << u64(32)) | lo.astype(u64)
+        field = jnp.where(ok, value - least, u64(0))
+        acc_hi, acc_lo = _place_bits(acc_hi, acc_lo, field,
+                                     128 - used - bits)
+        used = used + bits
+    packed = list(_split64(acc_hi) + _split64(acc_lo))[:words]
+    return packed, used <= 32 * words
 
 
 def rows_equal_adjacent(key_arrays: Sequence[jax.Array]) -> jax.Array:

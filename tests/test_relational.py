@@ -1688,6 +1688,8 @@ class TestJoinDenseMasked:
 
     @pytest.mark.parametrize("how", ["left", "semi"])
     def test_a_join_that_is_not_inner_hands_back_a_prefix(self, how):
+        """... unless it keeps the left rows (semi, anti: a lookup on the
+        dense path since PR 37, the scattered ``match`` its mask)."""
         from spark_rapids_jni_tpu.relational import join_dense_or_hash
 
         left, right, domain, kw, _ = self._inputs(
@@ -1696,8 +1698,16 @@ class TestJoinDenseMasked:
                                          how=how, **kw)
         got, live = join_dense_or_hash(left, right, "k", "k", domain,
                                        how=how, compact=False, **kw)
-        assert np.array_equal(np.asarray(live),
-                              np.arange(got.num_rows) < int(total))
+        if how == "semi":
+            assert int(np.asarray(live).sum()) == int(total)
+            assert not np.array_equal(
+                np.asarray(live), np.arange(got.num_rows) < int(total))
+            for c in left.names:   # the left rows where they were
+                assert np.array_equal(np.asarray(got[c].data),
+                                      np.asarray(left[c].data))
+        else:
+            assert np.array_equal(np.asarray(live),
+                                  np.arange(got.num_rows) < int(total))
         assert _live_rows(got, live) == _live_rows(
             want, np.arange(want.num_rows) < int(total))
 
