@@ -51,6 +51,9 @@ class PlanCache:
         # ... and the gathers of one index a row that its sort-engine
         # aggregates traced outside the branch that many groups take
         self.agg_rowwide_gathers = 0
+        # ... and the validity buffers its row gathers move (a packed
+        # word counts one)
+        self.validity_gathers = 0
         # ... and the row slots its ordered limits put through a sort or
         # a selection
         self.topk_sorted_rows = 0
@@ -76,6 +79,12 @@ class PlanCache:
         aggregates (``relational.aggregate.rowwide_gathers``)."""
         with self._lock:
             self.agg_rowwide_gathers = int(count)
+
+    def note_validity_gathers(self, count: int) -> None:
+        """A plan was traced: the validity buffers its row gathers of
+        more than 4096 indices move (``relational.gather.validity_gathers``)."""
+        with self._lock:
+            self.validity_gathers = int(count)
 
     def note_topk_rows(self, rows: int) -> None:
         """A plan was traced: the row slots its ordered limits (``TopK``)
@@ -192,6 +201,7 @@ class PlanCache:
                 **self.routes,
                 **self.joins,
                 "agg_rowwide_gathers": self.agg_rowwide_gathers,
+                "validity_gathers": self.validity_gathers,
                 "topk_sorted_rows": self.topk_sorted_rows,
                 "agg_input_slots": self.agg_input_slots,
                 # int8 slots of the newest one-hot contraction traced

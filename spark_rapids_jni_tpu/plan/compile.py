@@ -425,7 +425,7 @@ def _exchange_local(b: ColumnBatch, key: str, live, partitions: int,
     so live rows stay compacted in front and an arange<count mask
     remains valid after the regroup."""
     from ..parallel.partition import regroup_order, spark_partition_id
-    from ..relational.gather import gather_column
+    from ..relational.gather import gather_batch
 
     with profiler.scope("exchange.partition_id"):
         pid = spark_partition_id([b[key]], partitions, live)
@@ -433,8 +433,7 @@ def _exchange_local(b: ColumnBatch, key: str, live, partitions: int,
         order = regroup_order(pid, partitions + 1, secondary=secondary,
                               lead_bits=lead_bits)
     with profiler.scope("exchange.scatter"):
-        return ColumnBatch({name: gather_column(col, order)
-                            for name, col in zip(b.names, b.columns)})
+        return gather_batch(b, order)
 
 
 def _plain_int_key(col) -> bool:
@@ -1000,16 +999,20 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
             # span appears on a cache miss (or a retrace) and never on
             # a hit
             from ..relational.aggregate import rowwide_gathers
+            from ..relational.gather import validity_gathers
 
             with profiler.span("plan.trace"):
                 _TRACE_COUNT[0] += 1
                 st = _State(join_plans, agg_hints)
                 rowwide = rowwide_gathers()
+                validity = validity_gathers()
                 batch, live, _pfx = _lower(plan, env, prebuilts, st)
                 get_plan_cache().note_joins(st.joins_masked,
                                             st.joins_compacted)
                 get_plan_cache().note_rowwide_gathers(
                     rowwide_gathers() - rowwide)
+                get_plan_cache().note_validity_gathers(
+                    validity_gathers() - validity)
                 get_plan_cache().note_topk_rows(st.topk_sorted_rows)
                 get_plan_cache().note_agg_input_slots(st.agg_input_slots)
                 # from an Aggregate up ``live`` is the group count

@@ -77,7 +77,7 @@ from ..columnar.encoded import (
 from .. import profiler
 from ..profiler import scope
 from . import keys as K
-from .gather import gather_column
+from .gather import count_validity_gather, gather_column
 
 _OPS = ("sum", "count", "min", "max", "mean")
 
@@ -659,6 +659,8 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
         out.update(zip(key_names, firsts))
 
         def sorted_valid(name):
+            if not assume_grouped:
+                count_validity_gather(n)
             return in_order(batch[name].validity) & sorted_occ
 
         def sorted_col(name):
