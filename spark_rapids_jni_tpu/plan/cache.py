@@ -10,8 +10,7 @@ shape/dtype/dict-token change the schema fingerprint, so both are
 misses by construction rather than by invalidation logic.
 
 Counters surface the same way the spill/shuffle metrics do:
-``RmmSpark.plan_cache_metrics()`` and ``profiler.plan_cache_summary()``
-read :func:`plan_cache_metrics`.
+``RmmSpark.plan_cache_metrics()`` reads :func:`plan_cache_metrics`.
 """
 
 from __future__ import annotations

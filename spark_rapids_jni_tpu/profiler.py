@@ -14,7 +14,10 @@ captures into JSON offline).  The TPU equivalent wraps the XLA profiler
 * :func:`convert_profile` — the offline converter: reads a captured
   stream back into per-event records (kernel/op name, start, duration,
   the program's ``scope`` on device operations, the query's ``sid`` on
-  host spans); :func:`device_time_by_scope` adds those up.
+  host spans); :func:`device_time_by_scope` adds those up, and
+  :func:`idle_by_span` names each idle gap of the device after the host
+  span over it, ring spans of any process put on the trace's clock by a
+  :func:`clock_anchor`.
 * :func:`span` / :func:`note` / :func:`scope` — the one tracer inside the
   program.  A span is a host interval under a query id: always recorded in
   a bounded per-process ring (:func:`spans`, :func:`stage_totals`) and,
@@ -153,59 +156,6 @@ class Profiler:
             cls._writer(data)
 
 
-def spill_summary() -> dict:
-    """Spill-framework counters for profile reports: bytes/count per tier
-    transition (device→host, host→disk, read-backs), eviction latency,
-    and disk-write failures — the reference surfaces the same counters as
-    task-level spill metrics next to its profiler captures.  All zeros
-    when no spill framework is installed, so report code can emit the
-    section unconditionally."""
-    from .mem import spill
-
-    fw = spill.get_framework()
-    if fw is None:
-        return dict.fromkeys(spill.SpillMetrics.FIELDS, 0)
-    return fw.metrics.snapshot()
-
-
-def shuffle_summary() -> dict:
-    """ShuffleService counters for profile reports: shuffles/rounds run,
-    rows and bytes moved, bytes spilled under pressure, out-of-range and
-    dropped row counts, transport retry count, zone-map block skipping
-    (``blocks_skipped``/``blocks_scanned`` from predicate-pruned morsel
-    streams), and the worst skew ratio seen — the per-shuffle analogue
-    of :func:`spill_summary`.  Always zeros-safe: the registry exists as
-    soon as the shuffle package imports."""
-    from .shuffle import get_registry
-
-    return get_registry().metrics.snapshot()
-
-
-def plan_cache_summary() -> dict:
-    """Plan-cache counters for profile reports: compiled-program hits,
-    misses, LRU evictions, and current size/capacity — the retrace
-    story next to :func:`spill_summary`/:func:`shuffle_summary` (a hit
-    means a repeated plan shape re-executed with zero retraces).
-    Always zeros-safe: the cache exists as soon as the plan package
-    imports."""
-    from .plan.cache import plan_cache_metrics
-
-    return plan_cache_metrics()
-
-
-def fleet_summary() -> dict:
-    """Front-door fleet counters for profile reports: workers spawned
-    and respawned, crashes/stalls detected, session re-placements,
-    ``WorkerLost`` failures, load-shed admissions, circuit-breaker
-    opens, and the per-worker liveness map — the process-supervision
-    story next to :func:`spill_summary`.  Always zeros-safe: a process
-    that never constructed a :class:`~spark_rapids_jni_tpu.serve.
-    frontdoor.FrontDoor` reports all-zero counters and no workers."""
-    from .serve.frontdoor import fleet_metrics
-
-    return fleet_metrics()
-
-
 # ---------------------------------------------------------------------------
 # the tracer: host spans under a query id, device scopes
 # ---------------------------------------------------------------------------
@@ -308,6 +258,59 @@ def spans(since_ns: int = 0) -> List[Span]:
     with _ring_lock:
         rows = list(_ring)
     return [Span(*r) for r in rows if r[3] >= since_ns]
+
+
+def span_columns(since_ns: int = 0) -> dict:
+    """:func:`spans` as columns, for a process that hands its spans to
+    another over a JSON wire: ``names`` once each, then a span a place in
+    ``name`` and ``parent`` (indices into ``names``; -1 for no parent),
+    ``sid``, ``t0_ns`` and ``t1_ns``.  :func:`spans_from_columns` reads them
+    back."""
+    index: Dict[str, int] = {}
+    cols: dict = {"names": [], "name": [], "parent": [], "sid": [],
+                  "t0_ns": [], "t1_ns": []}
+
+    def at(name):
+        if name is None:
+            return -1
+        if name not in index:
+            index[name] = len(cols["names"])
+            cols["names"].append(name)
+        return index[name]
+
+    for s in spans(since_ns):
+        cols["name"].append(at(s.name))
+        cols["parent"].append(at(s.parent))
+        cols["sid"].append(s.sid)
+        cols["t0_ns"].append(s.t0_ns)
+        cols["t1_ns"].append(s.t1_ns)
+    return cols
+
+
+def spans_from_columns(cols: dict) -> List[Span]:
+    """The :class:`Span` records of :func:`span_columns`' columns."""
+    names = cols["names"]
+    return [Span(names[n], sid, names[p] if p >= 0 else None, t0, t1)
+            for n, p, sid, t0, t1 in zip(cols["name"], cols["parent"],
+                                         cols["sid"], cols["t0_ns"],
+                                         cols["t1_ns"])]
+
+
+def clock_anchor():
+    """``(perf_counter_ns, time_ns)`` read back to back: the ring's clock
+    against the trace's.
+
+    The XLA profiler stamps host events with CLOCK_REALTIME (tsl's
+    ``EnvTime::NowNanos``, which is ``time.time_ns()``) and writes them
+    relative to the session's start, the ``profile_start_time`` stat of
+    the trace's ``Task Environment`` plane (:func:`trace_start_ns`); the
+    runtime puts the device's events on the same clock.  A CPU trace
+    bears it out: a span's event lies 2-3 us before ``time.time_ns()``
+    read inside it.  The ring's ``time.perf_counter_ns()`` is
+    CLOCK_MONOTONIC, one clock for every process of the machine, so one
+    anchor places every process's ring spans on a trace
+    (:func:`on_trace_clock`)."""
+    return time.perf_counter_ns(), time.time_ns()
 
 
 def stage_totals() -> Dict[str, dict]:
@@ -567,6 +570,102 @@ def device_time_by_scope(events: List[dict], depth: int = 2
             key = "/".join(e["scope"].split("/")[:depth]) or NO_SCOPE
             stack.append([key, e["ts_us"] + e["dur_us"], 0.0, e["dur_us"]])
         close(float("inf"))
+    return out
+
+
+def trace_start_ns(payload: bytes) -> Optional[int]:
+    """The ``time.time_ns()`` at which the session of one ``.xplane.pb``
+    started: its events' times are counted from it (:func:`clock_anchor`).
+    ``None`` where the trace does not say."""
+    from jax.profiler import ProfileData
+
+    for plane in ProfileData.from_serialized_xspace(payload).planes:
+        if plane.name == "Task Environment":
+            for k, v in plane.stats:
+                if k == "profile_start_time":
+                    return int(v)
+    return None
+
+
+RING_PLANE = "ring"
+
+
+def on_trace_clock(ring: List[Span], anchor, start_ns: int) -> List[dict]:
+    """Ring spans (:func:`spans`, of any process of the machine) as records
+    of :func:`convert_xplane` on a trace's clock: ``anchor`` from
+    :func:`clock_anchor`, ``start_ns`` the trace's :func:`trace_start_ns`.
+    Their plane is ``RING_PLANE``."""
+    off = anchor[1] - anchor[0] - start_ns
+    return [{"name": s.name, "ts_us": (s.t0_ns + off) / 1e3,
+             "dur_us": (s.t1_ns - s.t0_ns) / 1e3, "plane": RING_PLANE,
+             "line": "", "sid": s.sid} for s in ring]
+
+
+# the layers whose host spans name the device's idle gaps: the program's own
+# (``span``) and the benchmark's annotations around its calls into it
+SPAN_LAYERS = ("bench", "plan", "serve", "worker", "shuffle")
+NO_SPAN = "(no span)"
+
+
+def _union(intervals):
+    out: list = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def idle_by_span(events: List[dict]) -> Dict[str, float]:
+    """Idle seconds of the busiest device by what the host was doing: each
+    gap between its operations (the records of :func:`convert_xplane` that
+    carry a ``scope``), from the first operation or span of the window to
+    the last, goes to the innermost span of ``SPAN_LAYERS`` that covers
+    more than half of it, else to the one that covers most of it, else to
+    ``NO_SPAN``.  Host spans in the trace bound the window; ring spans put
+    on its clock (:func:`on_trace_clock`: the supervisor's ``serve.*``)
+    only name gaps.  ``{}`` where no device operation was traced."""
+    busy: Dict[str, list] = {}
+    for e in events:
+        if "scope" in e:
+            busy.setdefault(e["plane"], []).append(
+                (e["ts_us"], e["ts_us"] + e["dur_us"]))
+    if not busy:
+        return {}
+    spans_ = [(e["ts_us"], e["ts_us"] + e["dur_us"], e["name"], e["plane"])
+              for e in events if not e["plane"].startswith("/device:")
+              and "." in e["name"] and e["name"].split(".")[0] in SPAN_LAYERS]
+    bounds = [iv for ivs in busy.values() for iv in ivs] + [
+        (s, e) for s, e, _n, plane in spans_ if plane != RING_PLANE]
+    w0, w1 = min(s for s, _e in bounds), max(e for _s, e in bounds)
+    merged = {p: _union(ivs) for p, ivs in busy.items()}
+    full = max(merged, key=lambda p: sum(e - s for s, e in merged[p]))
+    edges = [w0] + [x for iv in merged[full] for x in iv] + [w1]
+    out: Dict[str, float] = {}
+    spans_.sort()
+    active: list = []
+    i = 0
+    for g0, g1 in zip(edges[0::2], edges[1::2]):
+        if g1 <= g0:
+            continue
+        while i < len(spans_) and spans_[i][0] < g1:
+            active.append(spans_[i])
+            i += 1
+        # gaps come in order: a span that ended before this one ends
+        # before every later one too
+        active = [sp for sp in active if sp[1] > g0]
+        best, cover, inner = NO_SPAN, 0.0, None
+        for s, e, name, _plane in active:
+            ov = min(e, g1) - max(s, g0)
+            if ov <= 0:
+                continue
+            if 2 * ov > g1 - g0 and (inner is None or e - s < inner[0]):
+                inner = (e - s, name)
+            if ov > cover:
+                best, cover = name, ov
+        name = inner[1] if inner else best
+        out[name] = out.get(name, 0.0) + (g1 - g0) / 1e6
     return out
 
 

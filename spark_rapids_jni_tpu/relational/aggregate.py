@@ -434,7 +434,7 @@ def _sort_wide_keys(karr, narrow, occ):
 
             perm = jax.lax.fori_loop(0, len(karr), one_pass, iota)
             in_order = [k[perm] for k in karr]
-            run = jnp.cumsum((~K.rows_equal_adjacent(in_order))
+            run = K.scan_sum((~K.rows_equal_adjacent(in_order))
                              .astype(jnp.uint32))
             # the row flag, then each run of equal keys under its ordinal
             return ((in_order[0] & jnp.uint32(1 << 31), run)
@@ -677,7 +677,7 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                     ones = sorted_occ.astype(jnp.int64)
                 else:
                     ones = sorted_valid(spec.column).astype(jnp.int64)
-                out[spec.out_name] = Column(at_ends_diff(jnp.cumsum(ones)),
+                out[spec.out_name] = Column(at_ends_diff(K.scan_sum(ones)),
                                             out_valid, T.INT64)
                 continue
 
@@ -701,7 +701,7 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
 
                 svalid = sorted_valid(spec.column)
                 slimbs = in_order(dcol.limbs)
-                counts = jnp.cumsum(svalid.astype(jnp.int32))
+                counts = K.scan_sum(svalid.astype(jnp.int32))
                 nn_d = per_group(lambda w: ends_diff(counts, w))
                 has_any_d = out_valid & (nn_d > 0)
                 if spec.op in ("min", "max"):
@@ -731,10 +731,10 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                 live = jnp.where(svalid[:, None], slimbs,
                                  jnp.zeros((), jnp.uint64))
                 m32 = jnp.uint64(0xFFFFFFFF)
-                scans = [jnp.cumsum(x) for x in (
+                scans = [K.scan_sum(x) for x in (
                     live[:, 0] & m32, live[:, 0] >> jnp.uint64(32),
                     live[:, 1] & m32, live[:, 1] >> jnp.uint64(32))]
-                negatives = jnp.cumsum(
+                negatives = K.scan_sum(
                     (live[:, 1] >> jnp.uint64(63)).astype(jnp.int32))
 
                 def lane_sums(w):
@@ -760,7 +760,7 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
 
             data, valid = sorted_col(spec.column)
             col_dtype = batch[spec.column].dtype
-            nn = at_ends_diff(jnp.cumsum(valid.astype(jnp.int32)))
+            nn = at_ends_diff(K.scan_sum(valid.astype(jnp.int32)))
             has_any = nn > 0
 
             if spec.op in ("sum", "mean"):
@@ -772,7 +772,7 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                 if jnp.issubdtype(acc.dtype, jnp.floating):
                     s = at_ends(_seg_scan_sum(acc, boundary))
                 else:
-                    s = at_ends_diff(jnp.cumsum(acc))  # exact mod-2^64
+                    s = at_ends_diff(K.scan_sum(acc))  # exact mod-2^64
                 if spec.op == "mean":
                     s = s / jnp.maximum(nn, 1).astype(jnp.float64)
                 out[spec.out_name] = Column(s, out_valid & has_any, out_t)
@@ -799,9 +799,9 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                 r = at_ends(run)
                 if is_float:
                     seg_nan = at_ends_diff(
-                        jnp.cumsum(nan_in.astype(jnp.int32))) > 0
+                        K.scan_sum(nan_in.astype(jnp.int32))) > 0
                     seg_num = at_ends_diff(
-                        jnp.cumsum(valid_num.astype(jnp.int32))) > 0
+                        K.scan_sum(valid_num.astype(jnp.int32))) > 0
                     nan = jnp.array(jnp.nan, r.dtype)
                     if spec.op == "max":
                         r = jnp.where(seg_nan, nan, r)
@@ -1371,8 +1371,9 @@ def _onehot_sliced(batch, derive, lay, keys, domains, row_live):
         # columns' operations then keep the paths of their own plan node
         def body(i, carry):
             part, overflow = carry
-            start = jnp.minimum(i * rows, n - rows)
             with rejoin(), scope("agg.onehot_slice"):
+                start = jnp.minimum(i * rows, n - rows)
+
                 def cut(a):
                     return jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
 
@@ -1386,10 +1387,11 @@ def _onehot_sliced(batch, derive, lay, keys, domains, row_live):
                 with scope("agg.onehot_payload"):
                     X8 = _onehot_payload(blk, live, lay)
                 part = part + _onehot_contract_int8(bucket, live, X8, kids)
-            return part, overflow | ovf
+                return part, overflow | ovf
 
-        init = (jnp.zeros((kids.shape[1], lay.m8), jnp.int64),
-                jnp.zeros((), jnp.bool_))
+        with rejoin():
+            init = (jnp.zeros((kids.shape[1], lay.m8), jnp.int64),
+                    jnp.zeros((), jnp.bool_))
         return jax.lax.fori_loop(jnp.int32(0), jnp.int32(-(-n // rows)),
                                  body, init)
 

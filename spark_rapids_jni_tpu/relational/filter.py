@@ -22,6 +22,7 @@ from ..columnar.encoded import packed_filter_mask  # noqa: F401  (packed
 # filter path: compare u32 residual lanes against the once-transformed
 # literal, no decode — the compressed-domain half of the filter API)
 from .gather import gather_batch
+from .keys import scan_sum
 
 
 def selection_indices(mask):
@@ -36,8 +37,8 @@ def selection_indices(mask):
     # destination of each row: selected rows pack to the front by prefix
     # count, unselected rows follow — one permutation scatter instead of an
     # argsort (TPU sorts are the pipeline bottleneck; cumsum+scatter is not)
-    sel_pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-    unsel_pos = count + jnp.cumsum((~mask).astype(jnp.int32)) - 1
+    sel_pos = scan_sum(mask.astype(jnp.int32)) - 1
+    unsel_pos = count + scan_sum((~mask).astype(jnp.int32)) - 1
     pos = jnp.where(mask, sel_pos, unsel_pos)
     iota = jnp.arange(n, dtype=jnp.int32)
     idx = jnp.zeros((n,), jnp.int32).at[pos].set(iota)

@@ -349,7 +349,7 @@ def hash_join(
     with scope("join.expand"):
         counts_out = jnp.where(l_live, jnp.maximum(counts, 1), 0) \
             if outer else counts
-        cum = jnp.cumsum(counts_out)  # inclusive
+        cum = K.scan_sum(counts_out)  # inclusive
         total = cum[-1] if nl else jnp.int32(0)
         offsets = cum - counts_out
 

@@ -47,6 +47,22 @@ _SIGN32 = np.uint32(0x80000000)
 _F64_QNAN = np.uint64(0x7FF8000000000000)
 
 
+def scan_sum(vals):
+    """``jnp.cumsum(vals)`` of a 1-D array, as the one ``reduce_window`` it
+    lowers to, lowered in line: ``jnp.cumsum`` lowers into a function of
+    its own whose operations the compiler's rewrite of a long scan (a
+    128-lane blocked scan, the 64-bit one as u32 halves) names
+    ``reduce_window_sum``, outside every scope; in line they carry the
+    caller's.  The compiled program is the same."""
+    if vals.dtype == jnp.bool_:
+        vals = vals.astype(jnp.int64)   # what jnp.cumsum sums a flag in
+    n = vals.shape[0]
+    if n == 0:
+        return vals
+    return jax.lax.reduce_window(vals, jnp.zeros((), vals.dtype),
+                                 jax.lax.add, (n,), (1,), ((n - 1, 0),))
+
+
 def _split64(u64):
     """uint64[n] -> (hi, lo) uint32 pair."""
     return (u64 >> jnp.uint64(32)).astype(jnp.uint32), (

@@ -174,8 +174,7 @@ class QuotaExceeded(ServeError):
 
 class FleetMetrics:
     """Fleet-level counters + per-worker liveness, scraped via
-    :func:`fleet_metrics` → ``RmmSpark.fleet_metrics()`` →
-    ``profiler.fleet_summary()``."""
+    :func:`fleet_metrics` → ``RmmSpark.fleet_metrics()``."""
 
     FIELDS = ("workers_spawned", "respawns", "crashes", "stalls",
               "replacements", "worker_lost", "sheds", "circuit_open",

@@ -33,7 +33,7 @@ each module here is the TPU analogue of one of them:
   :class:`ShuffleInfo` per exchange, and aggregates
   :class:`ShuffleMetrics` (rounds, rows/bytes moved, spilled bytes, skew
   peak, out-of-range ids, the ``dropped == 0`` invariant), surfaced via
-  ``profiler.shuffle_summary()`` and ``RmmSpark.shuffle_metrics()``.
+  ``RmmSpark.shuffle_metrics()``.
 
 * **Persistent shuffle plane** — the external-shuffle-service role:
   :mod:`.store` persists committed map outputs and drained round chunks

@@ -7,8 +7,7 @@ flight, bytes moved, spill activity — next to the catalog.  Here the
 :class:`ShuffleRegistry` does the same for the TPU service: it hands out
 monotonically increasing shuffle ids, records one :class:`ShuffleInfo`
 per completed exchange, and aggregates :class:`ShuffleMetrics` for the
-process (surfaced via ``profiler.shuffle_summary()`` and
-``RmmSpark.shuffle_metrics()``).
+process (surfaced via ``RmmSpark.shuffle_metrics()``).
 """
 
 from __future__ import annotations

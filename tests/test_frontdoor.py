@@ -599,8 +599,7 @@ class TestFleetMetrics:
             assert field in snap and snap[field] >= 0
         from spark_rapids_jni_tpu.mem.rmm_spark import RmmSpark
         assert RmmSpark.fleet_metrics() == fleet_metrics()
-        from spark_rapids_jni_tpu.profiler import fleet_summary
-        summary = fleet_summary()
+        summary = RmmSpark.fleet_metrics()
         assert summary["workers_spawned"] >= 0
         assert "liveness" in summary
 

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from spark_rapids_jni_tpu import config, faultinj, profiler
+from spark_rapids_jni_tpu import config, faultinj
 from spark_rapids_jni_tpu.columnar import types as T
 from spark_rapids_jni_tpu.columnar.column import Column, ColumnBatch
 from spark_rapids_jni_tpu.parallel import data_mesh, shard_batch
@@ -290,11 +290,11 @@ class TestOutOfCore:
         # lossless: the received multiset equals the sent multiset
         assert res.rows_moved == n
         assert sorted(out[occ].tolist()) == sorted(vals.tolist())
-        summary = profiler.shuffle_summary()
+        summary = RmmSpark.shuffle_metrics()
         assert summary["rounds"] >= 2
         assert summary["spilled_bytes"] > 0  # the arena forced eviction
         assert summary["dropped_rows"] == 0
-        assert RmmSpark.shuffle_metrics() == summary
+        assert get_registry().metrics.snapshot() == summary
 
 
 # ---------------------------------------------------------------------------
@@ -451,7 +451,7 @@ class TestStreamingExchange:
 
         assert res.rows_moved == n
         assert (k[occ] == 3).all() and int(occ.sum()) == n
-        summary = profiler.shuffle_summary()
+        summary = RmmSpark.shuffle_metrics()
         assert summary["rounds"] >= 2
         assert summary["spilled_bytes"] > 0  # the arena forced demotion
         assert summary["dropped_rows"] == 0

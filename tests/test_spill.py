@@ -19,7 +19,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from spark_rapids_jni_tpu import faultinj, profiler
+from spark_rapids_jni_tpu import faultinj
 from spark_rapids_jni_tpu.mem import (
     RmmSpark,
     Spillable,
@@ -360,7 +360,8 @@ class TestSpillIOFault:
 
 
 class TestMetricsExport:
-    def test_rmm_spark_and_profiler_surfaces(self, framework, adaptor):
+    def test_rmm_spark_surfaces_the_frameworks_counters(self, framework,
+                                                        adaptor):
         with TaskContext(9) as ctx:
             h = SpillableHandle(_tree(64 * KB // 4), ctx=ctx)
             h.spill()
@@ -369,7 +370,7 @@ class TestMetricsExport:
         RmmSpark.task_done(9)
         g = RmmSpark.spill_metrics()
         assert g["device_to_host_bytes"] == 64 * KB
-        assert profiler.spill_summary() == g
+        assert g == framework.metrics.snapshot()
         t = RmmSpark.get_and_reset_task_spill_metrics(9)
         assert t["device_to_host_bytes"] == 64 * KB
         assert t["host_to_device_bytes"] == 64 * KB
@@ -379,7 +380,8 @@ class TestMetricsExport:
 
     def test_zeros_without_framework(self):
         assert sum(RmmSpark.spill_metrics().values()) == 0
-        assert sum(profiler.spill_summary().values()) == 0
+        assert set(RmmSpark.spill_metrics()) == set(
+            spill_mod.SpillMetrics.FIELDS)
 
 
 class TestLegacySpillableDelegates:

@@ -105,6 +105,30 @@ class TestSpans:
                  and s.sid == 9]
         assert noted and noted[-1].t1_ns - noted[-1].t0_ns == 9_000_000
 
+    def test_span_columns_cross_a_json_wire_and_back(self):
+        import json
+
+        t = _since()
+        with profiler.span("test.cols_outer", sid=17):
+            with profiler.span("test.cols_inner"):
+                pass
+            with profiler.span("test.cols_inner"):
+                pass
+        profiler.note("test.cols_note", "s-3", t, t + 5)
+        cols = json.loads(json.dumps(profiler.span_columns(t)))
+        back = [s for s in profiler.spans_from_columns(cols)
+                if s.name.startswith("test.cols")]
+        assert back == [s for s in profiler.spans(t)
+                        if s.name.startswith("test.cols")]
+        assert [s.name for s in back] == ["test.cols_inner",
+                                          "test.cols_inner",
+                                          "test.cols_outer",
+                                          "test.cols_note"]
+        # a name is written once, a span refers to it
+        assert cols["names"].count("test.cols_inner") == 1
+        assert back[0].parent == "test.cols_outer" and back[0].sid == 17
+        assert back[3].parent is None and back[3].sid == "s-3"
+
     @pytest.mark.parametrize("bad", ["join", "plan.join/dim", "a b.c",
                                      ".x", "x.", "plan..x"])
     def test_scope_names_are_layer_dot_what(self, bad):
@@ -185,6 +209,52 @@ def _lowered_scopes(the_plan, inputs):
                           text))
 
 
+def _unscoped_ops(text):
+    """The names, without a ``plan.`` scope, of the operations of a lowered
+    program (``as_text(debug_info=True)``), each named as the compiler
+    names it once it has inlined the calls: the call's name, then the
+    operation's own (a function called from several places is named once
+    for each)."""
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+
+    def name(ref):
+        d = locs.get(ref, "")
+        m = re.match(r'^"([^"]*)"', d)
+        if m:
+            return m.group(1)
+        m = re.match(r"^(#loc\d+)$", d)
+        return name(m.group(1)) if m else ""
+
+    funcs, cur = {}, None
+    for ln in text.splitlines():
+        m = re.match(r"\s*func\.func (?:public|private) @([\w.$-]+)\(", ln)
+        if m:
+            cur = funcs.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(ln)
+    skip = ("stablehlo.constant", "stablehlo.return", "func.return",
+            "func.call", "stablehlo.tuple", "stablehlo.get_tuple_element")
+    bad, seen, todo = set(), set(), [("main", "")]
+    while todo:
+        f, prefix = todo.pop()
+        if (f, prefix) in seen:
+            continue
+        seen.add((f, prefix))
+        for ln in funcs[f]:
+            ml = re.search(r"loc\((#loc\d+)\)\s*$", ln)
+            if not ml:
+                continue
+            full = "/".join(x for x in (prefix, name(ml.group(1))) if x)
+            call = re.search(r"[\s=]call @([\w.$-]+)\(", ln)
+            if call:
+                todo.append((call.group(1), full))
+                continue
+            m = re.match(r'\s*(?:%[^=]+ = )?"?([a-z_]+\.[a-z_]+)', ln)
+            if m and m.group(1) not in skip and "plan." not in full:
+                bad.add(full)
+    return bad
+
+
 class TestScopes:
     def test_q6_plan_names_every_node_and_the_onehot_phases(self):
         config.set("q6_float_mode", "f64")
@@ -257,6 +327,42 @@ class TestScopes:
         assert paths and all("/join.general/" in p for p in paths), paths
         assert [cp.decisions[k]["output"] for k in ("join0:k", "join1:wh")] \
             == ["mask", "mask"]
+
+    @pytest.mark.parametrize("config_name", [
+        "q6-scan-agg", "q95-join-agg", "tpch-q1", "tpch-q3", "tpch-q18"])
+    def test_every_operation_of_a_cells_plan_has_a_node_scope(
+            self, config_name, monkeypatch):
+        """A benchmark configuration's plan as its cell runs it (its knobs,
+        the chip's engines) at 2^12 rows: every operation the lowered
+        program holds is named under a ``plan.`` scope, with the name the
+        compiler gives it once calls are inlined (the call's, then the
+        operation's own).  Left out: the counter of the one-hot engine's
+        sliced loop (``tpch_q1_plan``), which is built under no scope so
+        that the expressions in its body keep their own node's path."""
+        from benchmark import lib
+
+        cfg, mod = lib.load_config(config_name, 12)
+        rows = mod.rows_per_query(cfg)
+        key = jax.random.PRNGKey(7)
+        inputs = dict(mod.make_partition(cfg, key, rows))
+        inputs.update(mod.make_shared(cfg, key, rows)
+                      if hasattr(mod, "make_shared") else {})
+        # every "auto" asks jax.default_backend(): answer as the chip does
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        for k, v in cfg["knobs"].items():
+            config.set(k, v)
+        try:
+            cp = plan.compile_plan(mod.plan(cfg), inputs)
+            text = cp.fn.lower(inputs, ()).as_text(debug_info=True)
+        finally:
+            config.reset()
+            plan.reset_plan_cache()   # no later test may run the chip's path
+        loop = ({"jit(run)/while/cond/lt", "jit(run)/while/body/add"}
+                if config_name == "tpch-q1" else set())
+        assert _unscoped_ops(text) == loop
+        # a prefix sum lowered out of line (jnp.cumsum) is named
+        # reduce_window_sum, outside every scope, in the compiled program
+        assert "func.func private @cumsum" not in text
 
     def test_node_scopes_hold_letters_digits_underscore_dot_only(self):
         from spark_rapids_jni_tpu.plan import compile as pc
@@ -395,6 +501,116 @@ class TestConverter:
 
 
 # ---------------------------------------------------------------------------
+# one clock: ring spans on the trace, idle gaps by the span over them
+# ---------------------------------------------------------------------------
+
+def _device_op(ts, dur, plane="/device:TPU:0"):
+    return {"name": "fusion", "ts_us": ts * 1e6, "dur_us": dur * 1e6,
+            "plane": plane, "line": "XLA Ops", "scope": "plan.x.y"}
+
+
+def _host_span(name, ts, dur):
+    return {"name": name, "ts_us": ts * 1e6, "dur_us": dur * 1e6,
+            "plane": "/host:CPU", "line": "python"}
+
+
+class TestOneClock:
+    def test_an_anchor_puts_a_ring_span_on_the_trace(self, tmp_path):
+        """Under a CPU profiler session a span is in the ring and in the
+        trace: its ring start, moved by the anchor, lands within 100 us of
+        its event."""
+        import glob
+        import os
+
+        t = _since()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            anchor = profiler.clock_anchor()
+            with profiler.span("test.anchored", sid=3):
+                time.sleep(0.003)
+            jax.block_until_ready(jax.jit(lambda x: x * 2)(jnp.arange(8)))
+            with profiler.span("test.anchored", sid=4):
+                time.sleep(0.001)
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                       "*", "*.xplane.pb"))
+        with open(path, "rb") as f:
+            payload = f.read()
+        start = profiler.trace_start_ns(payload)
+        assert start is not None
+        traced = sorted((e for e in profiler.convert_xplane(payload)
+                         if e["name"] == "test.anchored"),
+                        key=lambda e: e["ts_us"])
+        ring = [s for s in profiler.spans(t) if s.name == "test.anchored"]
+        placed = profiler.on_trace_clock(ring, anchor, start)
+        assert [p["sid"] for p in placed] == [e["sid"] for e in traced] \
+            == [3, 4]
+        for p, e in zip(placed, traced):
+            assert p["plane"] == profiler.RING_PLANE
+            assert abs(p["ts_us"] - e["ts_us"]) < 100
+            assert abs(p["dur_us"] - e["dur_us"]) < 100
+
+    def test_idle_gaps_go_to_the_innermost_span_over_most_of_them(self):
+        events = [
+            _device_op(0, 2), _device_op(5, 1), _device_op(9, 1),
+            _device_op(12, 1), _device_op(14, 1),
+            # a second, less busy device: its gaps are not counted
+            _device_op(0, 0.5, plane="/device:TPU:1"),
+            _host_span("bench.execute", 0, 10),
+            _host_span("plan.dispatch", 2.5, 2),   # 2 of the 3 s gap
+            _host_span("plan.lookup", 6, 0.5),     # a sixth of that gap
+            _host_span("bench.result", 10, 2),
+            _host_span("TpuExecutable::Execute", 13, 1),   # not a span
+        ]
+        got = profiler.idle_by_span(events)
+        assert got == pytest.approx({
+            "plan.dispatch": 3.0, "bench.execute": 3.0,
+            "bench.result": 2.0, profiler.NO_SPAN: 1.0})
+        # the supervisor's ring span, put on the trace's clock, names the
+        # gap that nothing in the trace covers; it does not widen the
+        # window
+        anchor, start_ns = (1_000, 5_000_000_000), 4_000_000_000
+        sup = [profiler.Span("serve.decode", 8, None, 11_500_001_000,
+                             14_000_001_000),
+               profiler.Span("serve.send", 9, None, 19_000_001_000,
+                             21_000_001_000)]
+        ring = profiler.on_trace_clock(sup, anchor, start_ns)
+        assert ring[0]["ts_us"] == pytest.approx(12.5e6)
+        got = profiler.idle_by_span(events + ring)
+        assert got == pytest.approx({
+            "plan.dispatch": 3.0, "bench.execute": 3.0,
+            "bench.result": 2.0, "serve.decode": 1.0})
+        assert profiler.idle_by_span([_host_span("plan.x", 0, 1)]) == {}
+
+    @pytest.mark.parametrize("path", [
+        ("tests", "data", "q6_inproc_1s_scoped.xplane.pb.gz"),
+        ("benchmark", "selfcheck", "q6_inproc_1s.xplane.pb.gz")])
+    def test_recorded_traces_idle_as_the_benchmark_reduces_them(self, path):
+        """The two recorded TPU traces: the gaps add up to the idle time
+        that ``benchmark/trace.py`` gives them, and the benchmark's own
+        reduction still gives its recorded numbers.  With the program's
+        spans in the trace (the first), some of ``bench.execute``'s idle
+        goes to ``plan.dispatch``."""
+        import gzip
+        import os
+
+        from benchmark import trace as bench_trace
+
+        file = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), *path)
+        with gzip.open(file, "rb") as f:
+            got = profiler.idle_by_span(profiler.convert_xplane(f.read()))
+        want = bench_trace.reduce_file(file, 1)
+        assert sum(got.values()) == pytest.approx(
+            want["window_s"] - want["busy_fullest_s"], rel=1e-9)
+        assert sum(got.values()) == pytest.approx(
+            sum(v for _k, v in want["idle_gaps"]), rel=1e-9)
+        assert ("plan.dispatch" in got) == (path[0] == "tests")
+        assert bench_trace.selfcheck() == 0
+
+
+# ---------------------------------------------------------------------------
 # the serving runtime: one timeline a query, across the process boundary
 # ---------------------------------------------------------------------------
 
@@ -468,7 +684,9 @@ class TestTimeline:
         assert "worker.encode" not in sess.timeline
         assert "serve.decode" not in sess.timeline
         # and it reaches operators the way every fleet counter does
-        assert profiler.fleet_summary()["stage_ms"] == after
+        from spark_rapids_jni_tpu.mem import RmmSpark
+
+        assert RmmSpark.fleet_metrics()["stage_ms"] == after
 
     def test_a_cache_hit_has_a_timeline_too(self, fleet):
         from spark_rapids_jni_tpu.serve import result_cache as rc
