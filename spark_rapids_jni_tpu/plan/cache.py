@@ -53,6 +53,9 @@ class PlanCache:
         # ... and the validity buffers its row gathers move (a packed
         # word counts one)
         self.validity_gathers = 0
+        # ... and the gather operations its row gathers make (a matrix
+        # of words counts one)
+        self.row_gathers = 0
         # ... and the row slots its ordered limits put through a sort or
         # a selection
         self.topk_sorted_rows = 0
@@ -84,6 +87,12 @@ class PlanCache:
         more than 4096 indices move (``relational.gather.validity_gathers``)."""
         with self._lock:
             self.validity_gathers = int(count)
+
+    def note_row_gathers(self, count: int) -> None:
+        """A plan was traced: the gather operations its row gathers of
+        more than 4096 indices make (``relational.gather.row_gathers``)."""
+        with self._lock:
+            self.row_gathers = int(count)
 
     def note_topk_rows(self, rows: int) -> None:
         """A plan was traced: the row slots its ordered limits (``TopK``)
@@ -201,6 +210,7 @@ class PlanCache:
                 **self.joins,
                 "agg_rowwide_gathers": self.agg_rowwide_gathers,
                 "validity_gathers": self.validity_gathers,
+                "row_gathers": self.row_gathers,
                 "topk_sorted_rows": self.topk_sorted_rows,
                 "agg_input_slots": self.agg_input_slots,
                 # int8 slots of the newest one-hot contraction traced

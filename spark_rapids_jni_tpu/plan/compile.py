@@ -999,13 +999,14 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
             # span appears on a cache miss (or a retrace) and never on
             # a hit
             from ..relational.aggregate import rowwide_gathers
-            from ..relational.gather import validity_gathers
+            from ..relational.gather import row_gathers, validity_gathers
 
             with profiler.span("plan.trace"):
                 _TRACE_COUNT[0] += 1
                 st = _State(join_plans, agg_hints)
                 rowwide = rowwide_gathers()
                 validity = validity_gathers()
+                gathers = row_gathers()
                 batch, live, _pfx = _lower(plan, env, prebuilts, st)
                 get_plan_cache().note_joins(st.joins_masked,
                                             st.joins_compacted)
@@ -1013,6 +1014,7 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
                     rowwide_gathers() - rowwide)
                 get_plan_cache().note_validity_gathers(
                     validity_gathers() - validity)
+                get_plan_cache().note_row_gathers(row_gathers() - gathers)
                 get_plan_cache().note_topk_rows(st.topk_sorted_rows)
                 get_plan_cache().note_agg_input_slots(st.agg_input_slots)
                 # from an Aggregate up ``live`` is the group count

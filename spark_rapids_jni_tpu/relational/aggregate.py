@@ -77,7 +77,7 @@ from ..columnar.encoded import (
 from .. import profiler
 from ..profiler import scope
 from . import keys as K
-from .gather import count_validity_gather, gather_column
+from .gather import count_validity_gather, gather_column, take_rows
 
 _OPS = ("sum", "count", "min", "max", "mean")
 
@@ -637,7 +637,7 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
         if assume_grouped:
             return arr
         _note_rowwide_gather(n)
-        return arr[sperm]
+        return take_rows(arr, sperm)
 
     with scope("agg.sortscan_reduce"):
         out = {}

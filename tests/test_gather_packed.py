@@ -1,10 +1,12 @@
 """``gather_batch`` moves the validity of its columns as packed ``uint32``
-words (bit i: the i-th packable column): bit for bit the per-column gather
-(``gather_column``) and a numpy reference of the validity, for every column
-representation, with and without a row mask, with indices out of range
-(clipped), over 0 to 65 packable columns; and the ``validity_gathers``
-counter, of one gather and of the benchmark's plans at 2^14 rows
-(``PERF.md`` section 3 records the counts)."""
+words (bit i: the i-th packable column) and, in a row gather (more than
+4096 indices), their fixed-width data as words of one ``[rows, words]``
+matrix: bit for bit the per-column gather (``gather_column``) and a numpy
+reference of the validity, for every column representation, with and
+without a row mask, with indices out of range (clipped), over 0 to 65
+packable columns, at 4096 and 4097 indices; and the ``validity_gathers``
+and ``row_gathers`` counters, of one gather and of the benchmark's plans
+at 2^14 rows (``PERF.md`` section 3 records the counts)."""
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,9 @@ from spark_rapids_jni_tpu.relational import gather
 
 N = 300
 KINDS = ("i32", "i64", "f64", "dec", "str", "dict", "rle", "for", "bits")
+# the matrix's own: int64 past 32 bits and below 0, a decimal in 64-bit
+# storage, float32 with NaN and -0.0
+WORD_KINDS = ("i64w", "d64", "f32")
 
 
 def _validity(rng, n, nulls):
@@ -39,6 +44,16 @@ def _column(rng, kind, n, nulls):
                       v, T.INT32)
     if kind == "f64":
         return Column(jnp.asarray(rng.normal(size=n)), v, T.FLOAT64)
+    if kind == "f32":
+        x = rng.normal(size=n).astype(np.float32)
+        x[::5], x[1::7], x[2::11] = np.nan, -0.0, np.inf
+        return Column(jnp.asarray(x), v, T.FLOAT32)
+    if kind in ("i64w", "d64"):
+        x = rng.integers(-(1 << 62), 1 << 62, n, dtype=np.int64)
+        x[::3] = rng.integers(-(1 << 33), 1 << 33, (n + 2) // 3)
+        x[:4] = [-1, 1 << 32, -(1 << 63), (1 << 63) - 1][:n]
+        return Column(jnp.asarray(x), v, T.INT64 if kind == "i64w"
+                      else T.SparkType.decimal(18, 2))
     if kind == "dec":
         limbs = rng.integers(0, 1 << 62, (n, 2)).astype(np.uint64)
         return Decimal128Column(jnp.asarray(limbs), v,
@@ -141,10 +156,114 @@ def test_an_empty_batch_and_an_empty_index():
 
 
 # ---------------------------------------------------------------------------
-# the counter
+# a row gather: the words as one matrix
 # ---------------------------------------------------------------------------
 
 ROWS = 4097   # one more than a group fetch: a row gather counts
+
+
+@pytest.fixture
+def small_sources(monkeypatch):
+    """Matrices out of sources of ``ROWS`` rows, not the 2^19 and more
+    that the chip's compiler lays out rows-minor."""
+    monkeypatch.setattr(gather, "_MATRIX_FROM_ROWS", ROWS)
+
+
+@pytest.mark.parametrize("rows,want", [((1 << 19) - 1, 3), (1 << 19, 1)])
+def test_a_matrix_takes_a_source_of_2_19_rows_or_more(rows, want):
+    rng = np.random.default_rng(8)
+    b = _batch(rng, ("i32", "i64w"), n=rows)
+    idx = _indices(rng, rows, ROWS, True)
+    before = gather.row_gathers()
+    _assert_per_column(b, idx, None)
+    # two gathers a column for the per-column reference beside
+    assert gather.row_gathers() - before == want + 4
+
+
+@pytest.mark.usefixtures("small_sources")
+@pytest.mark.parametrize("m", [4096, 4097])
+@pytest.mark.parametrize("nulls", ["mixed", "none", "all"])
+@pytest.mark.parametrize("masked", [False, True])
+def test_a_row_gather_of_every_representation(m, nulls, masked):
+    rng = np.random.default_rng(m)
+    b = _batch(rng, KINDS + WORD_KINDS, n=ROWS, nulls=nulls)
+    idx = _indices(rng, ROWS, m, True)
+    valid = jnp.asarray(rng.random(m) < 0.8) if masked else None
+    _assert_per_column(b, idx, valid)
+
+
+@pytest.mark.usefixtures("small_sources")
+@pytest.mark.parametrize("kind", ["i32", "i64", "i64w", "d64", "f32", "dec",
+                                  "dict", "str", "f64", "bits"])
+@pytest.mark.parametrize("beside", [(), ("str", "f64", "bits")])
+@pytest.mark.parametrize("masked", [False, True])
+def test_a_row_gather_of_each_representation(kind, beside, masked):
+    """Each alone (one column, then two) and beside the columns that
+    gather on their own."""
+    rng = np.random.default_rng(len(kind) + len(beside))
+    idx = _indices(rng, ROWS, ROWS, True)
+    valid = jnp.asarray(rng.random(ROWS) < 0.7) if masked else None
+    for kinds in ((kind,) + beside, (kind, kind) + beside):
+        _assert_per_column(_batch(rng, kinds, n=ROWS), idx, valid)
+
+
+@pytest.mark.usefixtures("small_sources")
+@pytest.mark.parametrize("m", [4096, 4097])
+def test_a_jitted_row_gather_is_the_eager_one(m):
+    rng = np.random.default_rng(6)
+    b = _batch(rng, ("i32", "i64w", "d64", "f32", "dec", "str", "dict",
+                     "f64", "bits"), n=ROWS)
+    idx = _indices(rng, ROWS, m, True)
+    valid = jnp.asarray(rng.random(m) < 0.6)
+    eager = _assert_per_column(b, idx, valid)
+    jitted = jax.jit(gather.gather_batch)(b, idx, valid)
+    for g, w in zip(jax.tree_util.tree_leaves(jitted),
+                    jax.tree_util.tree_leaves(eager)):
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+
+
+@pytest.mark.usefixtures("small_sources")
+@pytest.mark.parametrize("kinds,want,per_buffer", [
+    (("i32",), 1, 2),                 # a word and the validity word
+    (("i64",), 1, 2),                 # two halves and the validity word
+    (("dec",), 1, 2),                 # four limb words and the validity
+    (("f64",), 2, 2),                 # one word: the parent's gathers
+    (("f64", "f64"), 3, 3),           # one word: the parent's gathers
+    (("i32", "f64"), 2, 3),           # a matrix, the double on its own
+    (("str",), 2, 3),                 # lengths and validity; the chars
+    (("bits", "i32"), 3, 4),          # the bit-packed column on its own
+    (("i32", "dict", "d64", "f32"), 1, 5),   # six words
+    (("i64",) * 4, 2, 5),             # nine words: a matrix of eight, one
+    (("dec",) * 2, 2, 3),             # nine words
+    # 33 data and two validity words; with no data word the two
+    # validity words still make a matrix
+    (("i32",) * 33, 5, 34),
+])
+def test_gathers_made_by_one_row_gather(kinds, want, per_buffer):
+    rng = np.random.default_rng(7)
+    b = _batch(rng, kinds, n=ROWS)
+    idx = _indices(rng, ROWS, ROWS, False)
+    before = gather.row_gathers()
+    gather.gather_batch(b, idx)
+    assert gather.row_gathers() - before == want
+    # a fetch of at most 4096 indices is not a row gather
+    before = gather.row_gathers()
+    gather.gather_batch(b, _indices(rng, ROWS, 4096, False))
+    assert gather.row_gathers() == before
+    # with no data buffer a matrix may carry: one gather a buffer
+    words = gather._WORDS_PER_ROW
+    try:
+        gather._WORDS_PER_ROW = {}
+        before = gather.row_gathers()
+        gather.gather_batch(b, idx)
+        assert gather.row_gathers() - before == per_buffer
+    finally:
+        gather._WORDS_PER_ROW = words
+
+
+# ---------------------------------------------------------------------------
+# the counters
+# ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("packable,bitpacked,want", [
@@ -177,15 +296,18 @@ def test_the_count_only_rises():
 
 
 # the benchmark's plans at 2^14 rows, traced as the chip runs them (``auto``
-# answered by the sort engines), and the count the parent's per-column
-# gathers give (packing off): PERF.md section 3
-@pytest.mark.parametrize("config_name,packed,per_column", [
-    ("q95-join-agg", 10, 29),
-    ("tpch-q3", 8, 14),
-    ("tpch-q18", 11, 18),
-    ("q6-scan-agg", 0, 0),
+# answered by the sort engines): the validity buffers and the count the
+# per-column gathers give (packing off); the gathers, one a buffer (no
+# source of 2^19 rows, so no matrix), and with matrices out of sources of
+# more than 4096 rows: PERF.md section 3
+@pytest.mark.parametrize("config_name,packed,per_column,per_buffer,gathers", [
+    ("q95-join-agg", 10, 29, 39, 16),
+    ("tpch-q3", 8, 14, 22, 16),
+    ("tpch-q18", 11, 18, 29, 23),
+    ("q6-scan-agg", 0, 0, 0, 0),
 ])
-def test_the_plans_count(monkeypatch, config_name, packed, per_column):
+def test_the_plans_count(monkeypatch, config_name, packed, per_column,
+                         per_buffer, gathers):
     from benchmark import lib
 
     cfg, mod = lib.load_config(config_name, 14)
@@ -204,14 +326,19 @@ def test_the_plans_count(monkeypatch, config_name, packed, per_column):
             cp.fn.trace({n: inputs[n] for n in cp.input_names}, prebuilts)
         finally:
             cp.close()
-        return plan.plan_cache_metrics()["validity_gathers"]
+        m = plan.plan_cache_metrics()
+        return m["validity_gathers"], m["row_gathers"]
 
     for k, v in cfg["knobs"].items():
         config.set(k, v)
     try:
-        assert traced() == packed
+        assert traced() == (packed, per_buffer)
+        monkeypatch.setattr(gather, "_MATRIX_FROM_ROWS", ROWS)
+        assert traced() == (packed, gathers)
+        monkeypatch.setattr(gather, "_WORDS_PER_ROW", {})
+        assert traced() == (packed, per_buffer)
         monkeypatch.setattr(gather, "_PACKED", ())
-        assert traced() == per_column
+        assert traced()[0] == per_column
     finally:
         config.reset()
         plan.reset_plan_cache()

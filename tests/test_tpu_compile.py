@@ -145,14 +145,18 @@ def test_benchmark_plans_fit_two_in_flight(one_chip, monkeypatch,
 def _gathers(text):
     """``(scope path, rows of the operand read, rows of the result)`` of
     every gather in a lowered program's text
-    (``as_text(debug_info=True)``)."""
+    (``as_text(debug_info=True)``); a tensor's rows are its largest
+    dimension (a row gather's ``[words, rows]`` matrix has them last)."""
     import re
 
+    def rows(dims):
+        return max(int(d) for d in dims.split("x") if d)
+
     locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
-    return [(locs.get(m.group(3), ""), int(m.group(1)), int(m.group(2)))
+    return [(locs.get(m.group(3), ""), rows(m.group(1)), rows(m.group(2)))
             for m in re.finditer(
-                r'"stablehlo\.gather"\(.*\(tensor<(\d+)[x>].*'
-                r'-> tensor<(\d+)[x>].*loc\((#loc\d+)\)', text)]
+                r'"stablehlo\.gather"\(.*\(tensor<((?:\d+x)+)\w+>.*'
+                r'-> tensor<((?:\d+x)+)\w+>.*loc\((#loc\d+)\)', text)]
 
 
 def test_q95_plan_compiles_with_its_dense_joins_as_lookups(one_chip,
