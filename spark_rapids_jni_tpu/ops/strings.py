@@ -3,7 +3,8 @@
 The reference repo delegates plain string functions to libcudf (out of
 tree); the driver's string/regex-heavy config (BASELINE.md #4) names
 ``substring`` alongside the in-tree ``regexp`` fast path and
-``get_json_object``, so the Spark-exact substring lives here.
+``get_json_object``, so the Spark-exact substring lives here, and with it
+``like``, the kernel of a plan's ``LIKE`` / ``NOT LIKE`` filter.
 
 Semantics follow Spark's ``UTF8String.substringSQL`` (character-based,
 1-based positions, negative position counts from the end, window clamped
@@ -107,3 +108,64 @@ def substring(col: StringColumn, pos: int, length: int = -1) -> StringColumn:
     keep = in_str & (char_idx >= lo[:, None]) & (char_idx < e0[:, None])
     out, out_len = left_compact_rows(chars, keep)
     return StringColumn(out, jnp.where(validity, out_len, 0), validity)
+
+
+def like_segments(pattern: str) -> tuple:
+    """``(segments, free_start, free_end)`` of a ``LIKE`` pattern: the
+    literal texts between its ``%`` as UTF-8 bytes (empty ones, from
+    ``%%``, dropped), and whether the pattern begins and ends with ``%``.
+    ``_`` (one character) and the escape character ``\\`` are refused,
+    naming the pattern."""
+    for c, what in (("_", "'_' (one character)"), ("\\", "an escape")):
+        if c in pattern:
+            raise NotImplementedError(
+                f"LIKE pattern {pattern!r}: {what} is not supported; only "
+                "literal text between '%' is")
+    segs = tuple(p.encode("utf-8") for p in pattern.split("%") if p)
+    return segs, pattern.startswith("%"), pattern.endswith("%")
+
+
+def like(col: StringColumn, pattern: str):
+    """``bool[n]``: whether each row's string matches ``pattern`` under
+    Spark's ``LIKE`` (``%`` any run of characters), the rows' validity not
+    applied.
+
+    Each literal segment must be found in order, without overlap, after
+    the end of the one before; a pattern that does not begin (end) with
+    ``%`` anchors its first (last) segment at the string's start (end).
+    Found leftmost, a segment leaves the most room to the ones after it,
+    so one pass a segment decides every row exactly.  A segment's places
+    are ``m`` shifted byte comparisons over the padded ``[n, width]``
+    matrix, kept where the segment ends within the row's length; its
+    leftmost place is one lane reduction, in int16 where the width allows
+    (half the bytes of int32).  Matching bytes of UTF-8 is matching
+    characters: no segment can start inside a character, since a
+    continuation byte starts no character."""
+    segs, free_start, free_end = like_segments(pattern)
+    chars, lengths = col.chars, col.lengths
+    n, width = chars.shape
+    if not segs:   # '%' alone matches every string, '' the empty one
+        return jnp.ones((n,), jnp.bool_) if "%" in pattern else lengths == 0
+    if sum(len(s) for s in segs) > width:
+        return jnp.zeros((n,), jnp.bool_)
+    # a place, and a place past it by a segment, fit int16 below 2^14
+    place = jnp.int16 if width < 1 << 14 else jnp.int32
+    length = lengths.astype(place)[:, None]
+    found = jnp.ones((n,), jnp.bool_)
+    nxt = jnp.zeros((n, 1), place)   # where the next segment may start
+    for k, seg in enumerate(segs):
+        m = len(seg)
+        places = width - m + 1
+        at = jnp.arange(places, dtype=place)[None, :]
+        ok = (at >= nxt) & (at + m <= length)
+        for j, byte in enumerate(seg):
+            ok = ok & (chars[:, j:j + places] == byte)
+        if k == 0 and not free_start:
+            ok = ok & (at == 0)
+        if k == len(segs) - 1 and not free_end:
+            ok = ok & (at + m == length)
+        first = jnp.min(jnp.where(ok, at, place(width)), axis=1,
+                        keepdims=True)
+        found = found & (first[:, 0] < width)
+        nxt = first + m
+    return found

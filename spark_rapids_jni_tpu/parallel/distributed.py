@@ -442,16 +442,12 @@ def _sample_splitters(batch: ColumnBatch, key_names, P: int):
 
 def _local_sort_with_occ(shuffled: ColumnBatch, occ, key_names):
     """Local sort with dead shuffle slots last (shared epilogue)."""
-    from ..columnar import types as T
-    from ..columnar.column import Column
     from ..relational.sort import SortKey, sort_by
 
-    aug = shuffled.with_column(
-        "__occ", Column(occ.astype(jnp.int32), jnp.ones_like(occ), T.INT32))
-    out = sort_by(aug, [SortKey("__occ", ascending=False)]
-                  + [SortKey(k) for k in key_names])
-    occ_sorted = out["__occ"].data == 1
-    return out.select([n for n in out.names if n != "__occ"]), occ_sorted
+    out = sort_by(shuffled, [SortKey(k) for k in key_names], live=occ)
+    occ_sorted = jnp.arange(out.num_rows, dtype=jnp.int32) < jnp.sum(
+        occ.astype(jnp.int32))
+    return out, occ_sorted
 
 
 def distributed_sort(

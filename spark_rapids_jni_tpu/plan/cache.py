@@ -61,6 +61,9 @@ class PlanCache:
         self.topk_sorted_rows = 0
         # ... and the row slots its aggregates take in, summed
         self.agg_input_slots = 0
+        # ... and the char slots (rows x stored width) its string
+        # predicates scan
+        self.like_char_slots = 0
 
     def note_routes(self, routes) -> None:
         """Count a newly compiled plan's ``route:arithmetic:type``s."""
@@ -105,6 +108,12 @@ class PlanCache:
         summed over them, whatever share of the slots holds a live row."""
         with self._lock:
             self.agg_input_slots = int(slots)
+
+    def note_like_char_slots(self, slots: int) -> None:
+        """A plan was traced: the char slots (rows times the stored width)
+        that its ``LIKE`` filters scan, summed, 0 for a plan with none."""
+        with self._lock:
+            self.like_char_slots = int(slots)
 
     def _capacity(self) -> int:
         if self._maxsize is not None:
@@ -213,6 +222,7 @@ class PlanCache:
                 "row_gathers": self.row_gathers,
                 "topk_sorted_rows": self.topk_sorted_rows,
                 "agg_input_slots": self.agg_input_slots,
+                "like_char_slots": self.like_char_slots,
                 # int8 slots of the newest one-hot contraction traced
                 "onehot_slots": onehot_slots(),
             }

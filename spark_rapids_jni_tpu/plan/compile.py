@@ -8,10 +8,15 @@ The lowering rules are the hand-fused flagship pipelines, factored:
   late materialization preserved, no decode under jit.  Above an
   Aggregate (``HAVING``) the group count in front becomes ``arange <
   count`` and, with the predicate, a scattered mask; a decimal column
-  compares with an exact literal (``ir.Lit``) at the column's scale.
+  compares with an exact literal (``ir.Lit``) at the column's scale.  A
+  ``like`` / ``not_like`` on a string column is ``ops.strings.like``
+  over its padded bytes (``decisions``: ``filter<i>:<column>`` with the
+  op, the pattern and the route), under ``strings.like``.
 * Exchange -> the local shuffle leg (Spark-exact murmur3 pid + stable
   ``regroup_order``), dead rows routed to the trailing
-  pseudo-partition so live prefixes survive the permutation.
+  pseudo-partition so live prefixes survive the permutation.  Above an
+  Aggregate (a group-by over a group-by) it, and an Aggregate straight
+  above one, take the group count in front as ``arange < count``.
 * Exchange directly under an Aggregate on the same key FUSES, exactly
   the way ``_q95_prefix`` does: under the pinned sort group-by engine
   the group key's radix words ride the regroup sort as SECONDARY
@@ -34,7 +39,9 @@ The lowering rules are the hand-fused flagship pipelines, factored:
   and hands on ``match`` as the mask (``decisions``: ``"output":
   "mask"``, and ``"how"`` where it is not inner); at the root or under a
   Sort it compacts.  The build side may be an Aggregate's output, whose
-  group count becomes its ``right_valid``.
+  group count becomes its ``right_valid``.  A ``right`` (outer) join over a
+  dense domain takes the mask form too: the probe rows, then every build
+  row, live where no probe row matched it.
 * Aggregate -> ``group_by_onehot`` / ``group_by_domain_or_sort`` /
   general ``group_by`` by exactly the hand paths' dispatch (domain
   hints apply only to plain int keys; string/encoded keys run the
@@ -47,8 +54,9 @@ The lowering rules are the hand-fused flagship pipelines, factored:
   handed over unevaluated and computed inside its row slices.
 * Sort on exactly the keys of a composite-domain Aggregate below it is
   elided: that engine emits key order, nulls first.  Any other Sort is
-  one stable sort over the keys' radix words (a descending key's
-  complemented) and a gather of every column.
+  one sort over the keys' radix words packed end to end (a descending
+  key's complemented, a dead row's flag first, the row id last) and a
+  gather of every column.
 * TopK -> ``k`` rounds of selection over the order's radix words among
   the live rows (``relational.sort.top_k_rows``) and a gather of ``k``
   rows of every column: no row slot is sorted or moved, whatever the
@@ -71,7 +79,8 @@ import jax.numpy as jnp
 
 from .. import config, profiler
 from ..columnar import types as T
-from ..columnar.column import Column, ColumnBatch, Decimal128Column
+from ..columnar.column import Column, ColumnBatch, Decimal128Column, \
+    StringColumn
 from ..columnar.encoded import PACKED_COLUMNS, is_encoded, \
     packed_filter_mask, predicate_mask
 from . import adaptive, ir
@@ -178,6 +187,8 @@ def _filter_mask(col, op: str, value):
     u32 residual lanes for packed columns (``packed_filter_mask``:
     literal transformed once per frame, bit-identical to
     decode-then-compare, zero decodes on the fast path)."""
+    if op in ir.STRING_FILTER_OPS:
+        return _like_mask(col, op, value)
     fn = _FILTER_OPS[op]
     if isinstance(value, ir.Lit):
         return _decimal_filter_mask(col, op, value)
@@ -189,6 +200,19 @@ def _filter_mask(col, op: str, value):
         return predicate_mask(col, lambda d: fn(d.data, value))
     # a comparison with a null is null, and the row goes (as on codes)
     return fn(col.data, value) & col.validity
+
+
+def _like_mask(col, op: str, pattern: str):
+    """``col LIKE pattern`` (or ``NOT LIKE``) over a padded string column:
+    a null string gives null, and the row goes under either."""
+    from ..ops.strings import like
+
+    if not isinstance(col, StringColumn):
+        raise NotImplementedError(f"LIKE over {type(col).__name__}: only a "
+                                  "padded StringColumn")
+    with profiler.scope("strings.like"):
+        hit = like(col, pattern)
+        return (hit if op == "like" else ~hit) & col.validity
 
 
 def _decimal_filter_mask(col, op: str, lit: ir.Lit):
@@ -464,6 +488,8 @@ class _State:
         self.topk_sorted_rows = 0
         # row slots the plan's aggregates take in, summed
         self.agg_input_slots = 0
+        # char slots (rows x stored width) the plan's string predicates scan
+        self.like_char_slots = 0
 
 
 def node_scope(node: ir.PlanNode) -> str:
@@ -500,6 +526,8 @@ def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
         b, live, _pfx = _lower(node.child, env, prebuilts, st)
         with profiler.scope(node_scope(node)):
             mask = _filter_mask(b[node.column], node.op, node.value)
+            if node.op in ir.STRING_FILTER_OPS:
+                st.like_char_slots += b[node.column].chars.size
             # above an Aggregate (HAVING): its groups are in front
             live = _counted_rows(b, live)
             live = mask if live is None else live & mask
@@ -511,8 +539,9 @@ def _lower(node: ir.PlanNode, env: dict, prebuilts: tuple, st: _State):
 
     if isinstance(node, ir.Exchange):
         b, live, pfx = _lower(node.child, env, prebuilts, st)
-        _no_count(node, live)
         with profiler.scope(node_scope(node)):
+            if _is_count(live):   # above an Aggregate: its groups in front
+                live, pfx = _counted_rows(b, live), True
             live_arr = (jnp.ones((b.num_rows,), jnp.bool_) if live is None
                         else live)
             staged = _exchange_local(b, node.key, live_arr,
@@ -552,7 +581,8 @@ def _counted_rows(b: ColumnBatch, live):
 
 
 def _no_count(node: ir.PlanNode, live, side: str = "") -> None:
-    """A node that takes row masks only met an Aggregate's group count."""
+    """A node that takes row masks only (a Join's probe side) met an
+    Aggregate's group count."""
     if _is_count(live):
         raise TypeError(
             f"{type(node).__name__} ({node_scope(node)}) cannot take the "
@@ -568,31 +598,24 @@ def _sort_keys(node) -> list:
 
 
 def _lower_sort(node: ir.Sort, env, prebuilts, st):
-    from ..columnar import types as T
-    from ..relational.sort import SortKey, sort_by
+    from ..relational.sort import sort_by
 
     b, live, _pfx = _lower(node.child, env, prebuilts, st)
     # from an Aggregate: the count of live rows, which are in front
     count = live if _is_count(live) else None
-    if count is not None:
-        if id(node.child) in st.key_ordered \
-                and node.keys == node.child.keys:
-            return b, count, True   # already in this order: elided
-        live = jnp.arange(b.num_rows, dtype=jnp.int32) < count
+    if count is not None and id(node.child) in st.key_ordered \
+            and node.keys == node.child.keys:
+        return b, count, True   # already in this order: elided
     keys = _sort_keys(node)
     with profiler.scope(node_scope(node)):
+        if count is not None:
+            live = jnp.arange(b.num_rows, dtype=jnp.int32) < count
+        out = sort_by(b, keys, live)   # dead rows last
         if live is None:
-            return sort_by(b, keys), None, True
-        # dead rows last (same __occ trick as the distributed sort
-        # epilogue)
-        aug = b.with_column("__occ", Column(live.astype(jnp.int32),
-                                            jnp.ones_like(live), T.INT32))
-        out = sort_by(aug, [SortKey("__occ", ascending=False)] + keys)
-        n = out.num_rows
-        new_live = jnp.arange(n, dtype=jnp.int32) < jnp.sum(
+            return out, None, True
+        new_live = jnp.arange(out.num_rows, dtype=jnp.int32) < jnp.sum(
             live.astype(jnp.int32))
-        return (out.select([nm for nm in out.names if nm != "__occ"]),
-                new_live if count is None else count, True)
+        return out, new_live if count is None else count, True
 
 
 def _lower_topk(node: ir.TopK, env, prebuilts, st):
@@ -682,9 +705,10 @@ def _lower_aggregate(node: ir.Aggregate, env, prebuilts, st):
         # (as the regroup that orders its rows, or not at all)
         b, live, pfx = _lower(child.child if fuse else child, env,
                               prebuilts, st)
-    _no_count(node, live)
     st.agg_input_slots += b.num_rows
     with profiler.scope(node_scope(node)):
+        if _is_count(live):   # above an Aggregate: its groups are in front
+            live, pfx = _counted_rows(b, live), True
         return _aggregate(node, aggs, hint, child if fuse else None,
                           b, live, pfx, derive, st)
 
@@ -871,11 +895,14 @@ def _dense_domain(node: ir.Join, inputs: dict):
 
 # joins whose output, compacted or not, has the left side's row slots
 _ROW_KEEPING_JOINS = ("inner", "semi", "anti")
+# ... and those that may hand on a row mask: with the outer join that keeps
+# every build row, whose mask form has the build side's rows after them
+_MASKED_JOINS = _ROW_KEEPING_JOINS + ("right",)
 
 
 def _join_outputs(plan: ir.PlanNode, inputs: dict, decisions: dict) -> None:
     """Adds to each join's decision its output form: ``"mask"`` where the
-    join is an inner, semi or anti, shuffled one over a dense domain and
+    join is an inner, semi, anti or right, shuffled one over a dense domain and
     what consumes its rows takes a scattered row mask (an Exchange, an
     Aggregate, a Filter, a Join on either side: the build takes
     ``right_valid`` as the probe takes ``left_valid``; a Project hands its
@@ -900,7 +927,7 @@ def _join_outputs(plan: ir.PlanNode, inputs: dict, decisions: dict) -> None:
     for ji, (node, masked) in enumerate(joins):
         d = decisions[f"join{ji}:{node.left_on}"]
         d["output"] = "mask" if (
-            masked and node.how in _ROW_KEEPING_JOINS
+            masked and node.how in _MASKED_JOINS
             and d["strategy"] == "shuffled"
             and _dense_domain(node, inputs) is not None) else "compact"
         if node.how != "inner":
@@ -1017,6 +1044,7 @@ def compile_plan(plan: ir.PlanNode, inputs: dict, ctx=None,
                 get_plan_cache().note_row_gathers(row_gathers() - gathers)
                 get_plan_cache().note_topk_rows(st.topk_sorted_rows)
                 get_plan_cache().note_agg_input_slots(st.agg_input_slots)
+                get_plan_cache().note_like_char_slots(st.like_char_slots)
                 # from an Aggregate up ``live`` is the group count
                 return batch if live is None else (batch, live)
 
@@ -1065,12 +1093,22 @@ def _typed_decisions(plan: ir.PlanNode, inputs: dict) -> dict:
     it (``project<i>:<output>``), a Sort that the Aggregate below it
     makes redundant (``sort<i>:<keys>``), and an ordered limit's ``n``, its
     keys with direction and null placement and its route
-    (``topk<i>:<keys>``).  Plans with none of them get
+    (``topk<i>:<keys>``), and a string predicate's op, pattern and route
+    (``filter<i>:<column>``).  Plans with none of them get
     nothing, so their cache keys are what they were."""
+    from ..ops.strings import like_segments
+
     out = {}
-    pi = si = ti = 0
+    pi = si = ti = fi = 0
     for node in plan.walk():
-        if isinstance(node, ir.Project):
+        if isinstance(node, ir.Filter):
+            if node.op in ir.STRING_FILTER_OPS:
+                like_segments(node.value)   # refuses what it cannot match
+                out[f"filter{fi}:{node.column}"] = {
+                    "op": node.op, "pattern": node.value,
+                    "route": "strings.like"}
+            fi += 1
+        elif isinstance(node, ir.Project):
             below = _schema_at(node.child, inputs)
             seen = set()
             for name, expr in node.outputs():

@@ -20,7 +20,8 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
-FILTER_OPS = ("<", "<=", ">", ">=", "==", "!=")
+FILTER_OPS = ("<", "<=", ">", ">=", "==", "!=", "like", "not_like")
+STRING_FILTER_OPS = ("like", "not_like")
 JOIN_STRATEGIES = ("shuffled", "broadcast", "auto")
 ARITH_OPS = ("+", "-", "*")
 
@@ -173,6 +174,12 @@ class Filter(PlanNode):
     On a dictionary-encoded column the predicate evaluates over the
     d-entry dictionary once and pushes down onto codes
     (``predicate_mask``).
+
+    ``op`` ``'like'`` / ``'not_like'`` is SQL's ``LIKE`` / ``NOT LIKE`` of
+    a string column against a pattern ``value`` (a ``str``) with Spark's
+    semantics: ``%`` is any run of characters; ``_`` and escapes are
+    refused when the plan is lowered.  A null string gives null, and the
+    row goes, under either.
     """
 
     child: PlanNode
@@ -186,6 +193,9 @@ class Filter(PlanNode):
         if self.op not in FILTER_OPS:
             raise ValueError(f"unknown filter op {self.op!r}; "
                              f"known: {FILTER_OPS}")
+        if (self.op in STRING_FILTER_OPS) != isinstance(self.value, str):
+            raise ValueError(f"filter op {self.op!r} against {self.value!r}: "
+                             "a pattern is a str, and only LIKE takes one")
 
 
 @dataclass(frozen=True)
@@ -230,7 +240,15 @@ class Join(PlanNode):
     engine where the data says otherwise.  ``how='semi'`` / ``'anti'``
     (an ``IN`` / ``NOT EXISTS`` subquery as Spark plans one) keep the left
     rows whose key is, or is not, on the build side, and hand on no right
-    column; over a dense domain that is one lookup a row.  ``strategy``
+    column; over a dense domain that is one lookup a row.  ``how='right'``
+    is the outer join that keeps every build row: SQL's ``customer LEFT
+    OUTER JOIN orders`` written from the side whose keys are unique, with
+    ORDERS probing CUSTOMER; the output has the build side's columns, its
+    key among them, and the probe side's but its key, null where a build
+    row matched nothing.  Over a dense domain, and where its consumer
+    takes a row mask, it keeps the probe rows where they are and puts
+    every build row after them (``join_dense_or_hash``).  ``'left'`` and
+    ``'full'`` go to ``hash_join`` alone and compact.  ``strategy``
     picks the physical form: ``'shuffled'`` (the hand-q95 lowering),
     ``'broadcast'`` (spill-registered prebuilt build table +
     ``hash_join(prebuilt=)``), or ``'auto'`` (the adaptive layer

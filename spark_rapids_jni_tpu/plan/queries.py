@@ -14,6 +14,9 @@ shape Spark gives it: two filtered joins, one feeding the other's build
 side, a three-key group-by and an ordered limit.  ``tpch_q18_plan`` is
 TPC-H Q18: a group-by of one group an order under a ``HAVING``, the ``IN``
 subquery as a semi-join, two more joins, a second group-by and a top-100.
+``tpch_q13_plan`` is TPC-H Q13: a ``NOT LIKE`` over the order comments, an
+outer join whose unmatched customers count zero, and a group-by over a
+group-by.
 """
 
 from __future__ import annotations
@@ -250,3 +253,52 @@ def tpch_q18_plan(quantity: int = 300,
         keys=keys, aggs=(Agg("sum", "l_quantity", "sum_qty"),))
     return TopK(Project(volume, keys + ("sum_qty",)),
                 (Desc("o_totalprice"), "o_orderdate"), int(limit))
+
+
+# TPC-H C_CUSTKEY at scale factor 10: 1..1,500,000, dense
+TPCH_SF10_CUSTKEY_DOMAIN = 1_500_001
+
+
+def tpch_q13_plan(word1: str = "special", word2: str = "requests",
+                  custkey_domain: int = TPCH_SF10_CUSTKEY_DOMAIN) -> Sort:
+    """TPC-H Q13, the customer distribution query (specification clause
+    2.4.13, validation parameters WORD1 = special, WORD2 = requests)::
+
+        select c_count, count(*) as custdist
+        from (select c_custkey, count(o_orderkey) as c_count
+              from customer left outer join orders
+                on c_custkey = o_custkey
+                and o_comment not like '%special%requests%'
+              group by c_custkey) as c_orders
+        group by c_count
+        order by custdist desc, c_count desc
+
+    in the physical shape Spark plans it at scale factor 10: CUSTOMER
+    (1,500,000 keys, 12 MB) is over the broadcast threshold and the
+    preserved side of an outer join cannot be broadcast, so both sides are
+    exchanged on the customer key and sort-merge joined.  ORDERS is
+    filtered on its comment (the ``NOT LIKE`` belongs to the join's
+    condition, on the side that is not preserved, so it filters ORDERS
+    before the join) and pruned to its two keys; the outer join keeps
+    every customer, written from the side whose keys are unique
+    (``how='right'``: ORDERS probes CUSTOMER over the dense domain of its
+    keys).  ``count(o_orderkey)`` counts the matched orders, none for a
+    customer with no order, whose ``o_orderkey`` is null.  The rows are
+    then partitioned by the customer key, so the first aggregate follows
+    with no exchange; its counts are exchanged and aggregated again, and
+    sorted."""
+    orders = Project(
+        Filter(Scan("orders"), "o_comment", "not_like",
+               f"%{word1}%{word2}%"),
+        ("o_orderkey", "o_custkey"))
+    customer = Project(Scan("customer"), ("c_custkey",))
+    joined = Join(Exchange(orders, "o_custkey"),
+                  Exchange(customer, "c_custkey"),
+                  "o_custkey", "c_custkey", how="right",
+                  dense_domain=int(custkey_domain))
+    per_customer = Aggregate(joined, keys=("c_custkey",),
+                             aggs=(Agg("count", "o_orderkey", "c_count"),))
+    dist = Aggregate(Exchange(Project(per_customer, ("c_count",)), "c_count"),
+                     keys=("c_count",),
+                     aggs=(Agg("count", None, "custdist"),))
+    return Sort(dist, (Desc("custdist"), Desc("c_count")))

@@ -495,13 +495,29 @@ def join_dense_or_hash(
     dead row is dead by ``live`` alone) and ``live`` is the scattered
     ``match``; the general branch's rows are compacted by construction,
     its ``live`` a prefix.  The live rows are the same multiset either way.
+
+    ``how='right'`` with ``compact=False`` is the outer join that keeps
+    every build row, taken from the build side, whose keys are the unique
+    ones (SQL's ``customer LEFT OUTER JOIN orders`` with ORDERS probing
+    CUSTOMER): the output has ``left.num_rows + right.num_rows`` rows and
+    :func:`hash_join`'s ``'right'`` columns (the build side's, its key
+    among them, then the probe side's but its key).  The dense branch
+    leaves the probe rows where they are, each match live by ``live``
+    (the build columns fetched by row id, the key the probe's own value),
+    and puts every build row after them, its probe columns null, live
+    where no live probe row hit its key (``join.dense_outer``: one scatter
+    of the probe rows' hits over the domain); the general branch is
+    ``hash_join(..., 'right')`` compacted into as many rows (repeated build
+    keys whose matches pass them are cut there, as the inner form's are
+    past the probe side's rows).
     """
     if not compact and capacity is not None:
         raise ValueError("compact=False keeps the left side's rows: it "
                          "takes no capacity")
     lcol, rcol = left[left_on], right[right_on]
     presence = how in ("semi", "anti")   # is the key there: nothing fetched
-    eligible = ((how == "inner" or presence) and domain > 0
+    outer = how == "right" and not compact   # every build row kept
+    eligible = ((how == "inner" or presence or outer) and domain > 0
                 and not isinstance(lcol, (StringColumn, Decimal128Column,
                                           DictionaryColumn, RunLengthColumn,
                                           BitPackedColumn,
@@ -512,7 +528,9 @@ def join_dense_or_hash(
                                           FrameOfReferenceColumn))
                 and jnp.issubdtype(lcol.data.dtype, jnp.integer)
                 and jnp.issubdtype(rcol.data.dtype, jnp.integer)
-                and right.num_rows > 0)
+                and right.num_rows > 0 and (left.num_rows > 0 or not outer))
+    if outer:   # every probe row, then every build row
+        capacity = left.num_rows + right.num_rows
     if not eligible:
         out, total = hash_join(left, right, [left_on], [right_on], how,
                                capacity=capacity, suffixes=suffixes,
@@ -562,6 +580,27 @@ def join_dense_or_hash(
         with scope("join.dense_compact"):
             return _compact_rows(left, keep)
 
+    def dense_outer(lk_safe, match, fetched):
+        """The probe rows where they are, each match with its build row's
+        columns (``fetched``), then every build row."""
+        with scope("join.dense_outer"):
+            # the build rows no live probe row hit: the probe's matches
+            # scattered over the domain, read back at each build key
+            hits = jnp.zeros((K1 + 1,), jnp.int32).at[
+                jnp.where(match, lk_safe, K1)].add(1)
+            hit = in_dom & (hits[jnp.where(in_dom, rk, 0)] > 0)
+            unmatched = rv & ~hit
+        key = Column(lcol.data.astype(rcol.data.dtype), match, rcol.dtype)
+        probe_side = dict(zip(fetched.names, fetched.columns),
+                          **{right_on: key})
+        rpart = ColumnBatch({n: _concat_col(probe_side[n], right[n])
+                             for n in right.names})
+        lsel = left.select([n for n in left.names if n != left_on])
+        lpart = _concat_batches(lsel, gather_batch(
+            lsel, jnp.zeros((nr,), jnp.int32), jnp.zeros((nr,), jnp.bool_)))
+        return (_merge_parts(rpart, lpart, (suffixes[1], suffixes[0])),
+                jnp.concatenate([match, unmatched]))
+
     def dense(_):
         if presence:
             return dense_presence()
@@ -582,6 +621,8 @@ def join_dense_or_hash(
                 ri = rowid[lk_safe]
             with scope("join.gather_right"):
                 rpart = gather_batch(right_sel, ri, match)
+            if outer:
+                return dense_outer(lk_safe, match, rpart)
             # the left columns are taken after the cond: zeros stand in
             lpart = jax.tree_util.tree_map(jnp.zeros_like, left)
             return _merge_parts(lpart, rpart, suffixes), match
@@ -610,7 +651,7 @@ def join_dense_or_hash(
             return out, (total if compact else _prefix_live(out, total))
 
     out, live = jax.lax.cond(dense_ok, dense, general, None)
-    if compact:
+    if compact or outer:
         return out, live
     # The dense branch's left columns are the caller's, selected here and
     # not handed out of the cond: what a cond hands out the compiler keeps

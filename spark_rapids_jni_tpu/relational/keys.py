@@ -229,6 +229,13 @@ def packed_radix_keys(cols: Sequence, *, lead_flags: Sequence = (),
         fields.extend(
             (jnp.where(v, k, jnp.zeros((), k.dtype)), 32)
             for k in column_radix_keys(c, equality=equality))
+    return pack_fields(fields)
+
+
+def pack_fields(fields: Sequence) -> list:
+    """``(uint32 array, bits)`` fields laid end to end, the first the most
+    significant, and cut into uint32 words, the last padded with zeros: the
+    packed words' unsigned lexicographic order is the fields'."""
     total = sum(bits for _f, bits in fields)
     words = [None] * (-(-total // 32))
 

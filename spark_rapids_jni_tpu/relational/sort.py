@@ -2,9 +2,10 @@
 
 Spark semantics: per-key ascending/descending and nulls-first/last.  The key
 lowering (:mod:`keys`) yields uint32 arrays whose unsigned lexicographic
-order is Spark's; descending keys are bitwise-complemented.  ``lax.sort``
-with ``num_keys=len(keys)+1`` co-sorts an iota operand that becomes the row
-permutation — XLA lowers this to its vectorized bitonic sorter on TPU.
+order is Spark's; descending keys are bitwise-complemented.  The words are
+packed end to end and ``lax.sort`` sorts them with an iota as the last key,
+which becomes the row permutation — XLA lowers this to its vectorized
+bitonic sorter on TPU.
 """
 
 from __future__ import annotations
@@ -27,39 +28,63 @@ class SortKey:
     nulls_first: bool = True
 
 
-def order_words(batch: ColumnBatch, sort_keys: Sequence[SortKey]) -> list:
-    """uint32 arrays whose unsigned lexicographic ascending order is the
-    order of ``sort_keys``."""
-    ops = []
+def order_fields(batch: ColumnBatch, sort_keys: Sequence[SortKey]) -> list:
+    """``(uint32 array, bits)`` fields whose unsigned lexicographic
+    ascending order is the order of ``sort_keys``: each key's null flag, a
+    0/1 in one bit, then its radix words in 32 bits each.  A descending
+    key's fields are complemented whole, so its flag is ``~flag``, whose
+    lowest bit is the complemented bit."""
+    fields = []
     for sk in sort_keys:
         col = batch[sk.name]
         # Spark default: nulls first when ascending, last when descending;
         # callers pass the explicit flag.  Descending complements key bits,
         # including the null flag, so compute the flag for ascending order.
         flag_first = sk.nulls_first if sk.ascending else not sk.nulls_first
-        arrays = [K.null_flag(col, flag_first)]
-        # zero null rows' data keys: deterministic (stable) order among nulls
-        arrays += [
-            jnp.where(col.validity, k, jnp.zeros((), k.dtype))
+        part = [(K.null_flag(col, flag_first), 1)]
+        # zero null rows' data keys: deterministic order among nulls
+        part += [
+            (jnp.where(col.validity, k, jnp.zeros((), k.dtype)), 32)
             for k in K.column_radix_keys(col, equality=False)
         ]
         if not sk.ascending:
-            arrays = [~a for a in arrays]
-        ops.extend(arrays)
-    return ops
+            part = [(~f, bits) for f, bits in part]
+        fields.extend(part)
+    return fields
 
 
-def sort_permutation(batch: ColumnBatch, sort_keys: Sequence[SortKey]):
-    """int32[n] permutation ordering the batch by the given keys (stable)."""
-    ops = order_words(batch, sort_keys)
-    n = batch.num_rows
-    iota = jnp.arange(n, dtype=jnp.int32)
-    res = jax.lax.sort(tuple(ops) + (iota,), num_keys=len(ops), is_stable=True)
-    return res[-1]
+def order_words(batch: ColumnBatch, sort_keys: Sequence[SortKey]) -> list:
+    """uint32 arrays whose unsigned lexicographic ascending order is the
+    order of ``sort_keys``: :func:`order_fields`, a word each."""
+    return [f for f, _bits in order_fields(batch, sort_keys)]
 
 
-def sort_by(batch: ColumnBatch, sort_keys: Sequence[SortKey]) -> ColumnBatch:
-    return gather_batch(batch, sort_permutation(batch, sort_keys))
+def sort_permutation(batch: ColumnBatch, sort_keys: Sequence[SortKey],
+                     live=None):
+    """int32[n] permutation ordering the batch by ``sort_keys``, rows equal
+    in every key in their own order (what a stable sort gives), and the
+    rows that ``live`` (bool[n]) says are dead after every live one.
+
+    One unstable sort: :func:`order_fields` laid end to end
+    (:func:`keys.pack_fields`: a null flag one bit, a radix word its 32,
+    a dead row's flag leading), with the row id as the last key, which
+    makes the order total.  Two int64 keys are 5 words where they were 6,
+    and the v5e compiler's time for a sort grows with its key operands
+    (PERF.md section 6, PR 35)."""
+    fields = [] if live is None else [
+        ((~live.astype(jnp.bool_)).astype(jnp.uint32), 1)]
+    fields += [(f & jnp.uint32(1) if bits == 1 else f, bits)
+               for f, bits in order_fields(batch, sort_keys)]
+    words = K.pack_fields(fields)
+    iota = jnp.arange(batch.num_rows, dtype=jnp.int32)
+    return jax.lax.sort(tuple(words) + (iota,), num_keys=len(words) + 1,
+                        is_stable=False)[-1]
+
+
+def sort_by(batch: ColumnBatch, sort_keys: Sequence[SortKey],
+            live=None) -> ColumnBatch:
+    """The batch's rows in :func:`sort_permutation`'s order."""
+    return gather_batch(batch, sort_permutation(batch, sort_keys, live))
 
 
 def top_k_rows(batch: ColumnBatch, sort_keys: Sequence[SortKey], k: int,

@@ -521,22 +521,41 @@ def test_an_aggregates_output_is_a_build_side(how):
 
 @pytest.mark.parametrize("node", ["exchange", "join_probe", "aggregate"])
 def test_a_group_count_where_a_row_mask_is_needed_is_a_type_error(node):
-    """An Exchange, a Join's probe side or an Aggregate straight above an
-    Aggregate would and a count with a mask: refused, by name."""
+    """A Join's probe side straight above an Aggregate would and a count
+    with a mask: refused, by name.  An Exchange or an Aggregate above one
+    (a group-by over a group-by, TPC-H Q13) takes the group count as the
+    mask of the rows in front: the same rows as a Filter that keeps every
+    group makes."""
     batch = _fact()
     sums = ir.Aggregate(ir.Scan("t"), ("k",), (ir.Agg("sum", "v", "s"),))
-    bad = {"exchange": ir.Exchange(sums, "k"),
-           "join_probe": ir.Join(sums, ir.Scan("t"), "k", "k"),
-           "aggregate": ir.Aggregate(sums, ("s",),
-                                     (ir.Agg("count", None, "c"),))}[node]
-    with pytest.raises(TypeError, match="output of an Aggregate"):
-        plan.execute(bad, {"t": batch})
-    # a Filter above the Aggregate makes the mask they take
-    ok = {"exchange": lambda f: ir.Exchange(f, "k"),
-          "join_probe": lambda f: ir.Join(f, ir.Scan("t"), "k", "k"),
-          "aggregate": lambda f: ir.Aggregate(
-              f, ("s",), (ir.Agg("count", None, "c"),))}[node]
-    plan.execute(ok(ir.Filter(sums, "k", ">=", 0)), {"t": batch})
+    make = {"exchange": lambda f: ir.Exchange(f, "k"),
+            "join_probe": lambda f: ir.Join(f, ir.Scan("t"), "k", "k"),
+            "aggregate": lambda f: ir.Aggregate(
+                f, ("s",), (ir.Agg("count", None, "c"),))}[node]
+    # a Filter above the Aggregate makes a mask that every node takes
+    every = make(ir.Filter(sums, "k", ">=", 0))
+    want = plan.execute(every, {"t": batch})
+    if node == "join_probe":
+        with pytest.raises(TypeError, match="output of an Aggregate"):
+            plan.execute(make(sums), {"t": batch})
+        return
+    got = plan.execute(make(sums), {"t": batch})
+    if node == "exchange":   # (batch, live): the same live rows
+        def rows(out):
+            b, live = out
+            live = np.asarray(live)
+            return sorted(zip(*(
+                [v for v, ok in zip(b[c].to_pylist(), live) if ok]
+                for c in b.names)), key=repr)
+
+        assert rows(got) == rows(want)
+    else:                    # (result, num_groups): the same groups
+        def groups(out):
+            res, n = out
+            return sorted(zip(res["s"].to_pylist()[:int(n)],
+                              res["c"].to_pylist()[:int(n)]), key=repr)
+
+        assert groups(got) == groups(want)
 
 
 # ---------------------------------------------------------------------------

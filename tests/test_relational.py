@@ -95,6 +95,43 @@ class TestSort:
         out = sort_by(b, [SortKey("a", nulls_first=False)])
         assert out.to_pydict()["a"] == [-(2**62), -7, 0, 7, 2**62, None]
 
+    @pytest.mark.parametrize("jitted", [False, True])
+    @pytest.mark.parametrize("with_live", [False, True])
+    @pytest.mark.parametrize("ascending,nulls_first", [
+        (True, True), (True, False), (False, True), (False, False)])
+    def test_two_keys_and_dead_rows_against_python(
+            self, ascending, nulls_first, with_live, jitted):
+        """An int64 key and a float64 key in the other direction, both with
+        nulls and repeats, and the rows ``live`` says are dead: the rows in
+        Python's stable order of the keys, the dead ones after every live
+        one and in that order among themselves."""
+        import jax
+
+        rng = np.random.default_rng(11)
+        n = 96
+        a = [None if rng.random() < 0.2 else int(rng.integers(-3, 3)) << 40
+             for _ in range(n)]
+        f = [None if rng.random() < 0.2 else float(rng.integers(-2, 2))
+             for _ in range(n)]
+        live = rng.random(n) < 0.7 if with_live else np.ones(n, bool)
+        b = ColumnBatch({"a": ints(a, T.INT64),
+                         "f": Column.from_pylist(f, T.FLOAT64),
+                         "i": ints(list(range(n)))})
+        keys = [SortKey("a", ascending, nulls_first),
+                SortKey("f", not ascending, nulls_first)]
+        run = (lambda b, m: sort_by(b, keys, m))
+        if jitted:
+            run = jax.jit(run)
+        out = run(b, jnp.asarray(live) if with_live else None)
+
+        def part(v, asc):
+            null_rank = (v is None) != nulls_first
+            return (null_rank, 0 if v is None else (v if asc else -v))
+
+        want = sorted(range(n), key=lambda i: (
+            not live[i], part(a[i], ascending), part(f[i], not ascending)))
+        assert out.to_pydict()["i"] == want
+
 
 # ---------------------------------------------------------------------------
 # filter

@@ -329,7 +329,8 @@ class TestScopes:
             == ["mask", "mask"]
 
     @pytest.mark.parametrize("config_name", [
-        "q6-scan-agg", "q95-join-agg", "tpch-q1", "tpch-q3", "tpch-q18"])
+        "q6-scan-agg", "q95-join-agg", "tpch-q1", "tpch-q3", "tpch-q18",
+        "tpch-q13"])
     def test_every_operation_of_a_cells_plan_has_a_node_scope(
             self, config_name, monkeypatch):
         """A benchmark configuration's plan as its cell runs it (its knobs,
