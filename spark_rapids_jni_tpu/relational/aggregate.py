@@ -37,7 +37,12 @@ back to the input's rows, for the narrowest ``w`` of a short ladder
 row count, then every row) that holds the data's groups
 (``lax.switch`` on how many widths ``num_groups`` exceeds; a gather
 costs by the index, 33-47 ms a buffer of 2^22 on a v5e, PERF.md section
-5, and a result of ten groups needs ten, one of 11,000 no 6.0 M).
+5, and a result of ten groups needs ten, one of 11,000 no 6.0 M).  A
+width's branch reads every value it needs at the group ends in one
+fetch, and the keys at the groups' first rows in another: past 4096
+indices out of 2^19 rows or more, each as ``[words, w]`` matrices of up
+to eight u32 words (:func:`relational.gather.gather_rows`), since a
+gather costs by its indices and hardly by its width.
 
 Output is padded to the input row count with a device ``num_groups``
 scalar (same discipline as :mod:`filter`); groups appear in key-sorted
@@ -77,7 +82,8 @@ from ..columnar.encoded import (
 from .. import profiler
 from ..profiler import scope
 from . import keys as K
-from .gather import count_validity_gather, gather_column, take_rows
+from .gather import (gather_batch, gather_column, gather_ops, gather_rows,
+                     take_rows)
 
 _OPS = ("sum", "count", "min", "max", "mean")
 
@@ -385,17 +391,29 @@ def sortscan_head(num_rows: Optional[int] = None) -> int:
 
 
 def rowwide_gathers() -> int:
-    """Gathers of one index a row, over inputs of more rows than the
-    head, that the sort engine has traced in this process outside the
-    branches that fetch its scans (the row-wide one among them).  The
-    plan compiler notes a plan's share
+    """Gather operations of one index a row (a matrix of words one), over
+    inputs of more rows than the head, that the sort engine has traced in
+    this process outside the branches that fetch its scans.  The plan
+    compiler notes a plan's share
     (``plan.plan_cache_metrics()["agg_rowwide_gathers"]``)."""
     return _ROWWIDE_GATHERS[0]
 
 
-def _note_rowwide_gather(n: int) -> None:
+def _rowwide(n: int, gather):
+    """``gather()`` of one index a row of ``n``, its gather operations
+    counted as the sort engine's row-wide ones where ``n`` is more than
+    the head."""
+    before = gather_ops()
+    got = gather()
     if n > sortscan_head(n):
-        _ROWWIDE_GATHERS[0] += 1
+        _ROWWIDE_GATHERS[0] += gather_ops() - before
+    return got
+
+
+def _agg_data(col):
+    """The data buffer of an aggregated column: a decimal's limbs, or the
+    values."""
+    return col.limbs if isinstance(col, Decimal128Column) else col.data
 
 
 def _sort_wide_keys(karr, narrow, occ):
@@ -492,8 +510,8 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
     # post-sort gathers, but every 64-bit operand is an emulated u32 pair
     # inside the TPU sort network.  'gather' (the default): the sort
     # carries only [keys..., row-id] and each agg column is fetched
-    # afterwards with one take() along the permutation: 33-47 ms a
-    # u32-sized buffer of 2^22 rows on a v5e (PERF.md section 5).
+    # afterwards along the permutation, every column's data and
+    # validity together (relational.gather.gather_rows).
     from .. import config as _config
 
     ride = (not assume_grouped
@@ -579,9 +597,10 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                    + [f"agg.sortscan_tier.{w}" for w in tiers[1:-1]]
                    + ["agg.sortscan_full"])
 
-    def per_group(read, pad=lambda a: _pad_leading(a, n - a.shape[0])):
+    def per_group(read, pad):
         """``read(w)``: per-group values at the first ``w`` group slots;
-        padded to the n rows of the result where ``w`` is under them."""
+        padded by ``pad`` to the n rows of the result where ``w`` is under
+        them."""
         if len(tiers) == 1:
             return read(n)
 
@@ -595,33 +614,20 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
         return jax.lax.switch(
             tier, [at(w, name) for w, name in zip(tiers, tier_scopes)], None)
 
-    def at_ends(run):
-        """A segmented scan's value at each group's last row."""
-        return per_group(lambda w: run[ends[:w]])
+    first = iota == 0
 
-    def at_ends_diff(cs):
-        """Per-group total from a prefix scan: cs[end_g] - cs[end_{g-1}]."""
-        def read(w):
-            ce = cs[ends[:w]]
-            cp = jnp.where(iota[:w] == 0, jnp.zeros((), cs.dtype),
-                           cs[prev_ends[:w]])
-            return ce - cp
-
-        return per_group(read)
-
-    def ends_diff(cs, w):
-        """:func:`at_ends_diff`'s read with one gather: the scan at the
-        group before is the scan at this group's end, moved one slot.
+    def ends_diff(ce):
+        """Per-group totals from a prefix scan read at the group ends
+        (``ce``): the scan at the group before is ``ce`` moved one slot.
         A 64-bit scan is moved and subtracted as its 32-bit halves, with
         the borrow: moved whole, the v5e compiler's program lost the high
         half of the scan at the group before at one slot in every 79,872
         (sums off by multiples of 2^32 once the scan had passed 2^32:
         PERF.md section 6, PR 37; the ``tpch-q18.served`` cell holds it)."""
-        ce = cs[ends[:w]]
-        first = iota[:w] == 0
+        at_first = first[:ce.shape[0]]
 
         def before(x):
-            return jnp.where(first, jnp.zeros((), x.dtype), jnp.roll(x, 1))
+            return jnp.where(at_first, jnp.zeros((), x.dtype), jnp.roll(x, 1))
 
         if ce.dtype.itemsize < 8:
             return ce - before(ce)
@@ -629,64 +635,82 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
         hi_b, lo_b = before(hi), before(lo)
         d_hi = hi - hi_b - (lo < lo_b).astype(jnp.uint32)
         return ((d_hi.astype(jnp.uint64) << jnp.uint64(32))
-                | (lo - lo_b).astype(jnp.uint64)).astype(cs.dtype)
+                | (lo - lo_b).astype(jnp.uint64)).astype(ce.dtype)
 
-    def in_order(arr):
-        """A column's buffer in sorted row order: its own under
-        ``assume_grouped``, else one gather of all n rows."""
-        if assume_grouped:
-            return arr
-        _note_rowwide_gather(n)
-        return take_rows(arr, sperm)
+    def itself(ce):
+        return ce
 
     with scope("agg.sortscan_reduce"):
-        out = {}
-        starts = jnp.clip(jnp.where(iota == 0, 0, prev_ends + 1), 0, n - 1)
-
-        def first_rows(w):
-            """Each key column at its group's first row."""
-            rows0 = starts[:w] if assume_grouped else sperm[starts[:w]]
-            return [gather_column(batch[name], rows0, out_valid[:w])
-                    for name in key_names]
-
-        if all(isinstance(batch[name], _HEAD_KEY_TYPES)
-               for name in key_names):
-            firsts = per_group(
-                first_rows, pad=lambda cols: [_pad_rows(c, n) for c in cols])
-        else:   # a representation with no null-row padding of its own
-            _note_rowwide_gather(n)
-            firsts = first_rows(n)
-        out.update(zip(key_names, firsts))
+        # the aggregated columns in sorted row order: where they lie under
+        # assume_grouped, or riding the sort; else every column's data (a
+        # decimal in 64-bit storage narrow, widened after) and validity
+        # moved along the permutation together
+        sdata, svalidity = {}, {}
+        for name in agg_cols:
+            if assume_grouped:
+                sdata[name] = _agg_data(batch[name])
+                svalidity[name] = batch[name].validity
+            elif name in spans:   # payload[0] is iota (== sperm)
+                sdata[name] = spay[spans[name] - 1]
+                svalidity[name] = spay[spans[name]]
+        moved = [name for name in agg_cols if name not in svalidity]
+        with_data = [name for name in moved
+                     if any(spec.column == name and spec.op != "count"
+                            for spec in aggs)]
+        if moved:
+            data, valid = _rowwide(n, lambda: gather_rows(
+                [_agg_data(batch[name]) for name in with_data],
+                [batch[name].validity for name in moved], sperm))
+            sdata.update(zip(with_data, data))
+            svalidity.update(zip(moved, valid))
 
         def sorted_valid(name):
-            if not assume_grouped:
-                count_validity_gather(n)
-            return in_order(batch[name].validity) & sorted_occ
+            return svalidity[name] & sorted_occ
 
-        def sorted_col(name):
-            if name in spans:
-                off = spans[name]
-                data = spay[off - 1]  # payload[0] is iota (== sperm)
-                valid = spay[off] & sorted_occ
-                return data, valid
-            return in_order(batch[name].data), sorted_valid(name)
+        # Every value read at the group ends comes from one fetch: the
+        # arrays ``reads`` at ``ends[:w]`` (relational.gather.gather_rows:
+        # their words as matrices in a row gather), and each value a
+        # function of its reads, computed inside the fetch's branch.
+        reads, values = [], []
 
-        for spec in aggs:
+        def at_ends(fn, *arrays):
+            """The index, among the fetched values, of ``fn`` of
+            ``arrays`` at the group ends."""
+            values.append((fn, len(reads), len(arrays)))
+            reads.extend(arrays)
+            return len(values) - 1
+
+        made = {}
+
+        def once(key, make):
+            """``make()``'s index, made once for every aggregate that
+            reads the same value (``key``)."""
+            if key not in made:
+                made[key] = make()
+            return made[key]
+
+        def counts(name):
+            """Each group's live rows (``None``) or non-null values of
+            ``name``, int32: a count never passes the int32 row ids."""
+            def make():
+                flags = sorted_occ if name is None else sorted_valid(name)
+                return at_ends(ends_diff, K.scan_sum(flags.astype(jnp.int32)))
+
+            return once(("count", name), make)
+
+        def planned(spec):
+            """What ``spec`` reads at the group ends; returns what makes
+            its column of the fetched values."""
             if spec.op == "count":
-                if spec.column is None:
-                    ones = sorted_occ.astype(jnp.int64)
-                else:
-                    ones = sorted_valid(spec.column).astype(jnp.int64)
-                out[spec.out_name] = Column(at_ends_diff(K.scan_sum(ones)),
-                                            out_valid, T.INT64)
-                continue
+                h = counts(spec.column)
+                return lambda got: Column(got[h].astype(jnp.int64),
+                                          out_valid, T.INT64)
 
             dcol = batch[spec.column]
-            if _is_decimal(dcol.dtype) and spec.op in ("sum", "mean"):
-                # Spark types sum(decimal(p,s)) decimal(p+10,s) whatever
-                # stores the column: a 64-bit one sums on the same limbs
-                dcol = _widen_decimal(dcol)
-            if isinstance(dcol, Decimal128Column):
+            # Spark types sum(decimal(p,s)) decimal(p+10,s) whatever stores
+            # the column: a 64-bit one sums on the same limbs
+            if isinstance(dcol, Decimal128Column) or (
+                    _is_decimal(dcol.dtype) and spec.op in ("sum", "mean")):
                 # Decimal128 aggregation over sorted runs.  sum/mean: exact
                 # 256-bit segmented sums (values sign-extend to uint32[n,8]; a
                 # 2^31-row group of |v|<2^127 stays < 2^158, never wraps) —
@@ -700,10 +724,15 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                 from ..ops import decimal as D
 
                 svalid = sorted_valid(spec.column)
-                slimbs = in_order(dcol.limbs)
-                counts = K.scan_sum(svalid.astype(jnp.int32))
-                nn_d = per_group(lambda w: ends_diff(counts, w))
-                has_any_d = out_valid & (nn_d > 0)
+                slimbs = sdata[spec.column]
+                if slimbs.ndim == 1:   # in 32- or 64-bit storage
+                    slimbs = _widen_decimal(
+                        Column(slimbs, svalid, dcol.dtype)).limbs
+                h_nn = counts(spec.column)
+
+                def has_any(got):
+                    return out_valid & (got[h_nn] > 0)
+
                 if spec.op in ("min", "max"):
                     if spec.op == "min":  # fill nulls with +max signed 128
                         flo = jnp.uint64(0xFFFFFFFFFFFFFFFF)
@@ -714,11 +743,10 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                     lo = jnp.where(svalid, slimbs[:, 0], flo)
                     hi = jnp.where(svalid, slimbs[:, 1], fhi)
                     rlo, rhi = _seg_scan_minmax128(lo, hi, boundary, spec.op)
-                    out[spec.out_name] = Decimal128Column(
-                        per_group(lambda w: jnp.stack(
-                            [rlo[ends[:w]], rhi[ends[:w]]], axis=1)),
-                        has_any_d, dcol.dtype)
-                    continue
+                    h = at_ends(lambda a, b: jnp.stack([a, b], axis=1),
+                                rlo, rhi)
+                    return lambda got: Decimal128Column(got[h], has_any(got),
+                                                        dcol.dtype)
                 # Each 32-bit lane of the two's-complement value is summed
                 # on its own as a 64-bit prefix scan (2^31 rows of < 2^32
                 # stay under 2^63) and read at the group ends; a negative
@@ -728,40 +756,48 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                 # 256-bit associative scan gave the same sums; the v5e
                 # compiler had not built it for 2^20 rows after 50
                 # minutes of CPU time and 11 GB: PERF.md section 6, PR 35.)
-                live = jnp.where(svalid[:, None], slimbs,
-                                 jnp.zeros((), jnp.uint64))
                 m32 = jnp.uint64(0xFFFFFFFF)
-                scans = [K.scan_sum(x) for x in (
-                    live[:, 0] & m32, live[:, 0] >> jnp.uint64(32),
-                    live[:, 1] & m32, live[:, 1] >> jnp.uint64(32))]
-                negatives = K.scan_sum(
-                    (live[:, 1] >> jnp.uint64(63)).astype(jnp.int32))
 
-                def lane_sums(w):
-                    low4 = [ends_diff(cs, w) for cs in scans]
-                    ext = ends_diff(negatives, w).astype(jnp.uint64) * m32
+                def lane_sums(*ce):
+                    low4 = [ends_diff(c) for c in ce[:4]]
+                    ext = ends_diff(ce[4]).astype(jnp.uint64) * m32
                     return _carry_fold_u64_lanes(
                         jnp.stack(low4 + [ext] * 4, axis=1))
 
-                s256 = per_group(lane_sums)
-                if spec.op == "mean":
-                    limbs128, ok, out_t = _decimal_avg(s256, nn_d, dcol.dtype)
-                    out[spec.out_name] = Decimal128Column(
-                        limbs128, has_any_d & ok, out_t)
-                    continue
-                out_p = min(38, dcol.dtype.precision + 10)
-                mag, _ = D._abs(s256)
-                overflow = ~D._lt_u(mag, jnp.broadcast_to(D._pow10(out_p),
-                                                          mag.shape))
-                out[spec.out_name] = Decimal128Column(
-                    D._to_i128(s256), has_any_d & ~overflow,
-                    T.SparkType.decimal(out_p, dcol.dtype.scale))
-                continue
+                def sums():
+                    live = jnp.where(svalid[:, None], slimbs,
+                                     jnp.zeros((), jnp.uint64))
+                    scans = [K.scan_sum(x) for x in (
+                        live[:, 0] & m32, live[:, 0] >> jnp.uint64(32),
+                        live[:, 1] & m32, live[:, 1] >> jnp.uint64(32))]
+                    negatives = K.scan_sum(
+                        (live[:, 1] >> jnp.uint64(63)).astype(jnp.int32))
+                    return at_ends(lane_sums, *scans, negatives)
 
-            data, valid = sorted_col(spec.column)
-            col_dtype = batch[spec.column].dtype
-            nn = at_ends_diff(K.scan_sum(valid.astype(jnp.int32)))
-            has_any = nn > 0
+                h = once(("lanes", spec.column), sums)
+                if spec.op == "mean":
+                    def mean(got):
+                        limbs128, ok, out_t = _decimal_avg(got[h], got[h_nn],
+                                                           dcol.dtype)
+                        return Decimal128Column(limbs128, has_any(got) & ok,
+                                                out_t)
+
+                    return mean
+                out_p = min(38, dcol.dtype.precision + 10)
+
+                def total(got):
+                    mag, _ = D._abs(got[h])
+                    overflow = ~D._lt_u(mag, jnp.broadcast_to(
+                        D._pow10(out_p), mag.shape))
+                    return Decimal128Column(
+                        D._to_i128(got[h]), has_any(got) & ~overflow,
+                        T.SparkType.decimal(out_p, dcol.dtype.scale))
+
+                return total
+
+            data, valid = sdata[spec.column], sorted_valid(spec.column)
+            col_dtype = dcol.dtype
+            h_nn = counts(spec.column)
 
             if spec.op in ("sum", "mean"):
                 out_t = (T.FLOAT64 if spec.op == "mean"
@@ -770,46 +806,102 @@ def _group_by_sortscan(batch, key_names, aggs, row_valid, assume_grouped):
                                   else jnp.float64)
                 acc = jnp.where(valid, acc, jnp.zeros((), acc.dtype))
                 if jnp.issubdtype(acc.dtype, jnp.floating):
-                    s = at_ends(_seg_scan_sum(acc, boundary))
-                else:
-                    s = at_ends_diff(K.scan_sum(acc))  # exact mod-2^64
-                if spec.op == "mean":
-                    s = s / jnp.maximum(nn, 1).astype(jnp.float64)
-                out[spec.out_name] = Column(s, out_valid & has_any, out_t)
-            else:  # min / max — Spark float semantics: NaN greatest, one NaN
-                is_float = jnp.issubdtype(data.dtype, jnp.floating)
-                was_bool = data.dtype == jnp.bool_
+                    h = once(("sum", spec.column, acc.dtype), lambda: at_ends(
+                        itself, _seg_scan_sum(acc, boundary)))
+                else:   # exact mod-2^64
+                    h = once(("sum", spec.column, acc.dtype), lambda: at_ends(
+                        ends_diff, K.scan_sum(acc)))
+
+                def total(got):
+                    s = got[h]
+                    if spec.op == "mean":
+                        s = s / jnp.maximum(got[h_nn], 1).astype(jnp.float64)
+                    return Column(s, out_valid & (got[h_nn] > 0), out_t)
+
+                return total
+            # min / max — Spark float semantics: NaN greatest, one NaN
+            is_float = jnp.issubdtype(data.dtype, jnp.floating)
+            was_bool = data.dtype == jnp.bool_
+            if is_float:
+                fill = jnp.array(jnp.inf if spec.op == "min" else -jnp.inf,
+                                 data.dtype)
+                nan_in = valid & jnp.isnan(data)
+                valid_num = valid & ~jnp.isnan(data)
+            elif was_bool:   # a word, so that a matrix carries its run
+                data = data.astype(jnp.uint32)
+                fill = jnp.uint32(1 if spec.op == "min" else 0)
+                valid_num = valid
+            else:
+                info = jnp.iinfo(data.dtype)
+                fill = jnp.array(
+                    info.max if spec.op == "min" else info.min,
+                    data.dtype)
+                valid_num = valid
+            masked = jnp.where(valid_num, data, fill)
+            h = at_ends(itself, _seg_scan_minmax(masked, boundary, spec.op))
+            if is_float:
+                h_nan = once(("nan", spec.column), lambda: at_ends(
+                    ends_diff, K.scan_sum(nan_in.astype(jnp.int32))))
+                h_num = once(("num", spec.column), lambda: at_ends(
+                    ends_diff, K.scan_sum(valid_num.astype(jnp.int32))))
+
+            def extreme(got):
+                r = got[h]
                 if is_float:
-                    fill = jnp.array(jnp.inf if spec.op == "min" else -jnp.inf,
-                                     data.dtype)
-                    nan_in = valid & jnp.isnan(data)
-                    valid_num = valid & ~jnp.isnan(data)
-                elif was_bool:
-                    data = data.astype(jnp.uint8)
-                    fill = jnp.uint8(1 if spec.op == "min" else 0)
-                    valid_num = valid
-                else:
-                    info = jnp.iinfo(data.dtype)
-                    fill = jnp.array(
-                        info.max if spec.op == "min" else info.min,
-                        data.dtype)
-                    valid_num = valid
-                masked = jnp.where(valid_num, data, fill)
-                run = _seg_scan_minmax(masked, boundary, spec.op)
-                r = at_ends(run)
-                if is_float:
-                    seg_nan = at_ends_diff(
-                        K.scan_sum(nan_in.astype(jnp.int32))) > 0
-                    seg_num = at_ends_diff(
-                        K.scan_sum(valid_num.astype(jnp.int32))) > 0
+                    seg_nan = got[h_nan] > 0
                     nan = jnp.array(jnp.nan, r.dtype)
                     if spec.op == "max":
                         r = jnp.where(seg_nan, nan, r)
                     else:
-                        r = jnp.where(seg_nan & ~seg_num, nan, r)
+                        r = jnp.where(seg_nan & ~(got[h_num] > 0), nan, r)
                 if was_bool:
                     r = r.astype(jnp.bool_)
-                out[spec.out_name] = Column(r, out_valid & has_any, col_dtype)
+                return Column(r, out_valid & (got[h_nn] > 0), col_dtype)
+
+            return extreme
+
+        finish = [(spec.out_name, planned(spec)) for spec in aggs]
+
+        head_keys = all(isinstance(batch[name], _HEAD_KEY_TYPES)
+                        for name in key_names)
+        key_batch = ColumnBatch({name: batch[name] for name in key_names})
+        starts = jnp.clip(jnp.where(first, 0, prev_ends + 1), 0, n - 1)
+        if head_keys and not assume_grouped:
+            # a group's first sorted row is the row after the group
+            # before's end: the permutation one slot on, read at the ends
+            h_rows0 = at_ends(
+                lambda after: jnp.where(first[:after.shape[0]], sperm[:1],
+                                        jnp.roll(after, 1)),
+                jnp.concatenate([sperm[1:], sperm[-1:]]))
+
+        def fetch(w):
+            got = gather_rows(reads, (), ends[:w])[0]
+            return [fn(*got[at:at + k]) for fn, at, k in values]
+
+        vals = per_group(fetch, pad=lambda got: [
+            _pad_leading(v, n - v.shape[0]) for v in got])
+
+        def first_rows(w):
+            """Each key column at its group's first row."""
+            rows0 = starts[:w] if assume_grouped else vals[h_rows0][:w]
+            return list(gather_batch(key_batch, rows0, out_valid[:w]).columns)
+
+        if head_keys:
+            # a switch of their own: with the values' fetch in the same
+            # branch the v5e compiler crashed (a segmentation fault in its
+            # layout passes) on TPC-H Q18's aggregate below its exchange
+            firsts = per_group(first_rows, pad=lambda cols: [
+                _pad_rows(c, n) for c in cols])
+        else:   # a representation with no null-row padding
+            def whole():
+                rows0 = starts if assume_grouped else take_rows(sperm, starts)
+                return [gather_column(batch[name], rows0, out_valid)
+                        for name in key_batch.names]
+
+            firsts = _rowwide(n, whole)
+        out = dict(zip(key_batch.names, firsts))
+        for name, make in finish:
+            out[name] = make(vals)
 
     return ColumnBatch(out), num_groups
 

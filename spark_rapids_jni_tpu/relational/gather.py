@@ -18,7 +18,9 @@ words they are laid one above the other as a ``[words, rows]`` matrix of
 at most ``_MATRIX_WORDS`` words (one more for each more), gathered once
 and taken apart again.  Char matrices, ``float64`` data (the v5e
 compiler refuses ``bitcast f64 -> u64``), data narrower than 32 bits and
-bit-packed columns gather on their own.
+bit-packed columns gather on their own.  :func:`gather_rows` does the
+same for bare buffers (the sort engine's scans at its group ends and its
+aggregated columns along its sort).
 
 :func:`validity_gathers` counts the validity buffers that row gathers
 move (``plan.plan_cache_metrics()["validity_gathers"]``), a packed word
@@ -63,6 +65,7 @@ _WORDS_PER_ROW = {jnp.dtype(jnp.int32): 1, jnp.dtype(jnp.uint32): 1,
 
 _VALIDITY_GATHERS = [0]
 _ROW_GATHERS = [0]
+_GATHER_OPS = [0]
 
 
 def validity_gathers() -> int:
@@ -87,8 +90,15 @@ def row_gathers() -> int:
     return _ROW_GATHERS[0]
 
 
+def gather_ops() -> int:
+    """Gather operations of any number of indices that gathers through
+    this module have traced in this process."""
+    return _GATHER_OPS[0]
+
+
 def count_row_gather(indices: int, gathers: int = 1) -> None:
     """Note ``gathers`` gather operations of ``indices`` each."""
+    _GATHER_OPS[0] += gathers
     if indices > _COUNTED_ABOVE:
         _ROW_GATHERS[0] += gathers
 
@@ -238,26 +248,57 @@ def _gathered_words(words, idx):
     return out
 
 
+def _taken_as_words(bufs, words, idx):
+    """Each of ``bufs`` at rows ``idx``, then each of the ``u32[n]``
+    ``words``: the fixed-width buffers' words and ``words`` through
+    :func:`_gathered_words`, every other buffer on its own."""
+    got = iter(_gathered_words([w for b in bufs if _words_a_row(b)
+                                for w in _to_words(b)] + list(words), idx))
+    return [_from_words([next(got) for _ in range(k)], b)
+            if (k := _words_a_row(b)) else take_rows(b, idx)
+            for b in bufs] + list(got)
+
+
+def _row_gather(indices: int, rows: int) -> bool:
+    """Whether a gather of ``indices`` out of ``rows`` rows moves its
+    words as matrices: a row gather out of a source the v5e compiler
+    lays out rows-minor."""
+    return indices > _COUNTED_ABOVE and rows >= _MATRIX_FROM_ROWS
+
+
+def gather_rows(bufs, validities, idx):
+    """``bufs`` and the ``bool`` ``validities`` (arrays of one row count)
+    at rows ``idx`` (in range): in a row gather out of a source of at
+    least ``_MATRIX_FROM_ROWS`` rows, the fixed-width buffers' words and
+    the validities, packed 32 to a word, as matrices of at most
+    ``_MATRIX_WORDS`` words (:func:`_gathered_words`), the rest one
+    gather each; otherwise one gather a buffer.  Every gather counted."""
+    bufs, validities = list(bufs), list(validities)
+    m = idx.shape[0]
+    rows = (bufs + validities)[0].shape[0] if bufs or validities else 0
+    if not _row_gather(m, rows):
+        for _ in validities:
+            count_validity_gather(m)
+        return ([take_rows(b, idx) for b in bufs],
+                [take_rows(v, idx) for v in validities])
+    vwords = _validity_words(validities)
+    for _ in vwords:
+        count_validity_gather(m)
+    got = _taken_as_words(bufs, vwords, idx)
+    return (got[:len(bufs)],
+            _validity_bits(got[len(bufs):], len(validities), None))
+
+
 def _gather_matrix(cols, packed, idx, valid):
     """:func:`gather_batch` of a row gather: the fixed-width buffers and
     the validity words of ``packed`` through one matrix (or a few), every
     other buffer and column on its own."""
-    m = idx.shape[0]
     bufs = [_buffers(c) for c in packed]
-    words = [w for bs in bufs for b in bs if _words_a_row(b)
-             for w in _to_words(b)]
     vwords = _validity_words([c.validity for c in packed])
     for _ in vwords:
-        count_validity_gather(m)
-    got = iter(_gathered_words(words + vwords, idx))
-    taken = []
-    for bs in bufs:
-        out = []
-        for b in bs:
-            k = _words_a_row(b)
-            out.append(_from_words([next(got) for _ in range(k)], b) if k
-                       else take_rows(b, idx))
-        taken.append(out)
+        count_validity_gather(idx.shape[0])
+    got = iter(_taken_as_words([b for bs in bufs for b in bs], vwords, idx))
+    taken = [[next(got) for _ in bs] for bs in bufs]
     validity = iter(_validity_bits(list(got), len(packed), valid))
     rebuilt = iter(_rebuilt(c, out, next(validity))
                    for c, out in zip(packed, taken))
@@ -276,8 +317,7 @@ def gather_batch(batch: ColumnBatch, idx, valid=None) -> ColumnBatch:
     packed = [c for c in cols if isinstance(c, _PACKED)]
     words = (sum(_words_a_row(b) for c in packed for b in _buffers(c))
              + math.ceil(len(packed) / _WORD_BITS))
-    if (idx.shape[0] > _COUNTED_ABOVE and batch.num_rows >= _MATRIX_FROM_ROWS
-            and words >= 2):
+    if _row_gather(idx.shape[0], batch.num_rows) and words >= 2:
         idx = jnp.clip(idx, 0, max(batch.num_rows - 1, 0))
         return ColumnBatch(dict(zip(batch.names,
                                     _gather_matrix(cols, packed, idx,

@@ -299,11 +299,13 @@ def test_the_count_only_rises():
 # answered by the sort engines): the validity buffers and the count the
 # per-column gathers give (packing off); the gathers, one a buffer (no
 # source of 2^19 rows, so no matrix), and with matrices out of sources of
-# more than 4096 rows: PERF.md section 3
+# more than 4096 rows; the sort engine's reads at the group ends among
+# them: PERF.md section 3
 @pytest.mark.parametrize("config_name,packed,per_column,per_buffer,gathers", [
-    ("q95-join-agg", 10, 29, 39, 16),
-    ("tpch-q3", 8, 14, 22, 16),
-    ("tpch-q18", 11, 18, 29, 23),
+    ("q95-join-agg", 10, 29, 42, 16),
+    ("tpch-q3", 6, 14, 27, 12),
+    ("tpch-q18", 8, 18, 39, 18),
+    ("tpch-q13", 8, 10, 20, 11),
     ("q6-scan-agg", 0, 0, 0, 0),
 ])
 def test_the_plans_count(monkeypatch, config_name, packed, per_column,

@@ -32,16 +32,24 @@ _HEAD_AGGS = [
     AggSpec("min", "d", "lod"),
 ]
 _HEAD_JITS = {}
+# ... and the columns past them: an int32 sum, a decimal in 64-bit storage
+_FETCH_AGGS = _HEAD_AGGS + [
+    AggSpec("sum", "i", "si"), AggSpec("sum", "e", "se"),
+    AggSpec("mean", "e", "me"), AggSpec("count", "e", "ne"),
+]
 
 
-def _head_case(n, g, has_rv, grouped, seed):
+def _head_case(n, g, has_rv, grouped, seed, aggs=_HEAD_AGGS, key64=False):
     """``g`` groups over the live rows of an ``n``-row batch (dead rows
     trailing, one null key, one group whose values are all null) and what
-    every aggregate of ``_HEAD_AGGS`` must give, group by group in the
-    engine's order, from plain Python."""
+    every aggregate of ``aggs`` must give, group by group in the engine's
+    order, from plain Python.  Beside the columns ``_HEAD_AGGS`` reads, an
+    int32 ``i`` and a decimal ``e`` in 64-bit storage, drawn apart so that
+    the others stay as they were; ``key64``: keys past 32 bits."""
     from spark_rapids_jni_tpu.columnar.column import Decimal128Column
 
     rng = np.random.default_rng(seed)
+    more = np.random.default_rng(seed + 1)
     live = n - n // 8 if has_rv else n
     if g == "all":
         g = live
@@ -50,11 +58,11 @@ def _head_case(n, g, has_rv, grouped, seed):
     cuts = np.sort(rng.choice(np.arange(1, live), g - 1, replace=False)) \
         if g > 1 else np.zeros((0,), np.int64)
     sizes = np.diff(np.concatenate([[0], cuts, [live]])) if g else []
-    keyvals = [int(x) for x in rng.choice(
+    keyvals = [int(x) * ((1 << 33) + 1 if key64 else 1) for x in rng.choice(
         np.arange(-5 * n, 5 * n), g, replace=False)]
     if g >= 2:
         keyvals[1] = None
-    rows = []   # (group, key, v, f, b, d)
+    rows = []   # (group, key, v, f, b, d, i, e)
     for j, size in enumerate(sizes):
         for _ in range(int(size)):
             dull = g >= 3 and j == 2
@@ -62,6 +70,7 @@ def _head_case(n, g, has_rv, grouped, seed):
             fval = float(rng.integers(-1000, 1000))
             if rng.random() < 0.05:
                 fval = math.nan
+            pick2 = more.random(2)
             rows.append((
                 j, keyvals[j],
                 None if dull or pick[0] < 0.15
@@ -69,18 +78,28 @@ def _head_case(n, g, has_rv, grouped, seed):
                 None if dull or pick[1] < 0.15 else fval,
                 None if dull or pick[2] < 0.15 else bool(rng.random() < 0.5),
                 None if dull or pick[3] < 0.15
-                else int(rng.integers(-(10**18), 10**18)) * 10))
+                else int(rng.integers(-(10**18), 10**18)) * 10,
+                None if dull or pick2[0] < 0.15
+                else int(more.integers(-(1 << 31), 1 << 31)),
+                None if dull or pick2[1] < 0.15
+                else int(more.integers(-(10**17), 10**17))))
     if not grouped:
         rows = [rows[i] for i in rng.permutation(len(rows))]
-    dead = [(None, int(rng.integers(-5 * n, 5 * n)), 7, 7.0, True, 7)
+    dead = [(None, int(rng.integers(-5 * n, 5 * n)), 7, 7.0, True, 7, 7, 7)
             for _ in range(n - live)]
     allrows = rows + dead
+    e = [r[7] for r in allrows]
     batch = ColumnBatch({
-        "k": Column.from_pylist([r[1] for r in allrows], T.INT32),
+        "k": Column.from_pylist([r[1] for r in allrows],
+                                T.INT64 if key64 else T.INT32),
         "v": Column.from_pylist([r[2] for r in allrows], T.INT64),
         "f": Column.from_pylist([r[3] for r in allrows], T.FLOAT64),
         "b": Column.from_pylist([r[4] for r in allrows], T.BOOLEAN),
         "d": Decimal128Column.from_unscaled([r[5] for r in allrows], 20, 2),
+        "i": Column.from_pylist([r[6] for r in allrows], T.INT32),
+        "e": Column(jnp.asarray([x or 0 for x in e], jnp.int64),
+                    jnp.asarray([x is not None for x in e]),
+                    T.SparkType.decimal(18, 2)),
     })
     rv = jnp.asarray(np.arange(n) < live) if has_rv else None
 
@@ -99,11 +118,11 @@ def _head_case(n, g, has_rv, grouped, seed):
         q += 2 * r >= den
         return -q if num < 0 else q
 
-    want = {name: [] for name in ["k"] + [a.out_name for a in _HEAD_AGGS]}
+    want = {name: [] for name in ["k"] + [a.out_name for a in _FETCH_AGGS]}
     for j in order:
         mine = [r for r in rows if r[0] == j]
-        v, f, b, d = ([r[i] for r in mine if r[i] is not None]
-                      for i in (2, 3, 4, 5))
+        v, f, b, d, i, e = ([r[c] for r in mine if r[c] is not None]
+                            for c in (2, 3, 4, 5, 6, 7))
         want["k"].append(keyvals[j])
         want["n"].append(len(mine))
         want["nv"].append(len(v))
@@ -120,7 +139,12 @@ def _head_case(n, g, has_rv, grouped, seed):
         want["sd"].append(sum(d) if d else None)
         want["md"].append(half_up(sum(d) * 10**4, len(d)) if d else None)
         want["lod"].append(min(d) if d else None)
-    return batch, rv, g, want
+        want["si"].append(sum(i) if i else None)
+        want["se"].append(sum(e) if e else None)
+        want["me"].append(half_up(sum(e) * 10**4, len(e)) if e else None)
+        want["ne"].append(len(e))
+    names = ["k"] + [a.out_name for a in aggs]
+    return batch, rv, g, {name: want[name] for name in names}
 
 
 def _head_run(monkeypatch, head, batch, rv, grouped, mode):
@@ -321,3 +345,153 @@ class TestSortScanHead:
             np.asarray(out["s"].data)[:g],
             np.add.reduceat(v, np.concatenate([[0], cuts])))
         assert np.asarray(out["s"].validity).sum() == g
+
+
+# ---------------------------------------------------------------------------
+# the fetch as matrices: a head of 8 over 256 rows makes the widths 8, 128
+# and every row; with row gathers counted from more than 8 indices and
+# matrices out of sources of 256 rows, both wider branches move the words
+# they read as matrices, the head one gather a buffer
+# ---------------------------------------------------------------------------
+
+_FETCH_JITS = {}
+_FETCH_WIDTHS = {"head": 5, "tier.128": 100, "full": 200}   # groups
+
+
+def _fetch_run(monkeypatch, batch, rv, aggs, grouped, matrices):
+    """``group_by`` of ``aggs`` on the sort engine under the patched ladder,
+    with the words moved as matrices past the head (``matrices``) or one
+    gather a buffer; the lowered program's text beside the result."""
+    import jax
+
+    from spark_rapids_jni_tpu.relational import aggregate as A
+    from spark_rapids_jni_tpu.relational import gather as G
+
+    monkeypatch.setattr(A, "_DEFAULT_GROUP_SLOTS", _TIER_HEAD)
+    monkeypatch.setattr(G, "_COUNTED_ABOVE", _TIER_HEAD)
+    monkeypatch.setattr(G, "_MATRIX_FROM_ROWS",
+                        _HEAD_ROWS if matrices else 1 << 40)
+    key = (tuple(a.out_name for a in aggs), batch["k"].dtype, grouped,
+           matrices)
+    if key not in _FETCH_JITS:
+        def run(b, r):
+            return group_by(b, ["k"], aggs, row_valid=r, engine="sort",
+                            assume_grouped=grouped)
+
+        fn = jax.jit(run)
+        _FETCH_JITS[key] = fn, fn.lower(batch, rv).as_text(debug_info=True)
+    fn, text = _FETCH_JITS[key]
+    out, ng = fn(batch, rv)
+    return out, int(ng), text
+
+
+def _branch_ops(text, op=r'"stablehlo\.gather"\(.*-> tensor<([^>]*)>'):
+    """The types ``op`` captures of each of its operations in each fetch
+    branch of a lowered program's text (by default every gather's
+    result)."""
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    found = {}
+    for m in re.finditer(op + r'.*loc\((#loc\d+)\)', text):
+        scope = re.search(r"agg\.sortscan_(head|full|tier\.\d+)",
+                          locs.get(m.group(2), ""))
+        if scope:
+            found.setdefault(scope.group(1), []).append(m.group(1))
+    return found
+
+
+class TestTheFetchAsMatrices:
+    """The values every aggregate reads at the group ends come from one
+    fetch a width: past the head, one gather for every eight u32 words of
+    them and one for the keys, the same answers bit for bit as one gather a
+    buffer, and both plain Python's."""
+
+    # The words read at the group ends, 41 of them: the int32 counts of
+    # the live rows and of each column's non-null values (7), sum(v) and
+    # sum(i) (2 each), min(v) and max(v) (2 each), the NaN and number
+    # counts of f (1 each), the runs of b widened to a word (1 each), the
+    # decimals' lane sums (four 64-bit scans and the negatives: 9 each),
+    # min(d)'s limbs (4); the sorting path reads the permutation one slot
+    # on too (42).  Six matrices of eight and fewer; the float64 sums and
+    # runs on their own (f's sum and mean share one, mean(v), min(f),
+    # max(f)); the key's words and validity one matrix.  At the head one
+    # gather a buffer: 31 (32) and the key's data and validity.
+    @pytest.mark.parametrize("key64", [False, True],
+                             ids=["int32_key", "int64_key"])
+    @pytest.mark.parametrize("grouped,past_head,at_head", [
+        (True, 6 + 4 + 1, 31 + 2), (False, 6 + 4 + 1, 32 + 2)],
+        ids=["grouped", "sorting"])
+    @pytest.mark.parametrize("width", list(_FETCH_WIDTHS))
+    def test_every_aggregate_at_each_width(self, monkeypatch, width,
+                                           grouped, past_head, at_head,
+                                           key64):
+        g = _FETCH_WIDTHS[width]
+        batch, rv, g, want = _head_case(_HEAD_ROWS, g, True, grouped,
+                                        seed=41 + g, aggs=_FETCH_AGGS,
+                                        key64=key64)
+        out, ng, text = _fetch_run(monkeypatch, batch, rv, _FETCH_AGGS,
+                                   grouped, matrices=True)
+        ref, ng_ref, ref_text = _fetch_run(monkeypatch, batch, rv,
+                                           _FETCH_AGGS, grouped,
+                                           matrices=False)
+        assert ng == ng_ref == g
+        assert (128 if width == "tier.128" else g) >= g > (
+            {"head": 0, "tier.128": 8, "full": 128}[width])
+        for name, vals in want.items():
+            assert _canon(out[name].to_pylist()[:g]) == _canon(vals), name
+        assert out.names == ref.names
+        for name in out.names:
+            assert out[name].dtype == ref[name].dtype
+            for a, b in zip(_raw(out[name]), _raw(ref[name])):
+                assert a.dtype == b.dtype and a.shape == b.shape, name
+                assert a.tobytes() == b.tobytes(), name
+        gathers = {w: len(ts) for w, ts in _branch_ops(text).items()}
+        assert gathers == {"head": at_head, "tier.128": past_head,
+                           "full": past_head}
+        # every matrix a [words, slots] one of at most eight words
+        for w, ts in _branch_ops(text).items():
+            for t in ts:
+                dims = t.split("x")[:-1]
+                assert len(dims) == 1 or (len(dims) == 2 and w != "head"
+                                          and int(dims[0]) <= 8), (w, t)
+        # one gather a buffer where no matrix may be made
+        one_each = {w: len(ts)
+                    for w, ts in _branch_ops(ref_text).items()}
+        assert one_each == {"head": at_head, "tier.128": at_head,
+                            "full": at_head}
+
+    @pytest.mark.parametrize("matrices", [True, False],
+                             ids=["matrices", "one_a_buffer"])
+    @pytest.mark.parametrize("width", list(_FETCH_WIDTHS))
+    def test_a_64_bit_difference_borrows_on_halves(self, monkeypatch, width,
+                                                   matrices):
+        """sum(v) of values between 2^31 and 2^33, either sign: the prefix
+        scan passes multiples of 2^32 between nearly every two group ends,
+        so the difference borrows from the high half; no 64-bit array is
+        moved one slot (``jnp.roll``, which lowers to a function of its
+        own), the u32 halves are."""
+        n, g = _HEAD_ROWS, _FETCH_WIDTHS[width]
+        rng = np.random.default_rng(g)
+        cuts = np.sort(rng.choice(np.arange(1, n), g - 1, replace=False))
+        gid = np.zeros((n,), np.int64)
+        gid[cuts] = 1
+        gid = np.cumsum(gid)
+        v = (rng.integers(1 << 31, 1 << 33, n)
+             * rng.choice([-1, 1], n, p=[0.3, 0.7]))
+        assert len(set(np.cumsum(v)[np.append(cuts, n) - 1] >> 32)) > g // 2
+        ones = jnp.ones((n,), jnp.bool_)
+        batch = ColumnBatch({
+            "k": Column(jnp.asarray(gid.astype(np.int32)), ones, T.INT32),
+            "v": Column(jnp.asarray(v), ones, T.INT64)})
+        aggs = [AggSpec("sum", "v", "s")]
+        out, ng, text = _fetch_run(monkeypatch, batch, None, aggs, True,
+                                   matrices)
+        assert ng == g
+        assert np.array_equal(np.asarray(out["s"].data)[:g],
+                              np.add.reduceat(v, np.concatenate([[0], cuts])))
+        rolls = r"call @_roll\w*\((?:[^()]*)\) : \(tensor<([^>]*)>"
+        moved = _branch_ops(text, rolls)
+        assert set(moved) == {"head", "tier.128", "full"}
+        for w, ts in moved.items():
+            assert "ui32" in {t.split("x")[-1] for t in ts}, w
+        assert not [t for t in re.findall(rolls, text)
+                    if re.fullmatch(r"\d+x(ui|i)64", t)]

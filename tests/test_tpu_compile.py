@@ -82,11 +82,11 @@ def test_q6_step_compiles_and_fits(one_chip, monkeypatch, float_mode):
         assert mem.temp_size_in_bytes < 2 << 30
 
 
-def _lower_plan(config_name, one_chip, monkeypatch):
-    """A benchmark configuration's IR plan over one partition of the
-    configuration's own size (and the tables its partitions share), under
-    its knobs, lowered for the v5e; the plan's decisions; the configuration
-    and its module."""
+def _lower_plan(config_name, one_chip, monkeypatch, the_plan=None):
+    """A benchmark configuration's IR plan (or ``the_plan`` over its
+    tables) over one partition of the configuration's own size (and the
+    tables its partitions share), under its knobs, lowered for the v5e; the
+    plan's decisions; the configuration and its module."""
     from benchmark import lib
     from spark_rapids_jni_tpu import plan
 
@@ -105,7 +105,7 @@ def _lower_plan(config_name, one_chip, monkeypatch):
     for k, v in cfg["knobs"].items():
         config.set(k, v)
     try:
-        cp = plan.compile_plan(mod.plan(cfg), inputs)
+        cp = plan.compile_plan(the_plan or mod.plan(cfg), inputs)
         lowered = cp.fn.lower({n: inputs[n] for n in cp.input_names}, ())
     finally:
         config.reset()
@@ -168,8 +168,9 @@ def test_q95_plan_compiles_with_its_dense_joins_as_lookups(one_chip,
     tables alone.  The fused aggregate reads its grouped rows in place and
     fetches its scans at the narrowest of 4096, 65,536 and 1,048,576 group
     slots that holds the groups: under ``agg.sortscan_reduce`` only the
-    branch that more than 1,048,576 groups take gathers a row-wide result.
-    And the chip's compiler takes the program."""
+    branch that more than 1,048,576 groups take gathers a row-wide result,
+    and each branch past the head moves its words as two matrices.  And
+    the chip's compiler takes the program."""
     lowered, decisions, cfg, mod = _lower_plan("q95-join-agg", one_chip,
                                                monkeypatch)
     rows = mod.rows_per_query(cfg)
@@ -192,19 +193,66 @@ def test_q95_plan_compiles_with_its_dense_joins_as_lookups(one_chip,
                 if "/join.general/" not in path and n >= rows]
     in_reduce = [(path, out) for path, _n, out in gathers
                  if "/plan.aggregate.seg/agg.sortscan_reduce/" in path]
-    # the key, count(*), the value's non-null count and sum(v): two
-    # buffers each, at the head and at each wider slot count ...
-    for w, name in zip(tiers, ("agg.sortscan_head", "agg.sortscan_tier.65536",
-                               "agg.sortscan_tier.1048576")):
-        assert [out for path, out in in_reduce
-                if f"/{name}/" in path] == [w] * 8, name
-    # ... the same eight a row wide where the data has more groups ...
+    # at the head one gather a buffer: the key's data and validity, and
+    # count(*), the value's non-null count and sum(v), each read once at
+    # the group ends ...
     assert [out for path, out in in_reduce
-            if "/agg.sortscan_full/" in path] == [rows] * 8
+            if "/agg.sortscan_head/" in path] == [4096] * 5
+    # ... at each wider slot count two matrices: the three scans' four
+    # words, and the key's word with its validity ...
+    for w, name in zip(tiers[1:], ("agg.sortscan_tier.65536",
+                                   "agg.sortscan_tier.1048576")):
+        assert [out for path, out in in_reduce
+                if f"/{name}/" in path] == [w] * 2, name
+    # ... the same two a row wide where the data has more groups ...
+    assert [out for path, out in in_reduce
+            if "/agg.sortscan_full/" in path] == [rows] * 2
     # ... and nothing outside the branches
-    assert len(in_reduce) == 32
+    assert len(in_reduce) == 11
     mem = lowered.compile().memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+# what TPC-H Q18's first aggregate took with one gather a buffer at the
+# group ends (the v5e compiler, no chip; PERF.md section 6)
+_Q18_PER_ORDER_TEMP = 804_550_656
+
+
+def test_q18_per_order_aggregate_fetches_word_matrices(one_chip,
+                                                       monkeypatch):
+    """TPC-H Q18's first aggregate as the plan lowers it (LINEITEM's two
+    columns through its exchange, the sort engine over the grouped rows)
+    at the cell's size, 6,001,215 rows: the branch that more groups than
+    1,048,576 take reads the values at the group ends as a matrix of eight
+    u32 words and one of two, and the key at its groups' first rows as one
+    of three (its halves and the validity word), each ``[words, rows]`` and
+    laid out rows minor, not padded to 128 lanes; and the program's
+    temporaries stay within a tenth of what one gather a buffer took."""
+    import re
+
+    from spark_rapids_jni_tpu.plan.ir import (Agg, Aggregate, Exchange,
+                                              Project, Scan)
+
+    per_order = Aggregate(
+        Exchange(Project(Scan("lineitem"), ("l_orderkey", "l_quantity")),
+                 "l_orderkey"),
+        keys=("l_orderkey",), aggs=(Agg("sum", "l_quantity", "sum_qty"),))
+    lowered, _decisions, cfg, mod = _lower_plan("tpch-q18", one_chip,
+                                                monkeypatch, per_order)
+    rows = mod.rows_per_query(cfg)
+    assert rows == 6001215
+    text = lowered.as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    full = sorted(m.group(1) for m in re.finditer(
+        r'"stablehlo\.gather"\(.*-> tensor<([^>]*)>.*loc\((#loc\d+)\)', text)
+        if "/agg.sortscan_full/" in locs.get(m.group(2), ""))
+    assert full == [f"{k}x{rows}xui32" for k in (2, 3, 8)]
+    compiled = lowered.compile()
+    laid = re.findall(r"= u32\[(\d+),(\d+)\]\{(\d),\d[^}]*\} gather\(.*"
+                      r"agg\.sortscan_full/gather", compiled.as_text())
+    assert sorted(laid) == [(str(k), str(rows), "1") for k in (2, 3, 8)]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.1 * _Q18_PER_ORDER_TEMP
 
 
 def _lower_slot_build(s):
